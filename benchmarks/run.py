@@ -50,6 +50,8 @@ _TELEMETRY = {"refine", "sweeps", "sparse", "distributed", "robustness"}
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None,

@@ -302,6 +302,7 @@ _REGISTERED_ARMS = frozenset({
     ("src/repro/core/aggregate.py", "apply_cluster_move"),
     ("src/repro/core/cluster.py", "h_hop_mask"),
     ("src/repro/core/batch.py", "problem_shape_key"),
+    ("src/repro/core/reference.py", "host_aggregate"),
 })
 
 _CORE_SPARSE_ARMS = frozenset(a for a in _REGISTERED_ARMS

@@ -21,6 +21,7 @@ payload.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable
 
@@ -53,8 +54,12 @@ def symbolic_candidate_bytes(n: int, k: int, *, with_deltas: bool = False,
                              candidate_fn: Callable | None = None):
     """(candidate_bytes, delta_bytes) a shard of ``n // 4`` rows ships,
     sized by abstract evaluation — no FLOP runs."""
+    from ..core.refine import Acceptance
     from ..distributed import protocol
-    fn = candidate_fn or protocol.local_candidate_from_aggregate
+    one = jnp.float32(1.0)
+    fn = candidate_fn or functools.partial(
+        protocol.local_candidate_from_aggregate,
+        acc=Acceptance(tol=one, cut_scale=one, total_weight=one))
     rows = max(n // 4, 1)
     out = jax.eval_shape(
         lambda agg, b, ids, valid, r, loads, speeds, mu, total_b, m:

@@ -271,7 +271,8 @@ def apply_moves(problem: AnyProblem, agg: AggregateState, nodes: Array,
             ws[:, :, None] * col_delta[:, None, :])           # dups summed
     else:
         cols = problem.adjacency[:, nodes] * mask[None, :]    # (N, R)
-        new_aggregate = agg.aggregate + cols @ col_delta
+        new_aggregate = agg.aggregate + jnp.matmul(
+            cols, col_delta, precision=jax.lax.Precision.HIGHEST)
     safe_nodes = jnp.where(will_move, nodes, jnp.int32(problem.num_nodes))
     new_assignment = agg.assignment.at[safe_nodes].set(dests, mode="drop")
     new_loads = machine_loads(b, new_assignment, k)
@@ -309,7 +310,8 @@ def apply_cluster_move(problem: AnyProblem, agg: AggregateState, mask: Array,
                                     num_segments=problem.num_nodes,
                                     indices_are_sorted=True)  # (N,)
     else:
-        delta = problem.adjacency @ mask.astype(dt)           # (N,)
+        delta = jnp.matmul(problem.adjacency, mask.astype(dt),
+                           precision=jax.lax.Precision.HIGHEST)   # (N,)
     kidx = jnp.arange(k)
     col_delta = (kidx == dest).astype(dt) - (kidx == source).astype(dt)
     new_aggregate = agg.aggregate + delta[:, None] * col_delta[None, :]

@@ -66,9 +66,12 @@ DENSE_COMPLEXITY = {
 
 
 def adjacency_aggregate(adjacency: Array, assignment: Array, num_machines: int) -> Array:
-    """A[i, k] = sum_j c_ij * 1[r_j = k]; computed as C @ one_hot(r)."""
+    """A[i, k] = sum_j c_ij * 1[r_j = k]; computed as C @ one_hot(r).
+
+    ``HIGHEST`` precision: a TPU evaluates an f32 matmul at its default
+    precision in bf16, which rounds the edge weights to 8 bits."""
     onehot = jax.nn.one_hot(assignment, num_machines, dtype=adjacency.dtype)
-    return adjacency @ onehot
+    return jnp.matmul(adjacency, onehot, precision=jax.lax.Precision.HIGHEST)
 
 
 def adjacency_aggregate_sparse(sp: SparseProblem, assignment: Array) -> Array:
@@ -173,6 +176,16 @@ def node_costs(problem: AnyProblem, state: PartitionState,
     return jnp.take_along_axis(cm, state.assignment[:, None], axis=1)[:, 0]
 
 
+def _min_argmin_sum(a, b):
+    """Reducer of :func:`dissatisfaction_from_cost`: (min, lowest-index
+    argmin, sum) — ``jnp.argmin``'s semantics, NaN counting as smallest."""
+    (va, ia, sa), (vb, ib, sb) = a, b
+    a_nan, b_nan = jnp.isnan(va), jnp.isnan(vb)
+    tie = (va == vb) | (a_nan & b_nan)
+    take_a = (va < vb) | (a_nan & ~b_nan) | (tie & (ia < ib))
+    return (jnp.where(take_a, va, vb), jnp.where(take_a, ia, ib), sa + sb)
+
+
 def dissatisfaction_from_cost(cost: Array, row_assignment: Array,
                               theta: Array | None = None):
     """Eq. 4 from an already-assembled cost block: I(i) and the arg-best
@@ -187,9 +200,20 @@ def dissatisfaction_from_cost(cost: Array, row_assignment: Array,
     core↔distributed contract.  ``theta=None`` skips the subtraction
     entirely and is bit-for-bit today's behavior.
     """
-    current = jnp.take_along_axis(cost, row_assignment[:, None], axis=1)[:, 0]
-    best_machine = jnp.argmin(cost, axis=1).astype(jnp.int32)
-    best = jnp.min(cost, axis=1)
+    # One variadic reduction yields the current cost, the best cost and
+    # its machine together, so all three read the SAME evaluation of each
+    # cost entry.  Separate reductions let the compiler re-evaluate the
+    # fused cost expression per consumer with different rounding (e.g. an
+    # FMA in one loop and not the other), and a node already on its best
+    # machine would then show a spurious non-zero gain.
+    kidx = jax.lax.broadcasted_iota(jnp.int32, cost.shape, 1)
+    own_cost = jnp.where(kidx == row_assignment[:, None], cost,
+                         jnp.zeros((), cost.dtype))
+    best, best_machine, current = jax.lax.reduce(
+        (cost, kidx, own_cost),
+        (jnp.asarray(jnp.inf, cost.dtype), jnp.int32(cost.shape[1]),
+         jnp.zeros((), cost.dtype)),
+        _min_argmin_sum, (1,))
     dissat = current - best
     if theta is not None:
         dissat = dissat - theta

@@ -135,9 +135,64 @@ class DissatFn(Protocol):
         """Returns ``(dissat (rows,), best_machine (rows,))``."""
         ...
 
-# Dissatisfaction below this threshold counts as "satisfied" — guards float
-# round-off from keeping the loop alive on a plateau.
+# The acceptance test.  A move is accepted only when its net gain exceeds
+# ``tol`` PLUS a round-off allowance of ROUNDOFF_ULPS ulps of the largest
+# term in the two costs it compares (:func:`acceptance_threshold`, the one
+# helper every acceptance site calls).  The allowance is what makes the
+# loop terminate on any backend.  A gain is the difference of two f32
+# costs of magnitude S (b·L/w ≈ 1e4 on the §5.1 instance), each assembled
+# from at most four terms no larger than S with a few roundings apiece, so
+# its error is a few ulp(S): about 12 counted term by term in the worst
+# case.  Gains of that size are round-off, and an absolute 1e-6 floor let
+# them through: on XLA:CPU (jax 0.9) nodes already on their best machine
+# showed gains of 1-2 ulp(S) (4.9e-4 and 9.8e-4 at S ≈ 1.2e4) and five of
+# them cycled for all 10,000 turns.  16 ulps (up to 1.6e-2 on §5.1, whose
+# measured need lies between 1e-4 and 1e-3) stays about 1e-6 of the
+# costs, far below any real move's gain.  The scale is rounded to its
+# binade by ``jnp.spacing``, so two paths whose scales differ in the last
+# bit (sparse vs dense degree sums, vmap vs loop) agree on the threshold.
 DEFAULT_TOL = 1e-6
+ROUNDOFF_ULPS = 16
+
+
+class Acceptance(NamedTuple):
+    """Per-run constants of the acceptance threshold (:func:`acceptance`)."""
+    tol: Array            # () absolute floor
+    cut_scale: Array      # () 0.5·mu·max_i deg_i — the largest cut term
+    total_weight: Array   # () B
+
+
+def acceptance(problem, aggregate: Array, tol) -> Acceptance:
+    """Build the run's :class:`Acceptance` from any (N, K) aggregate of
+    ``problem`` (row sums are the weighted degrees)."""
+    degree = jnp.sum(aggregate, axis=-1)
+    return Acceptance(tol=jnp.asarray(tol, aggregate.dtype),
+                      cut_scale=0.5 * problem.mu * jnp.max(degree),
+                      total_weight=jnp.sum(problem.node_weights))
+
+
+def acceptance_threshold(acc: Acceptance, framework: str, b: Array,
+                         source: Array, dest: Array, loads: Array,
+                         speeds: Array) -> Array:
+    """THE acceptance threshold of a move of weight ``b`` from machine
+    ``source`` to ``dest`` (elementwise over candidates): ``tol`` plus
+    ROUNDOFF_ULPS ulps of the largest term of the two costs compared —
+    see the note above DEFAULT_TOL for the bound and its reasoning.
+
+    A sequential turn elects its machine's most dissatisfied node among
+    those whose gain clears their own threshold, so a converged run has
+    every node within its allowance (``reference.check_equilibrium``);
+    the sweep modes test each machine's elected candidates."""
+    def magnitude(k):
+        x = b / speeds[k]
+        y = loads[k] / speeds[k]
+        if framework == costs.C_FRAMEWORK:
+            load = b * y
+        else:
+            load = x * (x + 2.0 * (y + acc.total_weight))
+        return load + acc.cut_scale
+    scale = jnp.maximum(magnitude(source), magnitude(dest))
+    return acc.tol + ROUNDOFF_ULPS * jnp.spacing(scale)
 
 # Mover-buffer slots for the unbounded sweep apply (DESIGN.md §17): sets
 # up to this size update through apply_moves' incident windows; larger
@@ -175,7 +230,7 @@ def _raw_best_gain(dissat: Array, owned: Array, theta) -> Array:
 
 
 def _turn(problem: PartitionProblem, state: PartitionState, machine: Array,
-          framework: str, tol: float, cost_matrix_fn=None, theta=None,
+          framework: str, acc: Acceptance, cost_matrix_fn=None, theta=None,
           want_raw: bool = False):
     """One machine turn, recompute path: rebuild costs from scratch."""
     if cost_matrix_fn is None:
@@ -185,10 +240,13 @@ def _turn(problem: PartitionProblem, state: PartitionState, machine: Array,
     dissat, best = costs.dissatisfaction(problem, state, framework, cost=cost,
                                          theta=theta)
     owned = state.assignment == machine
-    masked = jnp.where(owned, dissat, -jnp.inf)
+    thresh = acceptance_threshold(acc, framework, problem.node_weights,
+                                  state.assignment, best, state.loads,
+                                  problem.speeds)
+    masked = jnp.where(owned & (dissat > thresh), dissat, -jnp.inf)
     node = jnp.argmax(masked).astype(jnp.int32)
     gain = masked[node]
-    do_move = gain > tol
+    do_move = gain > thresh[node]
 
     dest = best[node]
     new_assignment = jnp.where(
@@ -213,7 +271,7 @@ def _turn(problem: PartitionProblem, state: PartitionState, machine: Array,
 
 
 def _turn_incremental(problem: PartitionProblem, agg: agg_mod.AggregateState,
-                      machine: Array, framework: str, tol: float,
+                      machine: Array, framework: str, acc: Acceptance,
                       total_b: Array, dissat_fn=None, theta=None,
                       want_raw: bool = False):
     """One machine turn, incremental path: O(NK) costs from the carried
@@ -235,10 +293,13 @@ def _turn_incremental(problem: PartitionProblem, agg: agg_mod.AggregateState,
                                  problem.speeds, problem.mu, framework,
                                  total_b, theta)
     owned = agg.assignment == machine
-    masked = jnp.where(owned, dissat, -jnp.inf)
+    thresh = acceptance_threshold(acc, framework, problem.node_weights,
+                                  agg.assignment, best, agg.loads,
+                                  problem.speeds)
+    masked = jnp.where(owned & (dissat > thresh), dissat, -jnp.inf)
     node = jnp.argmax(masked).astype(jnp.int32)
     gain = masked[node]
-    do_move = gain > tol
+    do_move = gain > thresh[node]
 
     dest = best[node]
     new_agg = agg_mod.apply_move(problem, agg, node, machine, dest, do_move,
@@ -292,6 +353,8 @@ def _refine(problem: PartitionProblem, assignment: Array,
 
     if not incremental:
         state0 = make_state(problem, assignment)
+        acc = acceptance(problem, costs.problem_aggregate(problem, assignment,
+                                                          K), tol)
 
         def cond(carry):
             _, _, idle, turns, _ = carry
@@ -300,11 +363,11 @@ def _refine(problem: PartitionProblem, assignment: Array,
         def body(carry):
             state, machine, idle, turns, moves = carry
             if on_turn is None:
-                state, res = _turn(problem, state, machine, framework, tol,
+                state, res = _turn(problem, state, machine, framework, acc,
                                    cost_matrix_fn, theta)
             else:
                 state, res, raw_gain = _turn(problem, state, machine,
-                                             framework, tol, cost_matrix_fn,
+                                             framework, acc, cost_matrix_fn,
                                              theta, want_raw=True)
                 jax.debug.callback(on_turn, turns, machine, res.moved,
                                    res.node, res.source, res.dest, res.gain,
@@ -323,6 +386,7 @@ def _refine(problem: PartitionProblem, assignment: Array,
 
     agg0 = agg_mod.init_aggregate_state(problem, assignment)
     total_b = jnp.sum(problem.node_weights)
+    acc = acceptance(problem, agg0.aggregate, tol)
 
     def cond(carry):
         idle, turns = carry[2], carry[3]
@@ -332,10 +396,10 @@ def _refine(problem: PartitionProblem, assignment: Array,
         agg, machine, idle, turns, moves, max_drift = carry[:6]
         if on_turn is None:
             agg, res = _turn_incremental(problem, agg, machine, framework,
-                                         tol, total_b, dissat_fn, theta)
+                                         acc, total_b, dissat_fn, theta)
         else:
             agg, res, raw_gain = _turn_incremental(
-                problem, agg, machine, framework, tol, total_b, dissat_fn,
+                problem, agg, machine, framework, acc, total_b, dissat_fn,
                 theta, want_raw=True)
             jax.debug.callback(on_turn, turns, machine, res.moved, res.node,
                                res.source, res.dest, res.gain, res.c0,
@@ -491,17 +555,19 @@ def _refine_traced(problem: PartitionProblem, assignment: Array,
 
     if not incremental:
         state0 = make_state(problem, assignment)
+        acc = acceptance(problem, costs.problem_aggregate(problem, assignment,
+                                                          K), tol)
 
         def step(carry, _):
             state, machine, idle = carry
             active = idle < K
             if telemetry:
                 new_state, res, raw_gain = _turn(
-                    problem, state, framework=framework, tol=tol,
+                    problem, state, framework=framework, acc=acc,
                     machine=machine, theta=theta, want_raw=True)
             else:
                 new_state, res = _turn(problem, state, framework=framework,
-                                       tol=tol, machine=machine, theta=theta)
+                                       acc=acc, machine=machine, theta=theta)
             new_state = jax.tree.map(
                 lambda new, old: jnp.where(active, new, old), new_state, state)
             moved = res.moved & active
@@ -531,17 +597,18 @@ def _refine_traced(problem: PartitionProblem, assignment: Array,
 
     agg0 = agg_mod.init_aggregate_state(problem, assignment)
     total_b = jnp.sum(problem.node_weights)
+    acc = acceptance(problem, agg0.aggregate, tol)
 
     def step(carry, turn_idx):
         agg, machine, idle, max_drift = carry
         active = idle < K
         if telemetry:
             new_agg, res, raw_gain = _turn_incremental(
-                problem, agg, machine, framework, tol, total_b, theta=theta,
+                problem, agg, machine, framework, acc, total_b, theta=theta,
                 want_raw=True)
         else:
             new_agg, res = _turn_incremental(problem, agg, machine, framework,
-                                             tol, total_b, theta=theta)
+                                             acc, total_b, theta=theta)
         new_agg = jax.tree.map(
             lambda new, old: jnp.where(active, new, old), new_agg, agg)
         moved = res.moved & active
@@ -638,6 +705,7 @@ def _refine_simultaneous(problem: PartitionProblem, assignment: Array,
     theta = _resolve_theta(theta, problem.num_nodes)
     agg0 = agg_mod.init_aggregate_state(problem, assignment)
     total_b = jnp.sum(problem.node_weights)
+    acc = acceptance(problem, agg0.aggregate, tol)
 
     def sweep(carry, _):
         agg, done, moves = carry
@@ -651,7 +719,10 @@ def _refine_simultaneous(problem: PartitionProblem, assignment: Array,
         masked = jnp.where(owned.T > 0, dissat[None, :], -jnp.inf)    # (K,N)
         pick = jnp.argmax(masked, axis=1).astype(jnp.int32)           # (K,)
         gains = jnp.max(masked, axis=1)
-        will_move = gains > tol                                        # (K,)
+        will_move = gains > acceptance_threshold(
+            acc, framework, problem.node_weights[pick],
+            jnp.arange(K, dtype=jnp.int32), best[pick], agg.loads,
+            problem.speeds)                                            # (K,)
         any_move = jnp.any(will_move) & ~done
 
         # Apply all K moves at once (moving machines pick disjoint nodes: a
@@ -790,22 +861,27 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
     theta = _resolve_theta(theta, n)
     agg0 = agg_mod.init_aggregate_state(problem, assignment)
     total_b = jnp.sum(problem.node_weights)
+    acc = acceptance(problem, agg0.aggregate, tol)
 
     def sweep(carry, sweep_idx):
         agg, done, moves = carry
-        # ε-gain threshold (arXiv:1305.3354, approximate congestion
-        # games): a configuration is an ε-equilibrium once no player can
-        # improve by more than ε times the per-node average potential,
-        # so the acceptance floor scales with the CARRIED potential and
-        # the loop stops at an ε-Nash point instead of chasing O(tol)
-        # tail gains.  epsilon=0 is statically elided: thresh is the
-        # same python float ``tol`` that _refine_simultaneous compares
-        # against, keeping the degenerate config bitwise.
-        if epsilon:
-            pot = agg.c0 if framework == costs.C_FRAMEWORK else agg.ct0
-            thresh = tol + epsilon * jnp.abs(pot) / n
-        else:
-            thresh = tol
+
+        def threshold(b, source, dest):
+            # ε-gain threshold (arXiv:1305.3354, approximate congestion
+            # games): a configuration is an ε-equilibrium once no player
+            # can improve by more than ε times the per-node average
+            # potential, so the acceptance floor scales with the CARRIED
+            # potential and the loop stops at an ε-Nash point instead of
+            # chasing O(tol) tail gains.  epsilon=0 is statically elided:
+            # the threshold is then exactly the one
+            # _refine_simultaneous compares against, keeping the
+            # degenerate config bitwise.
+            thresh = acceptance_threshold(acc, framework, b, source, dest,
+                                          agg.loads, problem.speeds)
+            if epsilon:
+                pot = agg.c0 if framework == costs.C_FRAMEWORK else agg.ct0
+                thresh = thresh + epsilon * jnp.abs(pot) / n
+            return thresh
 
         if sweep_fn is not None:
             # fused election: gains/picks/dests straight off the kernel
@@ -836,7 +912,9 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
                 pick = jnp.argmax(masked, axis=1).astype(jnp.int32)  # (K,)
                 gains = jnp.max(masked, axis=1)
                 dest_k = best[pick]
-            cand = gains > thresh                                    # (K,)
+            cand = gains > threshold(problem.node_weights[pick],
+                                     jnp.arange(K, dtype=jnp.int32),
+                                     dest_k)                         # (K,)
         elif moves_per_machine is not None:
             owned = jax.nn.one_hot(agg.assignment, K, dtype=dissat.dtype)
             masked = jnp.where(owned.T > 0, dissat[None, :], -jnp.inf)
@@ -844,10 +922,12 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
             gains = gains.reshape(-1)                                # (K·M,)
             pick = pick.reshape(-1).astype(jnp.int32)
             dest_k = best[pick]
-            cand = gains > thresh
+            cand = gains > threshold(problem.node_weights[pick],
+                                     agg.assignment[pick], dest_k)
         else:
             # unbounded: every node clearing the threshold is a candidate
-            cand = dissat > thresh                                   # (N,)
+            cand = dissat > threshold(problem.node_weights, agg.assignment,
+                                      best)                          # (N,)
 
         # Probabilistic acceptance (arXiv:cs/0506098, Berenbrink et al.,
         # distributed selfish load balancing): simultaneous best
@@ -985,7 +1065,7 @@ def refine_sweeps(problem: PartitionProblem, assignment: Array,
     expected-drop bound — ``move_prob · min(1, gap_i / W_dest)``, so
     each destination's expected inflow never overshoots its load
     deficit (see the derivation comment in the sweep body).
-    ``epsilon`` raises the acceptance floor to ``tol + ε·|Φ|/N`` — the
+    ``epsilon`` raises the acceptance threshold by ``ε·|Φ|/N`` — the
     ε-equilibrium threshold of 1305.3354.  Convergence is declared when
     no CANDIDATE clears the threshold (coin luck never extends or ends
     the run); the unbounded adaptive mode additionally drops candidates

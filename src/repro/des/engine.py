@@ -307,6 +307,53 @@ def _select_events(ev: EventLists, idle: Array):
     return has, slot
 
 
+def _lower_coalesced(time: Array, sender: Array, slot_rb: Array,
+                     coalesce: Array, ann_time: Array) -> Array:
+    """Lower each coalesced ROLLBACK event to its sender's new threshold:
+    ``time[r, e] = min(time[r, e], ann_time[s])`` where ``s = sender[r,
+    e]`` coalesces into receiver r (``coalesce[r, s]``) at this very slot
+    (``slot_rb[r, s] == e``).  Only a slot's own sender can match it, so
+    an (R, E) gather gives what a scatter-min of the (R, S) announcements
+    into (R, E) would — without the scatter's colliding writes, which a
+    TPU serialises."""
+    s = jnp.clip(sender, 0)                                    # (R, E)
+    e_ids = jnp.arange(time.shape[1], dtype=slot_rb.dtype)[None, :]
+    hit = (jnp.take_along_axis(coalesce, s, axis=1)
+           & (jnp.take_along_axis(slot_rb, s, axis=1) == e_ids))
+    return jnp.where(hit, jnp.minimum(time, ann_time[s]), time)
+
+
+def _insert_proposals(ev: EventLists, prop_valid: Array,
+                      props: tuple) -> tuple[EventLists, Array]:
+    """Capacity-ranked insertion: receiver r's q-th valid proposal (in
+    proposal order, ``prop_valid`` being (P, N)) fills its q-th free slot
+    (in slot order); proposals beyond its free slots are dropped.
+    ``props`` holds the (P, N) proposal fields in :class:`EventLists`
+    order (``valid`` excluded).  Returns the new lists and the number of
+    dropped proposals.
+
+    Each free slot gathers its proposal — the first p whose running
+    count of valid proposals reaches the slot's free rank + 1 — so no
+    (P, N)-update scatter is staged (a TPU serialises its writes)."""
+    free = ~ev.valid                                           # (N, E)
+    free_rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1    # (N, E)
+    prop_csum = jnp.cumsum(prop_valid.astype(jnp.int32), axis=0)  # (P, N)
+    num_props = prop_csum[-1]                                  # (N,)
+    dropped = jnp.sum(num_props - jnp.minimum(num_props,
+                                              jnp.sum(free, axis=1)))
+    fill = free & (free_rank < num_props[:, None])             # (N, E)
+    src = jax.vmap(jnp.searchsorted, in_axes=(1, 0))(
+        prop_csum, free_rank + 1)                              # (N, E)
+    src = jnp.minimum(src, prop_valid.shape[0] - 1).T          # (E, N)
+
+    def insert(field, prop):
+        got = jnp.take_along_axis(prop, src, axis=0).T         # (N, E)
+        return jnp.where(fill, got.astype(field.dtype), field)
+
+    fields = [insert(f, p) for f, p in zip(ev[:-1], props, strict=True)]
+    return EventLists(*fields, valid=ev.valid | fill), dropped
+
+
 def des_tick(cfg: DESConfig, adj: Array, state: DESState,
              speed_schedule: SpeedSchedule | None = None,
              emit_tick=None, emit_refine=None) -> DESState:
@@ -562,9 +609,8 @@ def des_tick(cfg: DESConfig, adj: Array, state: DESState,
     has_rb = jnp.any(rb_match, axis=1)                           # (R, S)
     slot_rb = jnp.argmax(rb_match, axis=1).astype(jnp.int32)     # (R, S)
     coalesce = ann_pair.T & has_rb                               # (R, S)
-    upd = jnp.where(coalesce, ann_time[None, :], _INF)
-    r_idx = jnp.broadcast_to(jnp.arange(N)[:, None], (N, N))
-    ev = ev._replace(time=ev.time.at[r_idx, slot_rb].min(upd))
+    ev = ev._replace(time=_lower_coalesced(ev.time, ev.sender, slot_rb,
+                                           coalesce, ann_time))
     ann_pair = ann_pair & ~coalesce.T
 
     P = N + H
@@ -611,38 +657,10 @@ def des_tick(cfg: DESConfig, adj: Array, state: DESState,
     ], axis=0).astype(jnp.int32)
 
     # ---- P4: capacity-ranked insertion -------------------------------------
-    free = ~ev.valid                                          # (N, E)
-    free_count = jnp.sum(free, axis=1)
-    order_key = jnp.where(free, jnp.arange(E)[None, :],
-                          E + jnp.arange(E)[None, :])
-    free_pos = jnp.argsort(order_key, axis=1).astype(jnp.int32)  # (N, E)
-    prop_rank = jnp.cumsum(prop_valid.astype(jnp.int32), axis=0) - 1  # (P, N)
-    accept = prop_valid & (prop_rank < free_count[None, :]) & (prop_rank < E)
-    dropped = state.dropped + jnp.sum((prop_valid & ~accept).astype(jnp.int32))
-
-    r_grid = jnp.broadcast_to(jnp.arange(N)[None, :], (P, N))
-    slot_idx = free_pos[r_grid, jnp.clip(prop_rank, 0, E - 1)]   # (P, N)
-    flat = jnp.where(accept, r_grid * E + slot_idx, N * E)       # dummy last
-
-    def scatter(field_2d, updates, fill):
-        padded = jnp.concatenate(
-            [field_2d.reshape(-1), jnp.array([fill], field_2d.dtype)])
-        padded = padded.at[flat.reshape(-1)].set(
-            jnp.where(accept, updates, fill).reshape(-1).astype(field_2d.dtype))
-        return padded[:-1].reshape(N, E)
-
-    # non-accepted proposals all write to the dummy slot N*E (unique target),
-    # accepted ones write to unique (receiver, slot) pairs by construction.
-    ev = EventLists(
-        time=scatter(ev.time, prop_time, 0.0),
-        thread=scatter(ev.thread, prop_thread, 0),
-        typ=scatter(ev.typ, prop_typ, 0),
-        tick=scatter(ev.tick, prop_tick, 0),
-        count=scatter(ev.count, prop_count, 0),
-        sender=scatter(ev.sender, prop_sender, 0),
-        epoch=scatter(ev.epoch, prop_epoch, 0),
-        valid=scatter(ev.valid, jnp.ones((P, N), bool), False),
-    )
+    ev, n_dropped = _insert_proposals(
+        ev, prop_valid, (prop_time, prop_thread, prop_typ, prop_tick,
+                         prop_count, prop_sender, prop_epoch))
+    dropped = state.dropped + n_dropped
 
     # accepted forwards enter the receiver's event list, so next tick's
     # seen_time recomputation picks them up automatically.

@@ -48,7 +48,7 @@ import jax.numpy as jnp
 
 from ..core import aggregate as agg_mod
 from ..core import costs
-from ..core.refine import DissatFn
+from ..core.refine import Acceptance, DissatFn, acceptance_threshold
 
 Array = jax.Array
 
@@ -75,7 +75,7 @@ class Candidate(NamedTuple):
 
 class Winner(NamedTuple):
     """Deterministic election result, identical on every machine."""
-    moved: Array    # bool — gain > tol
+    moved: Array    # bool — gain clears the acceptance threshold
     node: Array     # i32
     dest: Array     # i32
     gain: Array     # f32
@@ -97,7 +97,8 @@ def block_aggregate(row_block: Array, assignment: Array,
     equal to the controller's full-aggregate rows (DESIGN.md §9.1).
     """
     onehot = jax.nn.one_hot(assignment, num_machines, dtype=row_block.dtype)
-    return row_block @ onehot
+    return jnp.matmul(row_block, onehot,
+                      precision=jax.lax.Precision.HIGHEST)   # as the controller
 
 
 def update_block_aggregate(aggregate: Array, row_block: Array, node: Array,
@@ -175,16 +176,27 @@ def _shard_dissatisfaction(row_block, b_local, ids, valid, assignment,
     return r_local, dissat, best_machine
 
 
+def _movable(acc: Acceptance, framework: str, dissat: Array, b_local: Array,
+             r_local: Array, best_machine: Array, loads: Array,
+             speeds: Array) -> Array:
+    """Rows whose gain clears their own acceptance threshold — the
+    sequential turn's filter, mirroring ``repro.core.refine._turn``."""
+    return dissat > acceptance_threshold(acc, framework, b_local, r_local,
+                                         best_machine, loads, speeds)
+
+
 def local_candidate(row_block: Array, b_local: Array, ids: Array,
                     valid: Array, assignment: Array, loads: Array,
                     speeds: Array, mu: Array, total_b: Array,
-                    machine: Array, framework: str,
+                    machine: Array, framework: str, acc: Acceptance,
                     cost_matrix_fn=None, theta_local=None) -> Candidate:
-    """The shard's most dissatisfied node owned by ``machine`` (Eq. 4)."""
+    """The shard's most dissatisfied node owned by ``machine`` (Eq. 4)
+    among those whose gain clears its acceptance threshold."""
     r_local, dissat, best_machine = _shard_dissatisfaction(
         row_block, b_local, ids, valid, assignment, loads, speeds, mu,
         total_b, framework, cost_matrix_fn, theta_local)
-    owned = (r_local == machine) & valid
+    owned = (r_local == machine) & valid & _movable(
+        acc, framework, dissat, b_local, r_local, best_machine, loads, speeds)
     masked = jnp.where(owned, dissat, -jnp.inf)
     loc = jnp.argmax(masked).astype(jnp.int32)
     return Candidate(gain=masked[loc], node=ids[loc],
@@ -196,6 +208,7 @@ def local_candidate_from_aggregate(aggregate: Array, b_local: Array,
                                    assignment: Array, loads: Array,
                                    speeds: Array, mu: Array, total_b: Array,
                                    machine: Array, framework: str,
+                                   acc: Acceptance,
                                    with_deltas: bool = False,
                                    dissat_fn: DissatFn | None = None,
                                    theta_local=None):
@@ -224,7 +237,8 @@ def local_candidate_from_aggregate(aggregate: Array, b_local: Array,
         dissat, best_machine = dissat_fn(aggregate, r_local, b_local, loads,
                                          speeds, mu, framework, total_b,
                                          theta_local)
-    owned = (r_local == machine) & valid
+    owned = (r_local == machine) & valid & _movable(
+        acc, framework, dissat, b_local, r_local, best_machine, loads, speeds)
     masked = jnp.where(owned, dissat, -jnp.inf)
     loc = jnp.argmax(masked).astype(jnp.int32)
     cand = Candidate(gain=masked[loc], node=ids[loc],
@@ -290,19 +304,30 @@ def local_candidates_all_machines(row_block: Array, b_local: Array,
 # Exchange + replicated apply (the O(K) part)
 # ---------------------------------------------------------------------------
 
-def elect(cands: Candidate, tol) -> Winner:
+def candidate_thresholds(cands: Candidate, acc: Acceptance, machine: Array,
+                         loads: Array, speeds: Array,
+                         framework: str) -> Array:
+    """(S,) acceptance thresholds of the gathered candidates — computed
+    by every machine from the candidate's own weight and destination and
+    the replicated loads, so nothing extra crosses the wire."""
+    return acceptance_threshold(acc, framework, cands.weight, machine,
+                                cands.dest, loads, speeds)
+
+
+def elect(cands: Candidate, thresh: Array) -> Winner:
     """Pick the winning candidate from the gathered (S,) Candidate arrays.
 
     Max gain wins; exact-gain ties break toward the lowest global node id —
     precisely the semantics of the single controller's ``jnp.argmax`` over
     the full masked dissatisfaction vector, because each shard's local
     argmax already picked its lowest-id maximizer and shard blocks are
-    contiguous ascending id ranges.
+    contiguous ascending id ranges.  The winner moves when its gain clears
+    its own entry of ``thresh`` (:func:`candidate_thresholds`).
     """
     best_gain = jnp.max(cands.gain)
     tie = cands.gain == best_gain
     shard = jnp.argmin(jnp.where(tie, cands.node, I32_MAX)).astype(jnp.int32)
-    return Winner(moved=best_gain > tol,
+    return Winner(moved=best_gain > thresh[shard],
                   node=cands.node[shard],
                   dest=cands.dest[shard],
                   gain=best_gain,
@@ -310,13 +335,14 @@ def elect(cands: Candidate, tol) -> Winner:
                   shard=shard)
 
 
-def elect_degraded(cands: Candidate, tol, lag: Array,
+def elect_degraded(cands: Candidate, thresh: Array, lag: Array,
                    stale_penalty) -> Winner:
     """Degraded-mode election under bounded staleness (DESIGN.md §15.2).
 
-    A shard whose carried aggregate is ``lag`` winner broadcasts old only
-    wins with gain above ``tol + lag * stale_penalty`` — the S-dependent
-    acceptance threshold from the Adolphs–Berenbrink bounded-staleness
+    A shard whose carried aggregate is ``lag`` winner broadcasts old
+    competes with its gain discounted by ``lag * stale_penalty``, and
+    moves only if the discounted gain clears its acceptance threshold —
+    the S-dependent threshold from the Adolphs–Berenbrink bounded-staleness
     analysis (arXiv:1109.6925): stale gains are optimistic by at most the
     drift a bounded number of missed moves can cause, so demanding a
     proportionally larger improvement keeps the potential descending.
@@ -324,20 +350,20 @@ def elect_degraded(cands: Candidate, tol, lag: Array,
     to ``-inf`` gain before electing.
 
     With ``lag == 0`` everywhere and no masks this is decision-equivalent
-    to :func:`elect`: the winner, tie-break, and every ``moved``-gated
-    field match bitwise, which is what keeps a zero-fault plan through
-    the faulty drivers identical to the fault-free path.
+    to :func:`elect` (subtracting an exact zero leaves every gain
+    unchanged): the winner, tie-break, and every ``moved``-gated field
+    match bitwise, which is what keeps a zero-fault plan through the
+    faulty drivers identical to the fault-free path.
     """
-    thresh = tol + stale_penalty * lag.astype(jnp.float32)   # (S,)
-    eligible = cands.gain > thresh
-    eff = jnp.where(eligible, cands.gain, -jnp.inf)
+    eff = cands.gain - stale_penalty * lag.astype(jnp.float32)   # (S,)
+    eff = jnp.where(jnp.isnan(eff), -jnp.inf, eff)   # NaN-poisoned columns
     best = jnp.max(eff)
     tie = eff == best
     shard = jnp.argmin(jnp.where(tie, cands.node, I32_MAX)).astype(jnp.int32)
-    return Winner(moved=best > -jnp.inf,
+    return Winner(moved=best > thresh[shard],
                   node=cands.node[shard],
                   dest=cands.dest[shard],
-                  gain=best,
+                  gain=cands.gain[shard],
                   weight=cands.weight[shard],
                   shard=shard)
 

@@ -61,8 +61,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..core import aggregate as agg_mod
 from ..core import costs
 from ..core.problem import PartitionProblem, make_state
-from ..core.refine import (DEFAULT_TOL, DissatFn, RefineResult, Trace,
-                           _open_run)
+from ..core.refine import (DEFAULT_TOL, Acceptance, DissatFn, RefineResult,
+                           Trace, _open_run, acceptance)
 from . import accounting, faults, protocol
 from .views import ShardViews, boundary_stats, build_views, shard_node_values
 
@@ -194,6 +194,28 @@ def _shard_dissat_fn(cost_fn: str) -> DissatFn | None:
     raise ValueError(f"unknown cost_fn {cost_fn!r}")
 
 
+def _acceptance(problem: PartitionProblem, assignment: Array,
+                tol) -> Acceptance:
+    """The controller's acceptance constants, built from the full problem
+    before sharding — replicated, so no collective is needed for them."""
+    return acceptance(problem, costs.problem_aggregate(
+        problem, assignment, problem.num_machines), tol)
+
+
+def _elect_sweep(cands: protocol.Candidate, acc: Acceptance, loads: Array,
+                 problem: PartitionProblem, framework: str,
+                 lag: Array | None = None, penalty=None) -> protocol.Winner:
+    """Per-machine elections of a sweep over (S, K) candidates."""
+    k = problem.num_machines
+    thresh = protocol.candidate_thresholds(
+        cands, acc, jnp.arange(k, dtype=jnp.int32)[None, :], loads,
+        problem.speeds, framework)                                  # (S, K)
+    if lag is None:
+        return jax.vmap(protocol.elect, in_axes=(1, 1))(cands, thresh)
+    return jax.vmap(protocol.elect_degraded, in_axes=(1, 1, None, None))(
+        cands, thresh, lag, penalty)
+
+
 def _init_block_aggregates(views: ShardViews, assignment: Array,
                            num_machines: int) -> Array:
     """(S, Ns, K) carried block aggregates — the one-time matmuls."""
@@ -205,6 +227,7 @@ def _init_block_aggregates(views: ShardViews, assignment: Array,
 def _vmap_candidates(views: ShardViews, assignment: Array, loads: Array,
                      speeds: Array, mu: Array, total_b: Array,
                      machine: Array, framework: str, cost_fn: str,
+                     acc: Acceptance,
                      theta_blocks: Array | None = None) -> protocol.Candidate:
     """Recompute-path emulated exchange: all S candidates, stacked."""
     shard_cost = _shard_cost_fn(cost_fn)
@@ -213,7 +236,7 @@ def _vmap_candidates(views: ShardViews, assignment: Array, loads: Array,
         with jax.named_scope("shard_candidate"):
             return protocol.local_candidate(
                 rb, b, ids, valid, assignment, loads, speeds, mu, total_b,
-                machine, framework, cost_matrix_fn=shard_cost,
+                machine, framework, acc, cost_matrix_fn=shard_cost,
                 theta_local=th)
 
     return _vmap_shards(one, theta_blocks, views.row_block, views.weights,
@@ -224,7 +247,8 @@ def _vmap_candidates_incremental(views: ShardViews, block_aggs: Array,
                                  assignment: Array, loads: Array,
                                  speeds: Array, mu: Array, total_b: Array,
                                  machine: Array, framework: str,
-                                 cost_fn: str, with_deltas: bool = False,
+                                 cost_fn: str, acc: Acceptance,
+                                 with_deltas: bool = False,
                                  theta_blocks: Array | None = None):
     """Incremental-path emulated exchange from the carried block aggregates."""
     dissat_fn = _shard_dissat_fn(cost_fn)
@@ -233,7 +257,7 @@ def _vmap_candidates_incremental(views: ShardViews, block_aggs: Array,
         with jax.named_scope("shard_candidate_incremental"):
             return protocol.local_candidate_from_aggregate(
                 agg, b, ids, valid, assignment, loads, speeds, mu, total_b,
-                machine, framework, with_deltas=with_deltas,
+                machine, framework, acc, with_deltas=with_deltas,
                 dissat_fn=dissat_fn, theta_local=th)
 
     return _vmap_shards(one, theta_blocks, block_aggs, views.weights,
@@ -325,6 +349,7 @@ def _refine_distributed(problem: PartitionProblem, assignment: Array,
     state0 = make_state(problem, assignment)
     total_b = jnp.sum(problem.node_weights)
     theta_blocks = _shard_theta(theta, problem, s)
+    acc = _acceptance(problem, state0.assignment, tol)
     measured: dict = {}
 
     if incremental:
@@ -338,9 +363,11 @@ def _refine_distributed(problem: PartitionProblem, assignment: Array,
             r, loads, aggs, machine, idle, turns, moves = carry
             cands = _vmap_candidates_incremental(
                 views, aggs, r, loads, problem.speeds, problem.mu, total_b,
-                machine, framework, cost_fn, theta_blocks=theta_blocks)
+                machine, framework, cost_fn, acc, theta_blocks=theta_blocks)
             measured["turn"] = _nbytes(cands)
-            winner = protocol.elect(cands, tol)
+            winner = protocol.elect(
+                cands, protocol.candidate_thresholds(
+                    cands, acc, machine, loads, problem.speeds, framework))
             aggs = _update_block_aggregates(views, aggs, winner, machine)
             r, loads = protocol.apply_move(r, loads, winner, machine)
             idle = jnp.where(winner.moved, 0, idle + 1)
@@ -368,9 +395,11 @@ def _refine_distributed(problem: PartitionProblem, assignment: Array,
         r, loads, machine, idle, turns, moves = carry
         cands = _vmap_candidates(views, r, loads, problem.speeds, problem.mu,
                                  total_b, machine, framework, cost_fn,
-                                 theta_blocks=theta_blocks)
+                                 acc, theta_blocks=theta_blocks)
         measured["turn"] = _nbytes(cands)
-        winner = protocol.elect(cands, tol)
+        winner = protocol.elect(
+            cands, protocol.candidate_thresholds(
+                cands, acc, machine, loads, problem.speeds, framework))
         r, loads = protocol.apply_move(r, loads, winner, machine)
         idle = jnp.where(winner.moved, 0, idle + 1)
         return (r, loads, (machine + 1) % k, idle, turns + 1,
@@ -422,6 +451,7 @@ def _refine_distributed_traced(problem: PartitionProblem, assignment: Array,
     state0 = make_state(problem, assignment)
     total_b = jnp.sum(problem.node_weights)
     theta_blocks = _shard_theta(theta, problem, s)
+    acc = _acceptance(problem, state0.assignment, tol)
     measured: dict = {}
     setup_base = _nbytes((state0.loads, total_b))
 
@@ -436,10 +466,12 @@ def _refine_distributed_traced(problem: PartitionProblem, assignment: Array,
             active = idle < k
             cands, dc0s, dct0s = _vmap_candidates_incremental(
                 views, aggs, r, loads, problem.speeds, problem.mu, total_b,
-                machine, framework, cost_fn, with_deltas=True,
+                machine, framework, cost_fn, acc, with_deltas=True,
                 theta_blocks=theta_blocks)
             measured["turn"] = _nbytes((cands, dc0s, dct0s))
-            winner = protocol.elect(cands, tol)
+            winner = protocol.elect(
+                cands, protocol.candidate_thresholds(
+                    cands, acc, machine, loads, problem.speeds, framework))
             moved = winner.moved & active
             gated = winner._replace(moved=moved)
             new_aggs = _update_block_aggregates(views, aggs, gated, machine)
@@ -476,8 +508,10 @@ def _refine_distributed_traced(problem: PartitionProblem, assignment: Array,
         active = idle < k
         cands = _vmap_candidates(views, r, loads, problem.speeds, problem.mu,
                                  total_b, machine, framework, cost_fn,
-                                 theta_blocks=theta_blocks)
-        winner = protocol.elect(cands, tol)
+                                 acc, theta_blocks=theta_blocks)
+        winner = protocol.elect(
+            cands, protocol.candidate_thresholds(
+                cands, acc, machine, loads, problem.speeds, framework))
         new_r, new_loads = protocol.apply_move(r, loads, winner, machine)
         new_r = jnp.where(active, new_r, r)
         new_loads = jnp.where(active, new_loads, loads)
@@ -546,6 +580,7 @@ def _refine_distributed_simultaneous(problem: PartitionProblem,
     total_b = jnp.sum(problem.node_weights)
     sq_weights = views.weights * views.weights
     theta_blocks = _shard_theta(theta, problem, s)
+    acc = _acceptance(problem, state0.assignment, tol)
     measured: dict = {}
 
     def _sweep_cands_incremental(aggs, r, loads, dissat_fn):
@@ -564,8 +599,8 @@ def _refine_distributed_simultaneous(problem: PartitionProblem,
         def sweep(carry, _):
             r, loads, aggs, done, moves = carry
             cands = _sweep_cands_incremental(aggs, r, loads, dissat_fn)
-            winners = jax.vmap(protocol.elect, in_axes=(1, None),
-                               out_axes=0)(cands, tol)            # (K,)
+            winners = _elect_sweep(cands, acc, loads, problem,
+                                   framework)                     # (K,)
             any_move = jnp.any(winners.moved) & ~done
             # Idle machines elect a fallback candidate (all gains -inf)
             # whose node id may collide with a real move — mask their
@@ -631,8 +666,8 @@ def _refine_distributed_simultaneous(problem: PartitionProblem,
 
         cands = _vmap_shards(one, theta_blocks, views.row_block,
                              views.weights, views.ids, views.valid)  # (S, K)
-        winners = jax.vmap(protocol.elect, in_axes=(1, None),
-                           out_axes=0)(cands, tol)                 # (K,)
+        winners = _elect_sweep(cands, acc, loads, problem,
+                               framework)                          # (K,)
         any_move = jnp.any(winners.moved) & ~done
         safe_picks = jnp.where(winners.moved, winners.node,
                                jnp.int32(problem.num_nodes))
@@ -813,6 +848,7 @@ def _refine_distributed_faulty(problem: PartitionProblem, assignment: Array,
     state0 = make_state(problem, assignment)
     total_b = jnp.sum(problem.node_weights)
     theta_blocks = _shard_theta(theta, problem, s)
+    acc = _acceptance(problem, state0.assignment, tol)
     measured: dict = {}
     rtol = degraded.repair_tol
     penalty = degraded.stale_penalty
@@ -832,11 +868,14 @@ def _refine_distributed_faulty(problem: PartitionProblem, assignment: Array,
         aggs = _fault_inject(aggs, row, True, k)
         cands = _vmap_candidates_incremental(
             views, aggs, r, loads, problem.speeds, problem.mu, total_b,
-            machine, framework, cost_fn, theta_blocks=theta_blocks)
+            machine, framework, cost_fn, acc, theta_blocks=theta_blocks)
         measured["turn"] = _nbytes(cands)
         blocked = row.down | row.quarantined | ~row.delivered
         cands = cands._replace(gain=jnp.where(blocked, -jnp.inf, cands.gain))
-        winner = protocol.elect_degraded(cands, tol, row.lag, penalty)
+        winner = protocol.elect_degraded(
+            cands, protocol.candidate_thresholds(
+                cands, acc, machine, loads, problem.speeds, framework),
+            row.lag, penalty)
         new_aggs = _update_block_aggregates(views, aggs, winner, machine)
         miss = (row.omit | row.down)[:, None, None]
         aggs = jnp.where(miss, aggs, new_aggs)
@@ -901,6 +940,7 @@ def _refine_distributed_traced_faulty(problem: PartitionProblem,
     state0 = make_state(problem, assignment)
     total_b = jnp.sum(problem.node_weights)
     theta_blocks = _shard_theta(theta, problem, s)
+    acc = _acceptance(problem, state0.assignment, tol)
     measured: dict = {}
     setup_base = _nbytes((state0.loads, total_b))
     rtol = degraded.repair_tol
@@ -922,12 +962,15 @@ def _refine_distributed_traced_faulty(problem: PartitionProblem,
         aggs = _fault_inject(aggs, row, active, k)
         cands, dc0s, dct0s = _vmap_candidates_incremental(
             views, aggs, r, loads, problem.speeds, problem.mu, total_b,
-            machine, framework, cost_fn, with_deltas=True,
+            machine, framework, cost_fn, acc, with_deltas=True,
             theta_blocks=theta_blocks)
         measured["turn"] = _nbytes((cands, dc0s, dct0s))
         blocked = row.down | row.quarantined | ~row.delivered
         cands = cands._replace(gain=jnp.where(blocked, -jnp.inf, cands.gain))
-        winner = protocol.elect_degraded(cands, tol, row.lag, penalty)
+        winner = protocol.elect_degraded(
+            cands, protocol.candidate_thresholds(
+                cands, acc, machine, loads, problem.speeds, framework),
+            row.lag, penalty)
         moved = winner.moved & active
         gated = winner._replace(moved=moved)
         new_aggs = _update_block_aggregates(views, aggs, gated, machine)
@@ -1030,6 +1073,7 @@ def _refine_distributed_simultaneous_faulty(problem: PartitionProblem,
     total_b = jnp.sum(problem.node_weights)
     sq_weights = views.weights * views.weights
     theta_blocks = _shard_theta(theta, problem, s)
+    acc = _acceptance(problem, state0.assignment, tol)
     measured: dict = {}
     rtol = degraded.repair_tol
     penalty = degraded.stale_penalty
@@ -1057,9 +1101,8 @@ def _refine_distributed_simultaneous_faulty(problem: PartitionProblem,
         blocked = row.down | row.quarantined | ~row.delivered
         cands = cands._replace(
             gain=jnp.where(blocked[:, None], -jnp.inf, cands.gain))
-        winners = jax.vmap(protocol.elect_degraded,
-                           in_axes=(1, None, None, None),
-                           out_axes=0)(cands, tol, row.lag, penalty)  # (K,)
+        winners = _elect_sweep(cands, acc, loads, problem, framework,
+                               row.lag, penalty)                   # (K,)
         any_move = jnp.any(winners.moved) & ~done
         safe_picks = jnp.where(winners.moved, winners.node,
                                jnp.int32(problem.num_nodes))
@@ -1150,7 +1193,7 @@ def refine_distributed_shard_map(problem: PartitionProblem, assignment: Array,
     Row blocks are placed along a 1-D ``Mesh`` axis ``"shards"``; the
     per-turn exchange is a real ``lax.all_gather`` of the 16-byte
     candidates; every device then elects/applies the identical delta to
-    its replicated mirror (``check_rep=False`` because the replication
+    its replicated mirror (``check_vma=False`` because the replication
     invariant is ours, established by construction, not inferable by the
     partitioner).  Each device also carries its (Ns, K) block aggregate —
     built once at entry, updated by the same rank-1 delta every turn — so
@@ -1166,8 +1209,6 @@ def refine_distributed_shard_map(problem: PartitionProblem, assignment: Array,
     a phase-timed ``run_start``/``wire``/``run_end`` stream with the
     measured bytes reconciled against the analytic ledger.
     """
-    from jax.experimental.shard_map import shard_map
-
     k = problem.num_machines
     if devices is None:
         devices = jax.devices()
@@ -1188,11 +1229,12 @@ def refine_distributed_shard_map(problem: PartitionProblem, assignment: Array,
     theta_blocks = _shard_theta(theta, problem, s)
     if theta_blocks is None:
         theta_blocks = jnp.zeros((s, views.shard_size), jnp.float32)
+    acc = _acceptance(problem, state0.assignment, tol)
 
     if fault_plan is not None:
         return _shard_map_faulty_run(
             problem, assignment, fault_plan, framework, s, mesh, views,
-            state0, total_b, theta_blocks, theta, max_turns, tol,
+            state0, total_b, theta_blocks, theta, max_turns, acc,
             degraded or faults.DEFAULT_DEGRADED, measure_wire, recorder)
 
     measured: dict = {}
@@ -1209,14 +1251,16 @@ def refine_distributed_shard_map(problem: PartitionProblem, assignment: Array,
             r, loads, agg, machine, idle, turns, moves = carry
             cand = protocol.local_candidate_from_aggregate(
                 agg, b, ids, valid, r, loads, speeds, mu, tot, machine,
-                framework, theta_local=th)
+                framework, acc, theta_local=th)
             cands = protocol.Candidate(
                 gain=jax.lax.all_gather(cand.gain, "shards"),
                 node=jax.lax.all_gather(cand.node, "shards"),
                 dest=jax.lax.all_gather(cand.dest, "shards"),
                 weight=jax.lax.all_gather(cand.weight, "shards"))
             measured["turn"] = _nbytes(cands)
-            winner = protocol.elect(cands, tol)
+            winner = protocol.elect(
+                cands, protocol.candidate_thresholds(
+                    cands, acc, machine, loads, problem.speeds, framework))
             agg = protocol.update_block_aggregate(
                 agg, rb, winner.node, machine, winner.dest, winner.moved)
             r, loads = protocol.apply_move(r, loads, winner, machine)
@@ -1233,11 +1277,11 @@ def refine_distributed_shard_map(problem: PartitionProblem, assignment: Array,
 
     sharded = P("shards")
     rep = P()
-    fn = shard_map(spmd, mesh=mesh,
-                   in_specs=(sharded, sharded, sharded, sharded, sharded,
-                             rep, rep, rep, rep, rep),
-                   out_specs=(rep, rep, rep, rep, rep),
-                   check_rep=False)
+    fn = jax.shard_map(spmd, mesh=mesh,
+                       in_specs=(sharded, sharded, sharded, sharded, sharded,
+                                 rep, rep, rep, rep, rep),
+                       out_specs=(rep, rep, rep, rep, rep),
+                       check_vma=False)
     run = (None if recorder is None else
            _open_run(recorder, "shard_map", problem, assignment, framework,
                      theta, num_shards=s))
@@ -1274,15 +1318,13 @@ def refine_distributed_shard_map(problem: PartitionProblem, assignment: Array,
 def _shard_map_faulty_run(problem: PartitionProblem, assignment: Array,
                           fault_plan, framework: str, s: int, mesh, views,
                           state0, total_b, theta_blocks, theta,
-                          max_turns: int, tol: float, degraded,
+                          max_turns: int, acc: Acceptance, degraded,
                           measure_wire: bool, recorder):
     """Real-mesh faulty path (DESIGN.md §15.3): the FaultPlan rides
     replicated (one ``P()`` spec covers the whole pytree); each device
     masks/injects/repairs only its *own* block (``lax.axis_index``), the
     outcome scalars reduce with ``pmax``/``psum``, and the wrapper-level
     recover-or-raise audit is identical to the emulated drivers."""
-    from jax.experimental.shard_map import shard_map
-
     k = problem.num_machines
     rtol = degraded.repair_tol
     penalty = degraded.stale_penalty
@@ -1311,7 +1353,7 @@ def _shard_map_faulty_run(problem: PartitionProblem, assignment: Array,
                             row.corrupt_val[idx], agg)
             cand = protocol.local_candidate_from_aggregate(
                 agg, b, ids, valid, r, loads, speeds, mu, tot, machine,
-                framework, theta_local=th)
+                framework, acc, theta_local=th)
             cands = protocol.Candidate(
                 gain=jax.lax.all_gather(cand.gain, "shards"),
                 node=jax.lax.all_gather(cand.node, "shards"),
@@ -1321,7 +1363,10 @@ def _shard_map_faulty_run(problem: PartitionProblem, assignment: Array,
             blocked = row.down | row.quarantined | ~row.delivered
             cands = cands._replace(
                 gain=jnp.where(blocked, -jnp.inf, cands.gain))
-            winner = protocol.elect_degraded(cands, tol, row.lag, penalty)
+            winner = protocol.elect_degraded(
+                cands, protocol.candidate_thresholds(
+                    cands, acc, machine, loads, problem.speeds, framework),
+                row.lag, penalty)
             new_agg = protocol.update_block_aggregate(
                 agg, rb, winner.node, machine, winner.dest, winner.moved)
             agg = jnp.where(row.omit[idx] | row.down[idx], agg, new_agg)
@@ -1378,9 +1423,9 @@ def _shard_map_faulty_run(problem: PartitionProblem, assignment: Array,
                 jax.lax.pmax(rdrift, "shards"))
 
     sharded, rep = P("shards"), P()
-    fn = shard_map(spmd, mesh=mesh,
-                   in_specs=(sharded,) * 5 + (rep,) * 6,
-                   out_specs=(rep,) * 12, check_rep=False)
+    fn = jax.shard_map(spmd, mesh=mesh,
+                       in_specs=(sharded,) * 5 + (rep,) * 6,
+                       out_specs=(rep,) * 12, check_vma=False)
     run = (None if recorder is None else
            _open_run(recorder, "shard_map", problem, assignment, framework,
                      theta, num_shards=s, faults=True))

@@ -301,6 +301,9 @@ def specialized_geometric(n: int, seed, links_per_node: int = 3,
     d2 = ((coords[:, None, :] - coords[None, :, :]) ** 2).sum(-1)
     np.fill_diagonal(d2, np.inf)
     adj = _empty(n)
+    # the diagonal is +inf and sorts last: never take more than the n - 1
+    # other nodes, or a small graph would link a node to itself
+    neighborhood = min(neighborhood, n - 1)
     for i in range(n):
         near = np.argsort(d2[i])[:neighborhood]
         chosen = rng.choice(near, size=min(links_per_node, near.size),

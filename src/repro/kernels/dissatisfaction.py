@@ -39,13 +39,12 @@ All tile dims are multiples of the 128-lane MXU width; K is padded to 128
 lanes by the wrappers.
 
 ``interpret`` defaults to backend auto-detection (:func:`resolve_interpret`):
-interpret mode everywhere except a real TPU backend, overridable explicitly
-or via ``REPRO_PALLAS_COMPILE=1``.
+compiled on a TPU backend, interpret mode everywhere else; an explicit
+bool overrides.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -62,12 +61,10 @@ _BIG = 3.0e38   # finite "+inf" for masked K lanes (0*inf = nan)
 
 def resolve_interpret(interpret: bool | None) -> bool:
     """Backend auto-detection for the ``interpret`` flag: explicit values
-    win; ``REPRO_PALLAS_COMPILE=1`` forces compiled; otherwise interpret
-    everywhere except a real TPU backend."""
+    win; otherwise compiled on a TPU backend and interpret mode
+    everywhere else."""
     if interpret is not None:
         return interpret
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
     return jax.default_backend() != "tpu"
 
 
@@ -86,6 +83,7 @@ def _kernel(c_ref, r_cols_ref, r_rows_ref, b_rows_ref, loads_ref, speeds_ref,
               ).astype(jnp.float32)                            # (TJ, K)
     acc_ref[...] += jax.lax.dot(
         c_ref[...].astype(jnp.float32), onehot,
+        precision=jax.lax.Precision.HIGHEST,      # edge weights stay f32
         preferred_element_type=jnp.float32)
 
     @pl.when(j == num_j - 1)

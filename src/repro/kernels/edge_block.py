@@ -14,14 +14,20 @@ slabs — row tile i (``tile_n`` nodes) owns the contiguous edge range
 whose senders fall in ``[i*tile_n, (i+1)*tile_n)``, padded to the fleet
 maximum ``EB`` (multiple of ``tile_e``).  Stored per edge:
 
-  * ``local_senders`` (T, EB) — sender minus the tile's row offset, so a
-    one-hot against a TN-iota scatters the edge to its row *inside
+  * ``local_senders`` (T, 1, EB) — sender minus the tile's row offset, so
+    a one-hot against a TN-iota scatters the edge to its row *inside
     VREGs*; padding points at row ``tile_n`` (matches nothing).
-  * ``recv_index``    (T, EB) — global receiver id.  The wrapper gathers
-    ``assignment[recv_index]`` (one O(E) XLA gather, the only
+  * ``recv_index``    (T, 1, EB) — global receiver id.  The wrapper
+    gathers ``assignment[recv_index]`` (one O(E) XLA gather, the only
     assignment-dependent prep) so the kernel itself never gathers.
-  * ``edge_w``        (T, EB) — weight, 0.0 on padding (exact +0.0
+  * ``edge_w``        (T, 1, EB) — weight, 0.0 on padding (exact +0.0
     contributions, the DESIGN.md §13.1 padding rule).
+
+The slabs are 3-D with a unit middle axis so that every block's last two
+dims, ``(1, tile_e)``, either equal the array's or divide (8, 128) — the
+TPU compiler's block-shape rule, which a ``(1, tile_e)`` block over a
+``(T, EB)`` array breaks.  The sweep kernel's ``(T, 1, k_pad)`` outputs
+follow the same rule.
 
 Grid ``(T, EB/tile_e)``, edge blocks innermost.  Per step the kernel
 forms the (TN, TE) sender one-hot and the weighted (TE, K) receiver
@@ -69,9 +75,9 @@ DEFAULT_TILE_E = 128
 
 class EdgeTileLayout(NamedTuple):
     """Row-tile-aligned edge slabs (see module docstring)."""
-    local_senders: Array   # (T, EB) int32; padding = tile_n
-    recv_index: Array      # (T, EB) int32; padding = 0 (weight-0 slot)
-    edge_w: Array          # (T, EB) float32; padding = 0.0
+    local_senders: Array   # (T, 1, EB) int32; padding = tile_n
+    recv_index: Array      # (T, 1, EB) int32; padding = 0 (weight-0 slot)
+    edge_w: Array          # (T, 1, EB) float32; padding = 0.0
     num_nodes: int
     tile_n: int
     tile_e: int
@@ -92,15 +98,15 @@ def build_edge_tile_layout(sp, tile_n: int = DEFAULT_TILE_N,
                              np.arange(num_tiles + 1) * tile_n, side="left")
     counts = np.diff(bounds)
     eb = -(-max(int(counts.max(initial=1)), 1) // tile_e) * tile_e
-    ls = np.full((num_tiles, eb), tile_n, np.int32)
-    ri = np.zeros((num_tiles, eb), np.int32)
-    ew = np.zeros((num_tiles, eb), np.float32)
+    ls = np.full((num_tiles, 1, eb), tile_n, np.int32)
+    ri = np.zeros((num_tiles, 1, eb), np.int32)
+    ew = np.zeros((num_tiles, 1, eb), np.float32)
     for t in range(num_tiles):
         lo, hi = int(bounds[t]), int(bounds[t + 1])
         c = hi - lo
-        ls[t, :c] = senders[lo:hi] - t * tile_n
-        ri[t, :c] = receivers[lo:hi]
-        ew[t, :c] = weights[lo:hi]
+        ls[t, 0, :c] = senders[lo:hi] - t * tile_n
+        ri[t, 0, :c] = receivers[lo:hi]
+        ew[t, 0, :c] = weights[lo:hi]
     return EdgeTileLayout(local_senders=jnp.asarray(ls),
                           recv_index=jnp.asarray(ri),
                           edge_w=jnp.asarray(ew),
@@ -115,15 +121,16 @@ def _accumulate_edge_block(ls_ref, ra_ref, ew_ref, loads_ref, acc_ref):
     kpad = loads_ref.shape[-1]
     tn = acc_ref.shape[0]
     te = ls_ref.shape[-1]
-    ls = ls_ref[0, :]                                          # (TE,)
-    ra = ra_ref[0, :]                                          # (TE,)
-    w = ew_ref[0, :].astype(jnp.float32)                       # (TE,)
+    ls = ls_ref[0, 0, :]                                       # (TE,)
+    ra = ra_ref[0, 0, :]                                       # (TE,)
+    w = ew_ref[0, 0, :].astype(jnp.float32)                    # (TE,)
     send_oh = (jax.lax.broadcasted_iota(jnp.int32, (tn, te), 0)
                == ls[None, :]).astype(jnp.float32)             # (TN, TE)
     recv_oh = (ra[:, None]
                == jax.lax.broadcasted_iota(jnp.int32, (te, kpad), 1)
                ).astype(jnp.float32) * w[:, None]              # (TE, K)
     acc_ref[...] += jax.lax.dot(send_oh, recv_oh,
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)
 
 
@@ -155,9 +162,9 @@ def _edge_in_specs(tile_e: int, tile_n: int, k_pad: int):
     slabs stream (tile, edge-block)-wise, row operands per row tile,
     (K,) operands and scalars broadcast to every step."""
     return [
-        pl.BlockSpec((1, tile_e), lambda i, e: (i, e)),    # local send
-        pl.BlockSpec((1, tile_e), lambda i, e: (i, e)),    # recv assign
-        pl.BlockSpec((1, tile_e), lambda i, e: (i, e)),    # edge weight
+        pl.BlockSpec((1, 1, tile_e), lambda i, e: (i, 0, e)),  # local send
+        pl.BlockSpec((1, 1, tile_e), lambda i, e: (i, 0, e)),  # recv assign
+        pl.BlockSpec((1, 1, tile_e), lambda i, e: (i, 0, e)),  # edge weight
         pl.BlockSpec((1, tile_n), lambda i, e: (0, i)),    # r (rows)
         pl.BlockSpec((1, tile_n), lambda i, e: (0, i)),    # b (rows)
         pl.BlockSpec((1, tile_n), lambda i, e: (0, i)),    # theta (rows)
@@ -183,7 +190,7 @@ def dissatisfaction_from_edges_pallas(
     interpret = resolve_interpret(interpret)
     n = layout.num_nodes
     tile_n, tile_e = layout.tile_n, layout.tile_e
-    num_tiles, eb = layout.local_senders.shape
+    num_tiles, _, eb = layout.local_senders.shape
     rows_pad = num_tiles * tile_n
     k = loads.shape[0]
     k_pad = -(-k // 128) * 128
@@ -191,7 +198,7 @@ def dissatisfaction_from_edges_pallas(
         total_weight = jnp.sum(node_weights)
 
     recv_assign = jnp.take(jnp.asarray(assignment, jnp.int32),
-                           layout.recv_index)                  # (T, EB)
+                           layout.recv_index)                  # (T, 1, EB)
     r_rows, b, t, l_pad, w_pad, scalars = pad_dissat_operands(
         assignment, node_weights, theta, loads, speeds, mu, total_weight,
         n, rows_pad, k, k_pad)
@@ -237,9 +244,9 @@ def _edge_sweep_kernel(ls_ref, ra_ref, ew_ref, r_rows_ref, b_rows_ref,
             theta_rows_ref[0, :], loads_ref[0, :], speeds_ref[0, :],
             scalars_ref[0, 0], scalars_ref[0, 1], row_base,
             framework=framework, k_real=k_real, n_real=n_real)
-        gain_ref[0, :] = gain
-        node_ref[0, :] = node
-        dest_ref[0, :] = dest
+        gain_ref[0, 0, :] = gain
+        node_ref[0, 0, :] = node
+        dest_ref[0, 0, :] = dest
 
 
 def sweep_candidates_from_edges_pallas(
@@ -268,7 +275,7 @@ def sweep_candidates_from_edges_pallas(
     interpret = resolve_interpret(interpret)
     n = layout.num_nodes
     tile_n, tile_e = layout.tile_n, layout.tile_e
-    num_tiles, eb = layout.local_senders.shape
+    num_tiles, _, eb = layout.local_senders.shape
     rows_pad = num_tiles * tile_n
     k = loads.shape[0]
     k_pad = -(-k // 128) * 128
@@ -276,7 +283,7 @@ def sweep_candidates_from_edges_pallas(
         total_weight = jnp.sum(node_weights)
 
     recv_assign = jnp.take(jnp.asarray(assignment, jnp.int32),
-                           layout.recv_index)                  # (T, EB)
+                           layout.recv_index)                  # (T, 1, EB)
     r_rows, b, t, l_pad, w_pad, scalars = pad_dissat_operands(
         assignment, node_weights, theta, loads, speeds, mu, total_weight,
         n, rows_pad, k, k_pad)
@@ -288,22 +295,22 @@ def sweep_candidates_from_edges_pallas(
         grid=(num_tiles, num_e),
         in_specs=_edge_in_specs(tile_e, tile_n, k_pad),
         out_specs=[
-            pl.BlockSpec((1, k_pad), lambda i, e: (i, 0)),
-            pl.BlockSpec((1, k_pad), lambda i, e: (i, 0)),
-            pl.BlockSpec((1, k_pad), lambda i, e: (i, 0)),
+            pl.BlockSpec((1, 1, k_pad), lambda i, e: (i, 0, 0)),
+            pl.BlockSpec((1, 1, k_pad), lambda i, e: (i, 0, 0)),
+            pl.BlockSpec((1, 1, k_pad), lambda i, e: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((num_tiles, k_pad), jnp.float32),
-            jax.ShapeDtypeStruct((num_tiles, k_pad), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, k_pad), jnp.int32),
+            jax.ShapeDtypeStruct((num_tiles, 1, k_pad), jnp.float32),
+            jax.ShapeDtypeStruct((num_tiles, 1, k_pad), jnp.int32),
+            jax.ShapeDtypeStruct((num_tiles, 1, k_pad), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((tile_n, k_pad), jnp.float32)],
         interpret=interpret,
     )(layout.local_senders, recv_assign, layout.edge_w, r_rows, b, t,
       l_pad, w_pad, scalars)
     # host combine: first-maximum over tiles = globally lowest node index
-    g = gains_t[:, :k]                                         # (T, K)
+    g = gains_t[:, 0, :k]                                      # (T, K)
     win_tile = jnp.argmax(g, axis=0)
     karange = jnp.arange(k)
-    return (jnp.max(g, axis=0), nodes_t[win_tile, karange],
-            dests_t[win_tile, karange])
+    return (jnp.max(g, axis=0), nodes_t[win_tile, 0, karange],
+            dests_t[win_tile, 0, karange])

@@ -1,8 +1,7 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``interpret`` defaults to backend auto-detection (interpret mode unless the
-default backend is a real TPU); pass an explicit bool, or set
-REPRO_PALLAS_COMPILE=1, to override.
+``interpret`` defaults to backend auto-detection (compiled on a TPU
+backend, interpret mode elsewhere); pass an explicit bool to override.
 """
 from __future__ import annotations
 

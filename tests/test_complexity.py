@@ -135,7 +135,6 @@ def _psum_trace(n, k, degree):
     """An injected per-shard psum of an (N,) operand inside the round
     loop — the collective audit must reject it twice over: the schedule
     depends on N, and the recurring bytes are not the ledger constant."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("shards",))
 
@@ -144,8 +143,8 @@ def _psum_trace(n, k, degree):
             return acc + jax.lax.psum(x, "shards")
         return jax.lax.fori_loop(0, 3, step, jnp.zeros_like(x))
 
-    f = shard_map(spmd, mesh=mesh, in_specs=P(), out_specs=P(),
-                  check_rep=False)
+    f = jax.shard_map(spmd, mesh=mesh, in_specs=P(), out_specs=P(),
+                      check_vma=False)
     return jax.make_jaxpr(f)(jnp.ones((n,), jnp.float32))
 
 

@@ -108,8 +108,7 @@ def test_seeded_callback_inside_scan_body_fires():
 
 
 def test_seeded_f64_leak_fires():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(lambda x: jnp.cumsum(x * 2.0))(
             np.ones(4, np.float64))
     drift = jaxpr_rules.dtype_drift(jaxpr)

@@ -185,3 +185,67 @@ def test_determinism():
     assert int(a.tick) == int(b.tick)
     assert int(a.processed) == int(b.processed)
     np.testing.assert_array_equal(np.asarray(a.seen), np.asarray(b.seen))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_insert_proposals_matches_sequential_reference(seed):
+    """Capacity-ranked insertion against a plain loop: each receiver takes
+    its valid proposals in order into its free slots in order, and the
+    rest are dropped."""
+    from repro.des.engine import EventLists, _insert_proposals
+
+    rng = np.random.default_rng(seed)
+    n, e, p = 12, 6, 20
+    valid = rng.random((n, e)) < 0.5
+    valid[0] = True                                   # a full receiver
+    ev = EventLists(rng.random((n, e)).astype(np.float32),
+                    *(rng.integers(-1, 9, (n, e)).astype(np.int32)
+                      for _ in range(6)), valid)
+    prop_valid = rng.random((p, n)) < 0.3
+    prop_valid[:, 1] = False                          # no proposals at all
+    props = (rng.random((p, n)).astype(np.float32),
+             *(rng.integers(-1, 9, (p, n)).astype(np.int32)
+               for _ in range(6)))
+
+    want = [np.array(f) for f in ev]
+    dropped = 0
+    for r in range(n):
+        slots = [s for s in range(e) if not valid[r, s]]
+        for q, src in enumerate(np.nonzero(prop_valid[:, r])[0]):
+            if q >= len(slots):
+                dropped += 1
+                continue
+            for f, prop in zip(want[:-1], props):
+                f[r, slots[q]] = prop[src, r]
+            want[-1][r, slots[q]] = True
+
+    got, got_dropped = _insert_proposals(
+        EventLists(*map(jnp.asarray, ev)), jnp.asarray(prop_valid),
+        tuple(map(jnp.asarray, props)))
+    assert int(got_dropped) == dropped
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w)
+
+
+def test_lower_coalesced_matches_scatter_min():
+    """The coalesce step against the scatter-min it replaces: every
+    (receiver, sender) pair writes its announcement (or the +inf
+    stand-in) into the slot its ROLLBACK event matched."""
+    from repro.des.engine import _INF, _lower_coalesced
+
+    rng = np.random.default_rng(3)
+    r, e, s = 9, 5, 11
+    time = rng.random((r, e)).astype(np.float32)
+    sender = rng.integers(-1, s, (r, e)).astype(np.int32)
+    match = (rng.random((r, e)) < 0.6)[:, :, None] \
+        & (sender[:, :, None] == np.arange(s))                 # (R, E, S)
+    slot_rb = match.argmax(axis=1).astype(np.int32)
+    coalesce = match.any(axis=1) & (rng.random((r, s)) < 0.7)
+    ann_time = (rng.random(s) - 0.5).astype(np.float32)
+    upd = np.where(coalesce, ann_time[None, :], _INF)
+    want = np.asarray(jnp.asarray(time).at[
+        np.broadcast_to(np.arange(r)[:, None], (r, s)), slot_rb].min(upd))
+    got = _lower_coalesced(*map(jnp.asarray, (time, sender, slot_rb,
+                                              coalesce, ann_time)))
+    assert (want != time).any()
+    np.testing.assert_array_equal(np.asarray(got), want)

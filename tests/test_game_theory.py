@@ -27,6 +27,7 @@ from repro.core.annealing import simulated_annealing
 from repro.core.cluster import cluster_move_pass
 from repro.core.constrained import equalize_cardinality
 from repro.core.problem import make_problem, make_state, machine_loads
+from repro.core.reference import check_equilibrium
 from repro.core.refine import (count_discrepancies, refine,
                                refine_simultaneous, refine_traced)
 from repro.graphs.generators import random_degree_graph, random_weights
@@ -205,6 +206,25 @@ def test_refine_fixed_point_is_stable(paper_problem):
     assert int(res2.num_moves) == 0
     np.testing.assert_array_equal(np.asarray(res.assignment),
                                   np.asarray(res2.assignment))
+
+
+@pytest.mark.parametrize("framework", costs.FRAMEWORKS)
+@pytest.mark.parametrize("incremental", [True, False])
+def test_paper_instance_converges_at_default_tol(framework, incremental,
+                                                 paper_problem):
+    """Regression: at an absolute 1e-6 acceptance floor the §5.1 instance
+    ran all 10,000 turns, cycling five nodes through zero-gain moves made
+    of cost round-off.  With the scale-relative threshold both the
+    incremental and the recompute path stop, at an assignment the
+    float64 host check confirms as an equilibrium."""
+    adj, prob = paper_problem
+    r0 = jnp.asarray(np.random.default_rng(42).integers(
+        0, prob.num_machines, prob.num_nodes), jnp.int32)
+    res = refine(prob, r0, framework, incremental=incremental)
+    assert bool(res.converged), int(res.num_turns)
+    assert int(res.num_turns) < 1000
+    check = check_equilibrium(prob, res.assignment, framework)
+    assert check.ok, check
 
 
 def test_refine_mu_zero_balances_load():
