@@ -281,29 +281,32 @@ def _turn_incremental(problem: PartitionProblem, agg: agg_mod.AggregateState,
     docstring) and substitutes e.g. the fused Pallas kernel
     (``repro.kernels.ops.make_aggregate_dissat_fn``) for the jnp assembly.
     """
-    if dissat_fn is None:
-        cost = costs.cost_matrix_from_aggregate(
-            agg.aggregate, agg.assignment, problem.node_weights, agg.loads,
-            problem.speeds, problem.mu, framework, total_weight=total_b)
-        dissat, best = costs.dissatisfaction_from_cost(cost, agg.assignment,
-                                                       theta)
-    else:
-        dissat, best = dissat_fn(agg.aggregate, agg.assignment,
-                                 problem.node_weights, agg.loads,
-                                 problem.speeds, problem.mu, framework,
-                                 total_b, theta)
-    owned = agg.assignment == machine
-    thresh = acceptance_threshold(acc, framework, problem.node_weights,
-                                  agg.assignment, best, agg.loads,
-                                  problem.speeds)
-    masked = jnp.where(owned & (dissat > thresh), dissat, -jnp.inf)
-    node = jnp.argmax(masked).astype(jnp.int32)
-    gain = masked[node]
-    do_move = gain > thresh[node]
+    with jax.named_scope("elect"):
+        if dissat_fn is None:
+            cost = costs.cost_matrix_from_aggregate(
+                agg.aggregate, agg.assignment, problem.node_weights,
+                agg.loads, problem.speeds, problem.mu, framework,
+                total_weight=total_b)
+            dissat, best = costs.dissatisfaction_from_cost(
+                cost, agg.assignment, theta)
+        else:
+            dissat, best = dissat_fn(agg.aggregate, agg.assignment,
+                                     problem.node_weights, agg.loads,
+                                     problem.speeds, problem.mu, framework,
+                                     total_b, theta)
+        owned = agg.assignment == machine
+        thresh = acceptance_threshold(acc, framework, problem.node_weights,
+                                      agg.assignment, best, agg.loads,
+                                      problem.speeds)
+        masked = jnp.where(owned & (dissat > thresh), dissat, -jnp.inf)
+        node = jnp.argmax(masked).astype(jnp.int32)
+        gain = masked[node]
+        do_move = gain > thresh[node]
+        dest = best[node]
 
-    dest = best[node]
-    new_agg = agg_mod.apply_move(problem, agg, node, machine, dest, do_move,
-                                 total_b)
+    with jax.named_scope("apply"):
+        new_agg = agg_mod.apply_move(problem, agg, node, machine, dest,
+                                     do_move, total_b)
     res = TurnResult(
         moved=do_move,
         node=jnp.where(do_move, node, -1),
@@ -325,11 +328,15 @@ class RefineResult(NamedTuple):
     # max deviation observed at verify_every cross-checks (0 when disabled
     # or on the recompute path — there is nothing to drift there)
     aggregate_drift: Array | float = 0.0
+    # sweeps of the unbounded refine_sweeps mode whose accepted set
+    # overflowed the mover buffer and took the O(E·K) rebuild (0 elsewhere)
+    num_rebuilds: Array | int = 0
 
 
 @partial(jax.jit, static_argnames=("framework", "max_turns", "cost_matrix_fn",
                                    "incremental", "verify_every",
                                    "repair_every", "dissat_fn", "on_turn"))
+@jax.named_scope("refine")
 def _refine(problem: PartitionProblem, assignment: Array,
             framework: str = costs.C_FRAMEWORK,
             max_turns: int = 10_000, tol: float = DEFAULT_TOL,
@@ -384,9 +391,10 @@ def _refine(problem: PartitionProblem, assignment: Array,
                             converged=idle >= K,
                             aggregate_drift=jnp.zeros(()))
 
-    agg0 = agg_mod.init_aggregate_state(problem, assignment)
-    total_b = jnp.sum(problem.node_weights)
-    acc = acceptance(problem, agg0.aggregate, tol)
+    with jax.named_scope("init"):
+        agg0 = agg_mod.init_aggregate_state(problem, assignment)
+        total_b = jnp.sum(problem.node_weights)
+        acc = acceptance(problem, agg0.aggregate, tol)
 
     def cond(carry):
         idle, turns = carry[2], carry[3]
@@ -449,6 +457,7 @@ def _open_run(recorder, runtime: str, problem, assignment, framework: str,
         speeds=np.asarray(problem.speeds), **extra)
 
 
+@partial(jax.profiler.annotate_function, name="repro.refine")
 def refine(problem: PartitionProblem, assignment: Array,
            framework: str = costs.C_FRAMEWORK,
            max_turns: int = 10_000, tol: float = DEFAULT_TOL,
@@ -844,6 +853,7 @@ class SweepCandidateFn(Protocol):
                                    "moves_per_machine", "move_prob",
                                    "epsilon", "dissat_fn", "sweep_fn",
                                    "telemetry"))
+@jax.named_scope("refine_sweeps")
 def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
                    framework: str = costs.C_FRAMEWORK,
                    max_sweeps: int = 256, tol: float = DEFAULT_TOL,
@@ -859,165 +869,178 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
     K = problem.num_machines
     n = problem.num_nodes
     theta = _resolve_theta(theta, n)
-    agg0 = agg_mod.init_aggregate_state(problem, assignment)
-    total_b = jnp.sum(problem.node_weights)
-    acc = acceptance(problem, agg0.aggregate, tol)
+    with jax.named_scope("init"):
+        agg0 = agg_mod.init_aggregate_state(problem, assignment)
+        total_b = jnp.sum(problem.node_weights)
+        acc = acceptance(problem, agg0.aggregate, tol)
 
     def sweep(carry, sweep_idx):
-        agg, done, moves = carry
+        agg, done, moves, rebuilds = carry
 
-        def threshold(b, source, dest):
-            # ε-gain threshold (arXiv:1305.3354, approximate congestion
-            # games): a configuration is an ε-equilibrium once no player
-            # can improve by more than ε times the per-node average
-            # potential, so the acceptance floor scales with the CARRIED
-            # potential and the loop stops at an ε-Nash point instead of
-            # chasing O(tol) tail gains.  epsilon=0 is statically elided:
-            # the threshold is then exactly the one
-            # _refine_simultaneous compares against, keeping the
-            # degenerate config bitwise.
-            thresh = acceptance_threshold(acc, framework, b, source, dest,
-                                          agg.loads, problem.speeds)
-            if epsilon:
-                pot = agg.c0 if framework == costs.C_FRAMEWORK else agg.ct0
-                thresh = thresh + epsilon * jnp.abs(pot) / n
-            return thresh
+        with jax.named_scope("elect"):
+            def threshold(b, source, dest):
+                # ε-gain threshold (arXiv:1305.3354, approximate congestion
+                # games): a configuration is an ε-equilibrium once no player
+                # can improve by more than ε times the per-node average
+                # potential, so the acceptance floor scales with the CARRIED
+                # potential and the loop stops at an ε-Nash point instead of
+                # chasing O(tol) tail gains.  epsilon=0 is statically elided:
+                # the threshold is then exactly the one
+                # _refine_simultaneous compares against, keeping the
+                # degenerate config bitwise.
+                thresh = acceptance_threshold(acc, framework, b, source, dest,
+                                              agg.loads, problem.speeds)
+                if epsilon:
+                    pot = agg.c0 if framework == costs.C_FRAMEWORK else agg.ct0
+                    thresh = thresh + epsilon * jnp.abs(pot) / n
+                return thresh
 
-        if sweep_fn is not None:
-            # fused election: gains/picks/dests straight off the kernel
-            gains, pick, dest_k = sweep_fn(
-                agg.aggregate, agg.assignment, problem.node_weights,
-                agg.loads, problem.speeds, problem.mu, framework, total_b,
-                theta)
-        else:
-            if dissat_fn is None:
-                cost = costs.cost_matrix_from_aggregate(
+            if sweep_fn is not None:
+                # fused election: gains/picks/dests straight off the kernel
+                gains, pick, dest_k = sweep_fn(
                     agg.aggregate, agg.assignment, problem.node_weights,
-                    agg.loads, problem.speeds, problem.mu, framework,
-                    total_weight=total_b)
-                dissat, best = costs.dissatisfaction_from_cost(
-                    cost, agg.assignment, theta)
+                    agg.loads, problem.speeds, problem.mu, framework, total_b,
+                    theta)
             else:
-                dissat, best = dissat_fn(agg.aggregate, agg.assignment,
-                                         problem.node_weights, agg.loads,
-                                         problem.speeds, problem.mu,
-                                         framework, total_b, theta)
+                if dissat_fn is None:
+                    cost = costs.cost_matrix_from_aggregate(
+                        agg.aggregate, agg.assignment, problem.node_weights,
+                        agg.loads, problem.speeds, problem.mu, framework,
+                        total_weight=total_b)
+                    dissat, best = costs.dissatisfaction_from_cost(
+                        cost, agg.assignment, theta)
+                else:
+                    dissat, best = dissat_fn(agg.aggregate, agg.assignment,
+                                             problem.node_weights, agg.loads,
+                                             problem.speeds, problem.mu,
+                                             framework, total_b, theta)
 
-        if sweep_fn is not None or moves_per_machine == 1:
-            if sweep_fn is None:
-                owned = jax.nn.one_hot(agg.assignment, K,
-                                       dtype=dissat.dtype)           # (N,K)
-                masked = jnp.where(owned.T > 0, dissat[None, :],
-                                   -jnp.inf)                         # (K,N)
-                pick = jnp.argmax(masked, axis=1).astype(jnp.int32)  # (K,)
-                gains = jnp.max(masked, axis=1)
+            if sweep_fn is not None or moves_per_machine == 1:
+                if sweep_fn is None:
+                    owned = jax.nn.one_hot(agg.assignment, K,
+                                           dtype=dissat.dtype)       # (N,K)
+                    masked = jnp.where(owned.T > 0, dissat[None, :],
+                                       -jnp.inf)                     # (K,N)
+                    pick = jnp.argmax(masked, axis=1) \
+                        .astype(jnp.int32)                           # (K,)
+                    gains = jnp.max(masked, axis=1)
+                    dest_k = best[pick]
+                cand = gains > threshold(problem.node_weights[pick],
+                                         jnp.arange(K, dtype=jnp.int32),
+                                         dest_k)                     # (K,)
+            elif moves_per_machine is not None:
+                owned = jax.nn.one_hot(agg.assignment, K, dtype=dissat.dtype)
+                masked = jnp.where(owned.T > 0, dissat[None, :], -jnp.inf)
+                gains, pick = jax.lax.top_k(masked,
+                                            moves_per_machine)       # (K,M)
+                gains = gains.reshape(-1)                            # (K·M,)
+                pick = pick.reshape(-1).astype(jnp.int32)
                 dest_k = best[pick]
-            cand = gains > threshold(problem.node_weights[pick],
-                                     jnp.arange(K, dtype=jnp.int32),
-                                     dest_k)                         # (K,)
-        elif moves_per_machine is not None:
-            owned = jax.nn.one_hot(agg.assignment, K, dtype=dissat.dtype)
-            masked = jnp.where(owned.T > 0, dissat[None, :], -jnp.inf)
-            gains, pick = jax.lax.top_k(masked, moves_per_machine)   # (K,M)
-            gains = gains.reshape(-1)                                # (K·M,)
-            pick = pick.reshape(-1).astype(jnp.int32)
-            dest_k = best[pick]
-            cand = gains > threshold(problem.node_weights[pick],
-                                     agg.assignment[pick], dest_k)
-        else:
-            # unbounded: every node clearing the threshold is a candidate
-            cand = dissat > threshold(problem.node_weights, agg.assignment,
-                                      best)                          # (N,)
-
-        # Probabilistic acceptance (arXiv:cs/0506098, Berenbrink et al.,
-        # distributed selfish load balancing): simultaneous best
-        # responses can overshoot their destinations, so each candidate
-        # migrates only with an independent per-candidate coin.  With
-        # unilateral gains g_i, the accepted set drops the potential by
-        # Σp_i·g_i in expectation while the collision overshoot scales
-        # as Σ_{i≠j sharing a dest} p_i·p_j·b_i·b_j, so E[ΔΦ] < 0
-        # whenever each destination's EXPECTED accepted inflow stays
-        # below its load deficit — the expected-drop bound.  In the
-        # unbounded mode (where overshoot is O(N)-wide) the coin rate is
-        # DERIVED from that bound per candidate:
-        #     p_i = move_prob · min(1, gap_i / W_{d_i}),
-        # gap_i being half the source→destination normalized-load
-        # imbalance (the weight that equalizes the pair) and W_d the
-        # total candidate weight targeting d, so each destination's
-        # expected inflow is at most move_prob · its absorbable weight.
-        # The elected modes (≤ K·M movers) keep the flat ``move_prob``
-        # coin — their overshoot is already bounded by the election.
-        # ``move_prob >= 1`` is statically elided: ``accept`` IS
-        # ``cand`` (same tensor, no PRNG op staged), which is what makes
-        # the degenerate config bitwise-reproduce
-        # :func:`_refine_simultaneous`.
-        if move_prob < 1.0:
-            coin_key = jax.random.fold_in(key, sweep_idx)
-            if sweep_fn is None and moves_per_machine is None:
-                norm = agg.loads / problem.speeds                    # (K,)
-                gap = 0.5 * (norm[agg.assignment] - norm[best]) \
-                    * problem.speeds[best]                           # (N,)
-                w_dest = jax.ops.segment_sum(
-                    jnp.where(cand, problem.node_weights,
-                              jnp.zeros((), dissat.dtype)),
-                    best, num_segments=K)                            # (K,)
-                frac = gap / jnp.maximum(w_dest[best],
-                                         jnp.asarray(1e-30, dissat.dtype))
-                coin = jax.random.bernoulli(
-                    coin_key, move_prob * jnp.clip(frac, 0.0, 1.0))
-                # A candidate whose destination gap is non-positive has
-                # acceptance probability 0 on every future sweep too (its
-                # coin rate only rises if loads change, and loads only
-                # change through moves) — once ALL candidates are in that
-                # state the chain is absorbed, so they must not keep the
-                # convergence test alive.
-                cand = cand & (frac > 0)
+                cand = gains > threshold(problem.node_weights[pick],
+                                         agg.assignment[pick], dest_k)
             else:
-                coin = jax.random.bernoulli(coin_key, move_prob,
-                                            cand.shape)
-            accept = cand & coin
-        else:
-            accept = cand
+                # unbounded: every node clearing the threshold is a
+                # candidate
+                cand = dissat > threshold(problem.node_weights,
+                                          agg.assignment, best)      # (N,)
 
-        any_cand = jnp.any(cand) & ~done
+            # Probabilistic acceptance (arXiv:cs/0506098, Berenbrink et al.,
+            # distributed selfish load balancing): simultaneous best
+            # responses can overshoot their destinations, so each candidate
+            # migrates only with an independent per-candidate coin.  With
+            # unilateral gains g_i, the accepted set drops the potential by
+            # Σp_i·g_i in expectation while the collision overshoot scales
+            # as Σ_{i≠j sharing a dest} p_i·p_j·b_i·b_j, so E[ΔΦ] < 0
+            # whenever each destination's EXPECTED accepted inflow stays
+            # below its load deficit — the expected-drop bound.  In the
+            # unbounded mode (where overshoot is O(N)-wide) the coin rate is
+            # DERIVED from that bound per candidate:
+            #     p_i = move_prob · min(1, gap_i / W_{d_i}),
+            # gap_i being half the source→destination normalized-load
+            # imbalance (the weight that equalizes the pair) and W_d the
+            # total candidate weight targeting d, so each destination's
+            # expected inflow is at most move_prob · its absorbable weight.
+            # The elected modes (≤ K·M movers) keep the flat ``move_prob``
+            # coin — their overshoot is already bounded by the election.
+            # ``move_prob >= 1`` is statically elided: ``accept`` IS
+            # ``cand`` (same tensor, no PRNG op staged), which is what makes
+            # the degenerate config bitwise-reproduce
+            # :func:`_refine_simultaneous`.
+            if move_prob < 1.0:
+                coin_key = jax.random.fold_in(key, sweep_idx)
+                if sweep_fn is None and moves_per_machine is None:
+                    norm = agg.loads / problem.speeds                    # (K,)
+                    gap = 0.5 * (norm[agg.assignment] - norm[best]) \
+                        * problem.speeds[best]                           # (N,)
+                    w_dest = jax.ops.segment_sum(
+                        jnp.where(cand, problem.node_weights,
+                                  jnp.zeros((), dissat.dtype)),
+                        best, num_segments=K)                            # (K,)
+                    frac = gap / jnp.maximum(w_dest[best],
+                                             jnp.asarray(1e-30, dissat.dtype))
+                    coin = jax.random.bernoulli(
+                        coin_key, move_prob * jnp.clip(frac, 0.0, 1.0))
+                    # A candidate whose destination gap is non-positive has
+                    # acceptance probability 0 on every future sweep too (its
+                    # coin rate only rises if loads change, and loads only
+                    # change through moves) — once ALL candidates are in that
+                    # state the chain is absorbed, so they must not keep the
+                    # convergence test alive.
+                    cand = cand & (frac > 0)
+                else:
+                    coin = jax.random.bernoulli(coin_key, move_prob,
+                                                cand.shape)
+                accept = cand & coin
+            else:
+                accept = cand
 
-        if sweep_fn is not None or moves_per_machine == 1:
-            new_agg = agg_mod.apply_sweep(problem, agg, pick, dest_k,
-                                          accept, total_b)
-        elif moves_per_machine is not None:
-            new_agg = agg_mod.apply_moves(problem, agg, pick, dest_k,
-                                          accept, total_b)
-        else:
-            # Unbounded apply: the adaptive coin keeps accepted sets small
-            # after the first sweeps, so gather the movers into a fixed
-            # R-slot buffer and reuse apply_moves' O(R·max_degree·K)
-            # incident-window update; only a sweep whose accepted set
-            # overflows the buffer pays the O(E) from-scratch rebuild
-            # (lax.cond, so the cheap branch is the one executed).
-            r_cap = min(_UNBOUNDED_APPLY_CAP, n)
-            n_acc = jnp.sum(accept.astype(jnp.int32))
-            idx = jnp.nonzero(accept, size=r_cap, fill_value=0)[0] \
-                .astype(jnp.int32)
-            valid = jnp.arange(r_cap) < n_acc
-            new_agg = jax.lax.cond(
-                n_acc <= r_cap,
-                lambda: agg_mod.apply_moves(problem, agg, idx, best[idx],
-                                            valid, total_b),
-                lambda: agg_mod.rebuild_state(
-                    problem, jnp.where(accept, best, agg.assignment),
-                    total_b))
-        new_agg = jax.tree.map(
-            lambda new, old: jnp.where(any_cand, new, old), new_agg, agg)
+            any_cand = jnp.any(cand) & ~done
+
+        with jax.named_scope("apply"):
+            if sweep_fn is not None or moves_per_machine == 1:
+                new_agg = agg_mod.apply_sweep(problem, agg, pick, dest_k,
+                                              accept, total_b)
+            elif moves_per_machine is not None:
+                new_agg = agg_mod.apply_moves(problem, agg, pick, dest_k,
+                                              accept, total_b)
+            else:
+                # Unbounded apply: the adaptive coin keeps accepted sets small
+                # after the first sweeps, so gather the movers into a fixed
+                # R-slot buffer and reuse apply_moves' O(R·max_degree·K)
+                # incident-window update; only a sweep whose accepted set
+                # overflows the buffer pays the O(E) from-scratch rebuild
+                # (lax.cond, so the cheap branch is the one executed).
+                r_cap = min(_UNBOUNDED_APPLY_CAP, n)
+                n_acc = jnp.sum(accept.astype(jnp.int32))
+                idx = jnp.nonzero(accept, size=r_cap, fill_value=0)[0] \
+                    .astype(jnp.int32)
+                valid = jnp.arange(r_cap) < n_acc
+
+                def rebuild():
+                    with jax.named_scope("rebuild"):
+                        return agg_mod.rebuild_state(
+                            problem, jnp.where(accept, best, agg.assignment),
+                            total_b)
+
+                new_agg = jax.lax.cond(
+                    n_acc <= r_cap,
+                    lambda: agg_mod.apply_moves(problem, agg, idx, best[idx],
+                                                valid, total_b),
+                    rebuild)
+                rebuilds = rebuilds + (n_acc > r_cap).astype(jnp.int32)
+            new_agg = jax.tree.map(
+                lambda new, old: jnp.where(any_cand, new, old), new_agg, agg)
         sweep_movers = jnp.where(any_cand,
                                  jnp.sum(accept.astype(jnp.int32)), 0)
         moves = moves + sweep_movers
         out = (new_agg.c0, new_agg.ct0, any_cand)
         if telemetry:
             out = out + (sweep_movers,)
-        return (new_agg, done | ~any_cand, moves), out
+        return (new_agg, done | ~any_cand, moves, rebuilds), out
 
-    (agg, done, moves), outs = jax.lax.scan(
-        sweep, (agg0, jnp.zeros((), bool), jnp.zeros((), jnp.int32)),
+    zero = jnp.zeros((), jnp.int32)
+    (agg, done, moves, rebuilds), outs = jax.lax.scan(
+        sweep, (agg0, jnp.zeros((), bool), zero, zero),
         jnp.arange(max_sweeps, dtype=jnp.int32))
     movers = None
     if telemetry:
@@ -1028,10 +1051,12 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
         assignment=agg.assignment, loads=agg.loads,
         num_moves=moves,
         num_turns=jnp.sum(active.astype(jnp.int32)),
-        converged=done, aggregate_drift=jnp.zeros(()))
+        converged=done, aggregate_drift=jnp.zeros(()),
+        num_rebuilds=rebuilds)
     return result, (c0s, ct0s, active), movers
 
 
+@partial(jax.profiler.annotate_function, name="repro.refine_sweeps")
 def refine_sweeps(problem: PartitionProblem, assignment: Array,
                   framework: str = costs.C_FRAMEWORK,
                   max_sweeps: int = 256, tol: float = DEFAULT_TOL,

@@ -184,31 +184,6 @@ def make_edge_sweep_fn(problem,
     return fn
 
 
-def make_timed_dissat_fn(dissat_fn: DissatFn, recorder,
-                         name: str = "kernels.dissat") -> DissatFn:
-    """Wrap a ``dissat_fn`` with recorder phase timing (DESIGN.md §14.3).
-
-    Eager calls are wall-clocked — ``recorder.phase(name)`` around the
-    call plus ``block_until_ready`` so the span covers device execution,
-    not just dispatch.  Calls made under tracing (any argument a
-    ``Tracer``) pass straight through untimed: inside jit the Python
-    call runs once at trace time and a wall-clock there measures
-    nothing, so the jaxpr stays identical to the unwrapped function's.
-    Follows the same 9-argument ``dissat_fn`` convention as the wrapped
-    callable, so it plugs into ``repro.core.refine(..., dissat_fn=...)``
-    anywhere the original does.
-    """
-    def fn(*args, **kwargs):
-        leaves = jax.tree.leaves((args, kwargs))
-        if any(isinstance(leaf, jax.core.Tracer) for leaf in leaves):
-            return dissat_fn(*args, **kwargs)
-        with recorder.phase(name):
-            out = dissat_fn(*args, **kwargs)
-            jax.block_until_ready(out)
-        return out
-    return fn
-
-
 def make_aggregate_dissat_fn(interpret: bool | None = None) -> DissatFn:
     """Adapter implementing THE ``dissat_fn`` calling convention — see the
     canonical 9-argument spec in :mod:`repro.core.refine` ("The

@@ -6,10 +6,12 @@ assigns run ids, buffers every event in ``self.events`` (the canonical
 in-process stream), fans events out to attached sinks, times wall-clock
 phases, and bridges device→host telemetry.
 
-Host-side only: this module imports numpy and the standard library —
-never JAX.  All potentially-hot device work stays in the instrumented
-modules; what crosses here is either post-run arrays (trace ingestion)
-or the buffered rows of a ``jax.debug.callback`` stream.
+Host-side only: this module imports numpy, the standard library and
+:mod:`repro.obs.spans`, which imports ``jax.profiler`` on first use
+only (``phase`` opens a profiler annotation of its name).  All
+potentially-hot device work stays in the instrumented modules; what
+crosses here is either post-run arrays (trace ingestion) or the
+buffered rows of a ``jax.debug.callback`` stream.
 
 Device-row bridge
 -----------------
@@ -37,6 +39,7 @@ from typing import Iterable, Sequence
 import numpy as np
 
 from .events import make_event
+from .spans import annotate
 
 # Standing accuracy budget for carried quantities (ROADMAP contract).
 DRIFT_BUDGET = 1e-3
@@ -85,7 +88,9 @@ class Recorder:
 
     @contextmanager
     def phase(self, name: str, run: str | None = None):
-        """Wall-clock a span; emits one ``phase`` event on exit.
+        """Wall-clock a span; emits one ``phase`` event on exit.  The span
+        is also a ``jax.profiler.TraceAnnotation`` of ``name``, so it
+        lands on the profiler's clock in any profile taken meanwhile.
 
         If the wrapped block raises (e.g. a jit failure before
         ``block_until_ready``), the span is still closed, a terminal
@@ -94,7 +99,8 @@ class Recorder:
         instead of being lost with the process (DESIGN.md §15.6)."""
         t0 = time.perf_counter()
         try:
-            yield
+            with annotate(name):
+                yield
         except BaseException as exc:
             rid = run or self._last_run or "r----"
             self.emit("phase", rid, name=name, ts=t0,

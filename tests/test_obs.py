@@ -18,7 +18,9 @@ Three properties are load-bearing and pinned here:
 """
 from __future__ import annotations
 
+import importlib
 import json
+import re
 
 import numpy as np
 import pytest
@@ -27,11 +29,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.problem import make_problem
-from repro.core.refine import refine, refine_simultaneous, refine_traced
+from repro.core.refine import (refine, refine_simultaneous, refine_sweeps,
+                               refine_traced)
 from repro.distributed import refine_distributed
 from repro.graphs.generators import random_degree_graph, random_weights
 from repro.obs import (EVENT_KINDS, JsonlSink, MemorySink, Recorder,
-                       chrome_trace, make_event, read_jsonl, validate_event)
+                       chrome_trace, compiles, make_event, read_jsonl,
+                       validate_event)
 from repro.obs.report import check_run, main as report_main, replay_run, \
     split_runs
 
@@ -292,36 +296,127 @@ def test_memory_sink_fanout_and_phase():
     assert rec.events == rec.sinks[0].events
 
 
-def test_timed_dissat_fn_eager_vs_traced(instance):
-    from repro.kernels.ops import make_timed_dissat_fn
+# ---------------------------------------------------------------------------
+# device scopes, the rebuild counter and the compile counter (§14.6)
+# ---------------------------------------------------------------------------
+
+def _op_name_components(hlo_text: str) -> set[str]:
+    names = set()
+    for path in re.findall(r'op_name="([^"]*)"', hlo_text):
+        names.update(path.split("/"))
+    return names
+
+
+def _sparse_instance(n: int = 2048, k: int = 5):
+    from repro.core.sparse import make_sparse_problem
+    from repro.graphs.generators import (random_degree_graph_edges,
+                                         random_weights_edges)
+
+    s, r = random_degree_graph_edges(n, seed=1)
+    b, w = random_weights_edges(n, s, seed=2)
+    sp = make_sparse_problem(s, r, w, b, [0.1, 0.2, 0.3, 0.3, 0.1][:k])
+    r0 = jnp.asarray(np.random.default_rng(3).integers(0, k, n), jnp.int32)
+    return sp, r0
+
+
+UNBOUNDED = dict(max_sweeps=64, moves_per_machine=None, move_prob=0.5,
+                 epsilon=1e-3)
+
+
+def test_sweep_scopes_in_compiled_hlo():
+    refine_mod = importlib.import_module("repro.core.refine")
+    sp, r0 = _sparse_instance(n=256)
+    key = jax.random.PRNGKey(4)
+    text = refine_mod._refine_sweeps.lower(
+        sp, r0, key, "c", **UNBOUNDED).compile().as_text()
+    assert {"refine_sweeps", "init", "elect", "apply", "rebuild"} \
+        <= _op_name_components(text)
+
+
+def test_refine_scopes_in_compiled_hlo(instance):
+    refine_mod = importlib.import_module("repro.core.refine")
+    prob, r0 = instance
+    text = refine_mod._refine.lower(prob, r0, "c").compile().as_text()
+    assert {"refine", "init", "elect", "apply"} \
+        <= _op_name_components(text)
+
+
+@pytest.fixture
+def small_apply_cap(monkeypatch):
+    """A 64-slot mover buffer, so the first unbounded sweeps overflow.
+    The cap is read when the sweep body is traced, so the jit cache is
+    cleared on both sides of the patch."""
+    refine_mod = importlib.import_module("repro.core.refine")
+    refine_mod._refine_sweeps.clear_cache()
+    monkeypatch.setattr(refine_mod, "_UNBOUNDED_APPLY_CAP", 64)
+    yield 64
+    monkeypatch.undo()
+    refine_mod._refine_sweeps.clear_cache()
+
+
+def test_num_rebuilds_counts_overflowing_sweeps(small_apply_cap):
+    refine_mod = importlib.import_module("repro.core.refine")
+    sp, r0 = _sparse_instance()
+    key = jax.random.PRNGKey(4)
+    result, _, movers = refine_mod._refine_sweeps(
+        sp, r0, key, "c", telemetry=True, **UNBOUNDED)
+    overflowed = int((np.asarray(movers) > small_apply_cap).sum())
+    assert overflowed >= 1 and np.asarray(movers)[0] > small_apply_cap
+    assert int(result.num_rebuilds) == overflowed
+    # the public entry (no telemetry side output) counts the same
+    plain, _ = refine_sweeps(sp, r0, key=key, **UNBOUNDED)
+    assert int(plain.num_rebuilds) == overflowed
+    assert _tree_equal(plain, result)
+
+
+def test_num_rebuilds_zero_when_the_buffer_holds():
+    sp, r0 = _sparse_instance(n=256)
+    result, _ = refine_sweeps(sp, r0, key=jax.random.PRNGKey(4), **UNBOUNDED)
+    assert int(result.num_rebuilds) == 0
+
+
+def test_num_rebuilds_zero_outside_the_unbounded_mode(instance,
+                                                      small_apply_cap):
+    prob, r0 = instance
+    sp, s0 = _sparse_instance()
+    one, _ = refine_sweeps(sp, s0, moves_per_machine=1, max_sweeps=64)
+    assert int(one.num_moves) > 0 and int(one.num_rebuilds) == 0
+    turn = refine(prob, r0, "c", max_turns=500)
+    assert int(turn.num_moves) > 0 and int(turn.num_rebuilds) == 0
+
+
+def test_compile_counter_counts_fresh_compiles():
+    x = jnp.arange(7.0)
+    before = compiles()
+    fn = jax.jit(lambda v: v * 3.0 + 1.0)
+    fn(x).block_until_ready()
+    first = compiles()
+    fn(x).block_until_ready()
+    second = compiles()
+    assert first.count - before.count == 1
+    assert first.seconds > before.seconds
+    assert second == first
+
+
+def test_host_spans_land_in_a_profile(instance, tmp_path):
+    """The entry points' and Recorder.phase's spans are on the profiler's
+    host line, beside the device ops of the same profile."""
+    from jax.profiler import ProfileData
 
     prob, r0 = instance
+    refine(prob, r0, "c", max_turns=500)           # compile outside
+    refine_sweeps(prob, r0, max_sweeps=8)
     rec = Recorder()
-    agg = jnp.zeros((N, K), jnp.float32)
-    loads = jnp.zeros(K, jnp.float32).at[r0].add(prob.node_weights)
-
-    def plain_fn(aggregate, assignment, node_weights, loads, speeds, mu,
-                 framework, total_weight, theta=None):
-        del aggregate, framework, theta
-        dissat = loads[assignment] / speeds[assignment]
-        return dissat, jnp.broadcast_to(jnp.argmin(loads), dissat.shape)
-
-    timed_fn = make_timed_dissat_fn(plain_fn, rec, name="unit.dissat")
-
-    def call(fn, loads_arg):
-        return fn(agg, r0, prob.node_weights, loads_arg, prob.speeds,
-                  prob.mu, "c", jnp.sum(prob.node_weights))
-
-    base = call(plain_fn, loads)
-    eager = call(timed_fn, loads)
-    assert _tree_equal(base, eager)
-    assert [e["name"] for e in rec.events if e["kind"] == "phase"] \
-        == ["unit.dissat"]
-
-    # under tracing the wrapper passes straight through: same jaxpr, no
-    # extra phase events
-    before = len(rec.events)
-    jaxpr_timed = str(jax.make_jaxpr(lambda l: call(timed_fn, l))(loads))
-    jaxpr_plain = str(jax.make_jaxpr(lambda l: call(plain_fn, l))(loads))
-    assert jaxpr_timed == jaxpr_plain
-    assert len(rec.events) == before
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(refine(prob, r0, "c", max_turns=500))
+        jax.block_until_ready(refine_sweeps(prob, r0, max_sweeps=8))
+        with rec.phase("unit.span"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    names = {e.name for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             for e in line.events}
+    assert {"repro.refine", "repro.refine_sweeps", "unit.span"} <= names
