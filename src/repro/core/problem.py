@@ -161,8 +161,15 @@ def validate_assignment(assignment, num_machines: int,
 
 
 def machine_loads(node_weights: Array, assignment: Array, num_machines: int) -> Array:
-    """L_k = sum of b_j over nodes assigned to machine k."""
-    return jnp.zeros((num_machines,), node_weights.dtype).at[assignment].add(node_weights)
+    """L_k = sum of b_j over nodes assigned to machine k.
+
+    One masked reduction over the nodes, not a scatter-add: a scatter
+    into K slots adds its N terms one after another, and at a million
+    LPs its float32 error reached tens of load units (on a TPU v5e),
+    enough to let a converged placement miss its ε-equilibrium in
+    float64.  The reduction sums in a tree."""
+    owned = assignment[:, None] == jnp.arange(num_machines)
+    return jnp.sum(jnp.where(owned, node_weights[:, None], 0), axis=0)
 
 
 def make_state(problem: PartitionProblem, assignment) -> PartitionState:

@@ -331,6 +331,11 @@ class RefineResult(NamedTuple):
     # sweeps of the unbounded refine_sweeps mode whose accepted set
     # overflowed the mover buffer and took the O(E·K) rebuild (0 elsewhere)
     num_rebuilds: Array | int = 0
+    # sweeps up to and including the first with no candidate (max_sweeps
+    # when every sweep had one): the sweeps refine_sweeps runs before it
+    # stops.  refine_simultaneous reports the same count but still scans
+    # all max_sweeps (0 outside the sweep modes)
+    num_sweeps: Array | int = 0
 
 
 @partial(jax.jit, static_argnames=("framework", "max_turns", "cost_matrix_fn",
@@ -759,11 +764,12 @@ def _refine_simultaneous(problem: PartitionProblem, assignment: Array,
         c0s, ct0s, active, movers = outs
     else:
         c0s, ct0s, active = outs
+    num_turns = jnp.sum(active.astype(jnp.int32))
     result = RefineResult(
         assignment=agg.assignment, loads=agg.loads,
-        num_moves=moves,
-        num_turns=jnp.sum(active.astype(jnp.int32)),
-        converged=done, aggregate_drift=jnp.zeros(()))
+        num_moves=moves, num_turns=num_turns,
+        converged=done, aggregate_drift=jnp.zeros(()),
+        num_sweeps=jnp.where(done, num_turns + 1, max_sweeps))
     return result, (c0s, ct0s, active), movers
 
 
@@ -860,11 +866,17 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
                    theta=None, moves_per_machine: int | None = 1,
                    move_prob: float = 1.0, epsilon: float = 0.0,
                    dissat_fn=None, sweep_fn=None, telemetry: bool = False):
-    """Jitted scan body of :func:`refine_sweeps`.
+    """Jitted sweep loop of :func:`refine_sweeps`: a ``lax.while_loop``
+    that stops after the first sweep with no candidate, or after
+    ``max_sweeps`` sweeps.
 
     Returns ``(RefineResult, (c0s, ct0s, active), movers)`` exactly like
     :func:`_refine_simultaneous` (``movers`` is ``None`` unless
     ``telemetry=True``; the default jaxpr is the pre-telemetry program).
+    The per-sweep outputs keep length ``max_sweeps``: the slots of the
+    sweeps not run hold what a sweep after convergence writes (the final
+    potentials, ``active`` false, no movers), so they equal the outputs of
+    a loop that runs all ``max_sweeps``.
     """
     K = problem.num_machines
     n = problem.num_nodes
@@ -1038,21 +1050,40 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
             out = out + (sweep_movers,)
         return (new_agg, done | ~any_cand, moves, rebuilds), out
 
+    # Stopping after the first sweep with no candidate changes no result:
+    # every later sweep would find the same empty set and keep the state.
+    # Each sweep writes its slot of the (max_sweeps,) buffers; the slots of
+    # the sweeps not run are filled after the loop.
     zero = jnp.zeros((), jnp.int32)
-    (agg, done, moves, rebuilds), outs = jax.lax.scan(
-        sweep, (agg0, jnp.zeros((), bool), zero, zero),
-        jnp.arange(max_sweeps, dtype=jnp.int32))
-    movers = None
+    bufs = (jnp.zeros((max_sweeps,), agg0.c0.dtype),
+            jnp.zeros((max_sweeps,), agg0.ct0.dtype),
+            jnp.zeros((max_sweeps,), bool))
     if telemetry:
-        c0s, ct0s, active, movers = outs
-    else:
-        c0s, ct0s, active = outs
+        bufs = bufs + (jnp.zeros((max_sweeps,), jnp.int32),)
+
+    def cond(carry):
+        done, sweep_idx = carry[1], carry[4]
+        return ~done & (sweep_idx < max_sweeps)
+
+    def body(carry):
+        state, sweep_idx, bufs = carry[:4], carry[4], carry[5]
+        state, out = sweep(state, sweep_idx)
+        bufs = tuple(buf.at[sweep_idx].set(o) for buf, o in zip(bufs, out))
+        return (*state, sweep_idx + 1, bufs)
+
+    agg, done, moves, rebuilds, num_sweeps, bufs = jax.lax.while_loop(
+        cond, body, (agg0, jnp.zeros((), bool), zero, zero, zero, bufs))
+    ran = jnp.arange(max_sweeps) < num_sweeps
+    c0s = jnp.where(ran, bufs[0], agg.c0)
+    ct0s = jnp.where(ran, bufs[1], agg.ct0)
+    active = bufs[2]
+    movers = bufs[3] if telemetry else None
     result = RefineResult(
         assignment=agg.assignment, loads=agg.loads,
         num_moves=moves,
         num_turns=jnp.sum(active.astype(jnp.int32)),
         converged=done, aggregate_drift=jnp.zeros(()),
-        num_rebuilds=rebuilds)
+        num_rebuilds=rebuilds, num_sweeps=num_sweeps)
     return result, (c0s, ct0s, active), movers
 
 
@@ -1114,9 +1145,18 @@ def refine_sweeps(problem: PartitionProblem, assignment: Array,
     (:class:`SweepCandidateFn`) fuses the per-machine election into the
     kernel epilogue itself (``moves_per_machine=1`` only).
 
+    The loop stops after the first sweep with no candidate: every later
+    sweep would find none either and leave the state unchanged, so
+    ``max_sweeps`` is only a cap.  ``RefineResult.num_sweeps`` counts
+    the sweeps run: ``num_turns + 1`` when converged before the cap,
+    else ``max_sweeps``.
+
     Returns ``(RefineResult, (c0s, ct0s, active))`` like
-    :func:`refine_simultaneous`; ``recorder`` opts into the identical
-    telemetry shape (per-sweep potentials + movers).
+    :func:`refine_simultaneous`, each per-sweep output of length
+    ``max_sweeps``: after the last sweep run, ``c0s``/``ct0s`` repeat
+    the final potentials and ``active`` is false.  ``recorder`` opts
+    into the identical telemetry shape (per-sweep potentials + movers,
+    and ``num_sweeps`` on ``run_end``).
     """
     if move_prob < 1.0 and key is None:
         raise ValueError("refine_sweeps(move_prob < 1) needs a PRNG `key` "

@@ -75,8 +75,10 @@ Kinds
     is the exception's ``repr``.
 ``run_end``
     Closes a run with the final counters and, when available, final
-    potentials and loads.  Fault-injected runs add ``recovered`` and
-    ``recovery_drift`` (the recover-or-raise verdict, DESIGN.md §15).
+    potentials and loads.  Sweep runs add ``num_sweeps`` (the sweeps run
+    before the loop stopped, ``RefineResult.num_sweeps``).  Fault-injected
+    runs add ``recovered`` and ``recovery_drift`` (the recover-or-raise
+    verdict, DESIGN.md §15).
 """
 from __future__ import annotations
 

@@ -272,7 +272,8 @@ class Recorder:
         """Emit the ``drift`` check and the closing ``run_end`` event.
 
         ``result`` is any ``RefineResult``-shaped object (duck-typed:
-        ``num_moves/num_turns/converged/loads/aggregate_drift``).
+        ``num_moves/num_turns/converged/loads/aggregate_drift``, and
+        ``num_sweeps`` where a sweep runtime sets it).
         ``extra`` fields ride on the ``run_end`` verbatim — fault-
         injected runs attach ``recovered``/``recovery_drift``
         (DESIGN.md §15.6)."""
@@ -284,6 +285,9 @@ class Recorder:
                       converged=bool(np.asarray(result.converged)),
                       loads=np.asarray(result.loads),
                       aggregate_drift=drift)
+        num_sweeps = int(np.asarray(getattr(result, "num_sweeps", 0)))
+        if num_sweeps:
+            fields["num_sweeps"] = num_sweeps
         if wall is not None:
             fields["wall"] = float(wall)
         if c0 is not None:

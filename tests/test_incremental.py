@@ -13,6 +13,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import aggregate as agg_mod
@@ -187,6 +188,21 @@ def test_cut_from_aggregate_identity():
     np.testing.assert_allclose(
         float(agg_mod.cut_from_aggregate(agg, r)),
         float(costs.total_cut(prob.adjacency, r)), rtol=1e-5)
+
+
+def test_machine_loads_float32_error_stays_small_at_a_million_nodes():
+    """A million float32 weights summed into five loads: a serial
+    scatter-add drifts by tens of load units here, enough to move a
+    node's float32 gain past the ε-equilibrium allowance; the loads must
+    stay within one load unit of float64."""
+    rng = np.random.default_rng(0)
+    n, k = 1 << 20, 5
+    b = rng.uniform(0.0, 10.0, n).astype(np.float32)
+    r = rng.integers(0, k, n).astype(np.int32)
+    exact = np.bincount(r, b.astype(np.float64), minlength=k)
+    loads = np.asarray(jax.jit(machine_loads, static_argnums=2)(b, r, k),
+                       np.float64)
+    assert np.abs(loads - exact).max() < 1.0
 
 
 def test_potentials_closed_form_matches_global():

@@ -385,6 +385,20 @@ def test_num_rebuilds_zero_outside_the_unbounded_mode(instance,
     assert int(turn.num_moves) > 0 and int(turn.num_rebuilds) == 0
 
 
+def test_run_end_carries_num_sweeps(instance):
+    prob, r0 = instance
+    sp, s0 = _sparse_instance(n=256)
+    rec = Recorder()
+    result, _ = refine_sweeps(sp, s0, key=jax.random.PRNGKey(4),
+                              recorder=rec, **dict(UNBOUNDED, max_sweeps=256))
+    end = [e for e in rec.events if e["kind"] == "run_end"][-1]
+    assert end["num_sweeps"] == int(result.num_sweeps) \
+        == int(result.num_turns) + 1
+    refine(prob, r0, "c", max_turns=500, recorder=rec)
+    end = [e for e in rec.events if e["kind"] == "run_end"][-1]
+    assert "num_sweeps" not in end
+
+
 def test_compile_counter_counts_fresh_compiles():
     x = jnp.arange(7.0)
     before = compiles()

@@ -247,6 +247,50 @@ def test_refine_sweeps_batched_requires_keys():
         refine_sweeps_batched(stacked, jnp.stack(r0s), "c", move_prob=0.5)
 
 
+@pytest.mark.parametrize("moves_per_machine", [None, 1, 4],
+                         ids=["unbounded", "m1", "m4"])
+def test_refine_sweeps_stops_at_convergence(moves_per_machine):
+    """The sweep loop stops after the first sweep with no candidate.  Its
+    per-sweep outputs keep length max_sweeps, padded with what the sweeps
+    not run would write (the final potentials, inactive), so a run capped
+    at exactly the sweeps it needs gives the same result, and each batch
+    element stops at its own sweep."""
+    problems, r0s = _mixed_problems(3, seed0=110)
+    keys = jax.random.split(jax.random.PRNGKey(110), 3)
+    kw = dict(moves_per_machine=moves_per_machine, move_prob=0.5,
+              epsilon=1e-3)
+    res_b, _ = refine_sweeps_batched(stack_problems(problems),
+                                     jnp.stack(r0s), "c", max_sweeps=256,
+                                     keys=keys, **kw)
+    for i, (prob, r0, key) in enumerate(zip(problems, r0s, keys)):
+        res, outs = refine_sweeps(prob, r0, "c", max_sweeps=256, key=key,
+                                  **kw)
+        turns, ran = int(res.num_turns), int(res.num_sweeps)
+        assert bool(res.converged)
+        assert ran == turns + 1 < 256
+        assert int(np.asarray(res_b.num_sweeps)[i]) == ran
+        c0s, ct0s, active = (np.asarray(o) for o in outs)
+        assert c0s.shape == ct0s.shape == active.shape == (256,)
+        assert active[:turns].all() and not active[turns:].any()
+
+        exact, exact_outs = refine_sweeps(prob, r0, "c", max_sweeps=ran,
+                                          key=key, **kw)
+        assert int(exact.num_sweeps) == ran and bool(exact.converged)
+        for name in ("assignment", "loads", "num_moves", "num_turns"):
+            np.testing.assert_array_equal(np.asarray(getattr(res, name)),
+                                          np.asarray(getattr(exact, name)),
+                                          err_msg=name)
+        for full, short in zip((c0s, ct0s, active), exact_outs):
+            np.testing.assert_array_equal(full[:ran], np.asarray(short))
+        for pots in (c0s, ct0s):      # the final carried value, repeated
+            np.testing.assert_array_equal(pots[ran - 1:], pots[ran - 1])
+
+        capped, capped_outs = refine_sweeps(prob, r0, "c", max_sweeps=3,
+                                            key=key, **kw)
+        assert int(capped.num_sweeps) == 3 and not bool(capped.converged)
+        np.testing.assert_array_equal(np.asarray(capped_outs[0]), c0s[:3])
+
+
 # ---------------------------------------------------------------------------
 # the SweepSpec -> SweepResult runtime
 # ---------------------------------------------------------------------------
