@@ -300,6 +300,7 @@ _REGISTERED_ARMS = frozenset({
     ("src/repro/core/aggregate.py", "apply_sweep"),
     ("src/repro/core/aggregate.py", "apply_moves"),
     ("src/repro/core/aggregate.py", "apply_cluster_move"),
+    ("src/repro/core/refine.py", "_refine_sweeps"),
     ("src/repro/core/cluster.py", "h_hop_mask"),
     ("src/repro/core/batch.py", "problem_shape_key"),
     ("src/repro/core/reference.py", "host_aggregate"),

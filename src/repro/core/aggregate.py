@@ -49,6 +49,7 @@ bounding the error for arbitrarily long runs.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -285,6 +286,161 @@ def apply_moves(problem: AnyProblem, agg: AggregateState, nodes: Array,
                           aggregate=new_aggregate, c0=c0, ct0=ct0)
 
 
+# A slot of the unbounded sweep apply's edge buffer (DESIGN.md §17.5)
+# costs a few gathers, a K-wide row update and a few passes of the buffer:
+# about three times what the O(E) rebuild pays per edge, one scatter update
+# and one gather (about 19 ns an edge on a TPU v5e, PERF.md).  So past
+# E / REBUILD_CROSSOVER movers' edges the rebuild is the cheaper apply.
+REBUILD_CROSSOVER = 4
+EDGE_BUFFER_COUNT = 5       # edge buffers below the crossover, a factor
+EDGE_BUFFER_MIN = 1024      # of four apart, none smaller than this
+_LANES = 128
+
+
+def edge_buffer_sizes(num_edges: int) -> tuple[int, ...]:
+    """The static edge-slot buffers of :func:`apply_mover_edges`,
+    ascending: the largest is the power of two at or below
+    ``num_edges / REBUILD_CROSSOVER``, each smaller one a quarter of the
+    next, none under ``EDGE_BUFFER_MIN``."""
+    top = max(num_edges // REBUILD_CROSSOVER, EDGE_BUFFER_MIN)
+    top = 1 << (top.bit_length() - 1)
+    return tuple(sorted({max(top >> 2 * i, EDGE_BUFFER_MIN)
+                         for i in range(EDGE_BUFFER_COUNT)}))
+
+
+def _scan(x: Array, op, identity) -> Array:
+    """Inclusive scan of ``x`` along axis 0 by log-step shifts in rows of
+    128, then of the row totals the same way.  Only elementwise ops: the
+    TPU compiler takes seconds for it where ``jnp.cumsum`` or
+    ``lax.cummax`` over ~10^6 elements (a ``reduce_window``) takes half
+    a minute.  Exact for integer ``op``."""
+    n = x.shape[0]
+    pad = -n % _LANES
+    fill = jnp.full((pad,) + x.shape[1:], identity, x.dtype)
+    rows = jnp.concatenate([x, fill]).reshape((-1, _LANES) + x.shape[1:])
+    shift = 1
+    while shift < _LANES:
+        head = jnp.full((rows.shape[0], shift) + x.shape[1:], identity,
+                        x.dtype)
+        rows = op(rows, jnp.concatenate([head, rows[:, :-shift]], axis=1))
+        shift *= 2
+    if rows.shape[0] > 1:
+        before = _scan(rows[:, -1], op, identity)[:-1]
+        rows = rows.at[1:].set(op(rows[1:], before[:, None]))
+    return rows.reshape((-1,) + x.shape[1:])[:n]
+
+
+def _compact(keep: Array, size: int) -> Array:
+    """The first ``size`` indices where ``keep``, ascending, ``n`` past
+    the last (``jnp.nonzero(keep, size=size, fill_value=n)``).  Each row
+    of 128 is sorted on its own, then written at its offset by one
+    128-wide window per row, the windows' overlaps resolved by a minimum:
+    no sort or scatter as long as ``keep``, which the TPU runs
+    element by element."""
+    n = keep.shape[0]
+    i32 = jnp.int32
+    ids = jnp.where(keep, jnp.arange(n, dtype=i32), n)
+    ids = jnp.concatenate([ids, jnp.full((-n % _LANES,), n, i32)])
+    rows = jnp.sort(ids.reshape(-1, _LANES), axis=1)
+    counts = jnp.sum((rows < n).astype(i32), axis=1)
+    starts = _scan(counts, jnp.add, 0) - counts
+    windows = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(),
+        scatter_dims_to_operand_dims=(0,))
+    out = jax.lax.scatter_min(
+        jnp.full((size + _LANES,), n, i32), starts[:, None], rows, windows,
+        indices_are_sorted=True, mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+    return out[:size]
+
+
+def apply_mover_edges(problem: SparseProblem, agg: AggregateState,
+                      accept: Array, dests: Array, total_weight: Array
+                      ) -> tuple[AggregateState, Array]:
+    """Apply every accepted move of an unbounded sweep (DESIGN.md §17.5):
+    node ``i`` migrates to ``dests[i]`` wherever ``accept[i]``.
+
+    The movers' real incident edges are compacted into one buffer, and
+    each edge (mover j, neighbour v, weight w) adds ``-w`` at
+    ``A[v, r_j]`` and ``+w`` at ``A[v, dests[j]]``: one K-wide row update
+    per edge.  The buffer is the smallest of :func:`edge_buffer_sizes`
+    that holds the movers' D edges, picked by a ``lax.switch``; when D
+    exceeds the largest, the aggregate is rebuilt from scratch
+    (:func:`rebuild_state`) instead.
+
+    The movers are compacted in ascending id order; each marks its first
+    edge slot with its slab offset and machines, and running maxima
+    carry those to the rest of its slots, so the slots run
+    mover-ascending, then in CSR order.  Every aggregate entry therefore
+    receives the same non-zero addends, in the same order, as
+    :func:`apply_moves` given the same movers ascending; the addends that
+    differ are exact ``±0.0``.  Slots past D carry weight 0.
+
+    Returns ``(state, slots)``: ``slots`` is the size of the edge buffer
+    used, 0 when the sweep rebuilt.
+    """
+    n, k, e = problem.num_nodes, problem.num_machines, problem.num_edges
+    b = problem.node_weights
+    dt = agg.aggregate.dtype
+    i32 = jnp.int32
+    ends = jnp.concatenate([problem.row_start[1:], jnp.full((1,), e, i32)])
+    degree = jnp.minimum(ends - problem.row_start, problem.max_degree)
+    moving = accept & (degree > 0)
+    total = jnp.sum(jnp.where(moving, degree, 0))
+    new_assignment = jnp.where(accept, dests, agg.assignment).astype(i32)
+    sizes = edge_buffer_sizes(e)
+    # a mover takes at least one slot, so a buffer of B slots has at most
+    # B movers
+    movers = _compact(moving, min(sizes[-1], n))
+    per_node = jnp.stack([degree, problem.row_start, agg.assignment, dests],
+                         axis=1)
+    per_edge = jnp.stack([problem.receivers, jax.lax.bitcast_convert_type(
+        problem.edge_weights, i32)], axis=1)
+    kidx = jnp.arange(k, dtype=i32)
+
+    def incremental(slots):
+        r = min(slots, n)
+        mover = movers[:r]
+        real = mover < n
+        deg, start, src, dst = per_node.at[jnp.minimum(mover, n - 1)].get(
+            indices_are_sorted=True).T
+        deg = jnp.where(real, deg, 0)
+        first = _scan(deg, jnp.add, 0) - deg          # mover's first slot
+        rank = jnp.arange(r, dtype=i32) * k
+        # slab offset (edge id = offset + slot) and machines, each
+        # non-decreasing over the movers, so a running max fills them in
+        at = jnp.where(real, jnp.minimum(first, slots), slots)  # sorted
+        marks = jnp.zeros((slots, 3), i32).at[at].max(
+            jnp.stack([start - first, rank + src, rank + dst], axis=1),
+            mode="drop", indices_are_sorted=True)
+        offset, src, dst = _scan(marks, jnp.maximum, 0).T
+        t = jnp.arange(slots, dtype=i32)
+        nbr, w = per_edge.at[jnp.minimum(offset + t, e - 1)].get(
+            indices_are_sorted=True).T
+        w = jnp.where(t < total, jax.lax.bitcast_convert_type(w, dt),
+                      jnp.zeros((), dt))
+        delta = (kidx == dst[:, None] % k).astype(dt) \
+            - (kidx == src[:, None] % k).astype(dt)
+        return agg.aggregate.at[nbr].add(w[:, None] * delta)
+
+    def rebuild():
+        with jax.named_scope("rebuild"):
+            return rebuild_state(problem, new_assignment,
+                                 total_weight).aggregate
+
+    branch = jnp.sum((total > jnp.asarray(sizes)).astype(i32))
+    new_aggregate = jax.lax.switch(
+        branch, [partial(incremental, s) for s in sizes] + [rebuild])
+    new_loads = machine_loads(b, new_assignment, k)
+    sq_loads = machine_loads(b * b, new_assignment, k)
+    cut = cut_from_aggregate(new_aggregate, new_assignment)
+    c0, ct0 = potentials_closed_form(new_loads, sq_loads, cut,
+                                     problem.speeds, problem.mu,
+                                     total_weight)
+    state = AggregateState(assignment=new_assignment, loads=new_loads,
+                           aggregate=new_aggregate, c0=c0, ct0=ct0)
+    return state, jnp.asarray((*sizes, 0), i32)[branch]
+
+
 def apply_cluster_move(problem: AnyProblem, agg: AggregateState, mask: Array,
                        source: Array, dest: Array, do_move: Array,
                        total_weight: Array) -> AggregateState:
@@ -335,10 +491,10 @@ def rebuild_state(problem: AnyProblem, assignment: Array,
     Ct_0 come from :func:`repro.core.costs.potentials_closed_form` over
     (loads, sq_loads, cut) — O(E·K) + O(K) total — instead of the
     representation-dispatched global passes.  This is the overflow path
-    of the unbounded multi-move mode (DESIGN.md §17): when a sweep's
-    accepted set outgrows the mover buffer the rank-R scatter would be
-    O(N)-wide, and a from-scratch rebuild is both cheaper and drift-free
-    by construction.
+    of the unbounded multi-move mode (DESIGN.md §17.5): when a sweep's
+    movers' edges outgrow the largest edge buffer of
+    :func:`apply_mover_edges`, a from-scratch rebuild is both cheaper
+    and drift-free by construction.
     """
     assignment = jnp.asarray(assignment, jnp.int32)
     k = problem.num_machines

@@ -109,6 +109,7 @@ from . import aggregate as agg_mod
 from . import checkpoint as ckpt_mod
 from . import costs
 from .problem import PartitionProblem, PartitionState, make_state
+from .sparse import SparseProblem
 
 Array = jax.Array
 
@@ -193,12 +194,6 @@ def acceptance_threshold(acc: Acceptance, framework: str, b: Array,
         return load + acc.cut_scale
     scale = jnp.maximum(magnitude(source), magnitude(dest))
     return acc.tol + ROUNDOFF_ULPS * jnp.spacing(scale)
-
-# Mover-buffer slots for the unbounded sweep apply (DESIGN.md §17): sets
-# up to this size update through apply_moves' incident windows; larger
-# sets fall back to the O(E) rebuild.
-_UNBOUNDED_APPLY_CAP = 4096
-
 
 class TurnResult(NamedTuple):
     moved: Array          # bool   — did this turn transfer a node?
@@ -328,14 +323,17 @@ class RefineResult(NamedTuple):
     # max deviation observed at verify_every cross-checks (0 when disabled
     # or on the recompute path — there is nothing to drift there)
     aggregate_drift: Array | float = 0.0
-    # sweeps of the unbounded refine_sweeps mode whose accepted set
-    # overflowed the mover buffer and took the O(E·K) rebuild (0 elsewhere)
+    # sweeps of the unbounded refine_sweeps mode whose movers' edges
+    # outgrew the largest edge buffer and took the O(E) rebuild (0 elsewhere)
     num_rebuilds: Array | int = 0
     # sweeps up to and including the first with no candidate (max_sweeps
     # when every sweep had one): the sweeps refine_sweeps runs before it
     # stops.  refine_simultaneous reports the same count but still scans
     # all max_sweeps (0 outside the sweep modes)
     num_sweeps: Array | int = 0
+    # edge slots the unbounded sparse refine_sweeps apply scattered: the sum
+    # of the edge buffers its sweeps took (0 elsewhere)
+    apply_slots: Array | int = 0
 
 
 @partial(jax.jit, static_argnames=("framework", "max_turns", "cost_matrix_fn",
@@ -886,8 +884,10 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
         total_b = jnp.sum(problem.node_weights)
         acc = acceptance(problem, agg0.aggregate, tol)
 
+    sparse = isinstance(problem, SparseProblem)
+
     def sweep(carry, sweep_idx):
-        agg, done, moves, rebuilds = carry
+        agg, done, moves, rebuilds, slots = carry
 
         with jax.named_scope("elect"):
             def threshold(b, source, dest):
@@ -1015,31 +1015,20 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
             elif moves_per_machine is not None:
                 new_agg = agg_mod.apply_moves(problem, agg, pick, dest_k,
                                               accept, total_b)
+            elif sparse:
+                # Unbounded apply: the movers' incident edges, in the
+                # smallest edge buffer that holds them, or the O(E) rebuild
+                new_agg, took = agg_mod.apply_mover_edges(
+                    problem, agg, accept, best, total_b)
+                rebuilds = rebuilds + (took == 0).astype(jnp.int32)
+                slots = slots + took
             else:
-                # Unbounded apply: the adaptive coin keeps accepted sets small
-                # after the first sweeps, so gather the movers into a fixed
-                # R-slot buffer and reuse apply_moves' O(R·max_degree·K)
-                # incident-window update; only a sweep whose accepted set
-                # overflows the buffer pays the O(E) from-scratch rebuild
-                # (lax.cond, so the cheap branch is the one executed).
-                r_cap = min(_UNBOUNDED_APPLY_CAP, n)
+                # dense unbounded apply: all N slots, one matmul
                 n_acc = jnp.sum(accept.astype(jnp.int32))
-                idx = jnp.nonzero(accept, size=r_cap, fill_value=0)[0] \
+                idx = jnp.nonzero(accept, size=n, fill_value=0)[0] \
                     .astype(jnp.int32)
-                valid = jnp.arange(r_cap) < n_acc
-
-                def rebuild():
-                    with jax.named_scope("rebuild"):
-                        return agg_mod.rebuild_state(
-                            problem, jnp.where(accept, best, agg.assignment),
-                            total_b)
-
-                new_agg = jax.lax.cond(
-                    n_acc <= r_cap,
-                    lambda: agg_mod.apply_moves(problem, agg, idx, best[idx],
-                                                valid, total_b),
-                    rebuild)
-                rebuilds = rebuilds + (n_acc > r_cap).astype(jnp.int32)
+                new_agg = agg_mod.apply_moves(problem, agg, idx, best[idx],
+                                              jnp.arange(n) < n_acc, total_b)
             new_agg = jax.tree.map(
                 lambda new, old: jnp.where(any_cand, new, old), new_agg, agg)
         sweep_movers = jnp.where(any_cand,
@@ -1048,7 +1037,7 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
         out = (new_agg.c0, new_agg.ct0, any_cand)
         if telemetry:
             out = out + (sweep_movers,)
-        return (new_agg, done | ~any_cand, moves, rebuilds), out
+        return (new_agg, done | ~any_cand, moves, rebuilds, slots), out
 
     # Stopping after the first sweep with no candidate changes no result:
     # every later sweep would find the same empty set and keep the state.
@@ -1062,17 +1051,18 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
         bufs = bufs + (jnp.zeros((max_sweeps,), jnp.int32),)
 
     def cond(carry):
-        done, sweep_idx = carry[1], carry[4]
+        done, sweep_idx = carry[1], carry[5]
         return ~done & (sweep_idx < max_sweeps)
 
     def body(carry):
-        state, sweep_idx, bufs = carry[:4], carry[4], carry[5]
+        state, sweep_idx, bufs = carry[:5], carry[5], carry[6]
         state, out = sweep(state, sweep_idx)
         bufs = tuple(buf.at[sweep_idx].set(o) for buf, o in zip(bufs, out))
         return (*state, sweep_idx + 1, bufs)
 
-    agg, done, moves, rebuilds, num_sweeps, bufs = jax.lax.while_loop(
-        cond, body, (agg0, jnp.zeros((), bool), zero, zero, zero, bufs))
+    agg, done, moves, rebuilds, slots, num_sweeps, bufs = jax.lax.while_loop(
+        cond, body,
+        (agg0, jnp.zeros((), bool), zero, zero, zero, zero, bufs))
     ran = jnp.arange(max_sweeps) < num_sweeps
     c0s = jnp.where(ran, bufs[0], agg.c0)
     ct0s = jnp.where(ran, bufs[1], agg.ct0)
@@ -1083,7 +1073,7 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
         num_moves=moves,
         num_turns=jnp.sum(active.astype(jnp.int32)),
         converged=done, aggregate_drift=jnp.zeros(()),
-        num_rebuilds=rebuilds, num_sweeps=num_sweeps)
+        num_rebuilds=rebuilds, num_sweeps=num_sweeps, apply_slots=slots)
     return result, (c0s, ct0s, active), movers
 
 
@@ -1107,13 +1097,15 @@ def refine_sweeps(problem: PartitionProblem, assignment: Array,
         applied as one rank-K·M update
         (:func:`repro.core.aggregate.apply_moves`);
       * ``None`` — unbounded: EVERY node whose net dissatisfaction
-        clears the threshold migrates to its best response.  Accepted
-        sets are gathered into a fixed mover buffer and applied through
-        :func:`repro.core.aggregate.apply_moves`' incident-edge windows
-        (O(R·max_degree·K) per sweep); a sweep whose accepted set
-        overflows the buffer falls back to the drift-free O(E·K) rebuild
-        (:func:`repro.core.aggregate.rebuild_state`) — the
-        million-node-in-seconds mode of ROADMAP item 1.
+        clears the threshold migrates to its best response.  On a sparse
+        problem the movers' incident edges are compacted into the
+        smallest static edge buffer that holds them and applied at two
+        element updates per edge
+        (:func:`repro.core.aggregate.apply_mover_edges`); a sweep whose
+        movers' edges outgrow the largest buffer (a quarter of E) takes
+        the drift-free O(E) rebuild instead — the million-node-in-seconds
+        mode of ROADMAP item 1.  A dense problem applies all movers as one
+        (N, N) @ (N, K) matmul (:func:`repro.core.aggregate.apply_moves`).
 
     ``move_prob < 1`` then thins the candidates with independent coins:
     a flat ``move_prob`` rate in the elected modes, and in the
@@ -1156,7 +1148,7 @@ def refine_sweeps(problem: PartitionProblem, assignment: Array,
     ``max_sweeps``: after the last sweep run, ``c0s``/``ct0s`` repeat
     the final potentials and ``active`` is false.  ``recorder`` opts
     into the identical telemetry shape (per-sweep potentials + movers,
-    and ``num_sweeps`` on ``run_end``).
+    and ``num_sweeps`` and ``apply_slots`` on ``run_end``).
     """
     if move_prob < 1.0 and key is None:
         raise ValueError("refine_sweeps(move_prob < 1) needs a PRNG `key` "

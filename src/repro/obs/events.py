@@ -76,7 +76,9 @@ Kinds
 ``run_end``
     Closes a run with the final counters and, when available, final
     potentials and loads.  Sweep runs add ``num_sweeps`` (the sweeps run
-    before the loop stopped, ``RefineResult.num_sweeps``).  Fault-injected
+    before the loop stopped, ``RefineResult.num_sweeps``), and unbounded
+    sparse ones ``apply_slots`` (the edge slots the move apply scattered,
+    ``RefineResult.apply_slots``).  Fault-injected
     runs add ``recovered`` and ``recovery_drift`` (the recover-or-raise
     verdict, DESIGN.md §15).
 """

@@ -273,7 +273,8 @@ class Recorder:
 
         ``result`` is any ``RefineResult``-shaped object (duck-typed:
         ``num_moves/num_turns/converged/loads/aggregate_drift``, and
-        ``num_sweeps`` where a sweep runtime sets it).
+        ``num_sweeps`` and ``apply_slots`` where a sweep runtime sets
+        them).
         ``extra`` fields ride on the ``run_end`` verbatim — fault-
         injected runs attach ``recovered``/``recovery_drift``
         (DESIGN.md §15.6)."""
@@ -285,9 +286,10 @@ class Recorder:
                       converged=bool(np.asarray(result.converged)),
                       loads=np.asarray(result.loads),
                       aggregate_drift=drift)
-        num_sweeps = int(np.asarray(getattr(result, "num_sweeps", 0)))
-        if num_sweeps:
-            fields["num_sweeps"] = num_sweeps
+        for counter in ("num_sweeps", "apply_slots"):
+            value = int(np.asarray(getattr(result, counter, 0)))
+            if value:
+                fields[counter] = value
         if wall is not None:
             fields["wall"] = float(wall)
         if c0 is not None:

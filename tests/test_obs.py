@@ -342,30 +342,41 @@ def test_refine_scopes_in_compiled_hlo(instance):
 
 
 @pytest.fixture
-def small_apply_cap(monkeypatch):
-    """A 64-slot mover buffer, so the first unbounded sweeps overflow.
-    The cap is read when the sweep body is traced, so the jit cache is
-    cleared on both sides of the patch."""
-    refine_mod = importlib.import_module("repro.core.refine")
-    refine_mod._refine_sweeps.clear_cache()
-    monkeypatch.setattr(refine_mod, "_UNBOUNDED_APPLY_CAP", 64)
-    yield 64
-    monkeypatch.undo()
-    refine_mod._refine_sweeps.clear_cache()
+def overflowing_start():
+    """Every LP of the 2,048-LP instance on one machine: about half of
+    them move in the first unbounded sweep, whose edges then outgrow the
+    largest edge buffer (a quarter of E), so that sweep rebuilds."""
+    sp, _ = _sparse_instance()
+    return sp, jnp.zeros((sp.num_nodes,), jnp.int32)
 
 
-def test_num_rebuilds_counts_overflowing_sweeps(small_apply_cap):
+def test_num_rebuilds_counts_overflowing_sweeps(overflowing_start):
     refine_mod = importlib.import_module("repro.core.refine")
-    sp, r0 = _sparse_instance()
+    from repro.core.aggregate import edge_buffer_sizes
+
+    sp, r0 = overflowing_start
     key = jax.random.PRNGKey(4)
     result, _, movers = refine_mod._refine_sweeps(
         sp, r0, key, "c", telemetry=True, **UNBOUNDED)
-    overflowed = int((np.asarray(movers) > small_apply_cap).sum())
-    assert overflowed >= 1 and np.asarray(movers)[0] > small_apply_cap
-    assert int(result.num_rebuilds) == overflowed
+    # a sweep rebuilds when its movers' edges exceed the largest buffer:
+    # certainly if even its movers' least degrees do, never if their
+    # largest do not
+    largest = max(edge_buffer_sizes(sp.num_edges))
+    ends = np.append(np.asarray(sp.row_start)[1:], sp.num_edges)
+    degree = ends - np.asarray(sp.row_start)
+    movers = np.asarray(movers)
+    surely = movers * degree.min() > largest
+    maybe = int((movers * sp.max_degree > largest).sum())
+    # the first sweep alone: its movers' edges, read off the placement
+    first, _, _ = refine_mod._refine_sweeps(
+        sp, r0, key, "c", **dict(UNBOUNDED, max_sweeps=1))
+    moved = np.asarray(first.assignment) != np.asarray(r0)
+    assert degree[moved].sum() > largest and int(first.num_rebuilds) == 1
+    surely[0] = True
+    assert 1 <= int(surely.sum()) <= int(result.num_rebuilds) <= maybe
     # the public entry (no telemetry side output) counts the same
     plain, _ = refine_sweeps(sp, r0, key=key, **UNBOUNDED)
-    assert int(plain.num_rebuilds) == overflowed
+    assert int(plain.num_rebuilds) == int(result.num_rebuilds)
     assert _tree_equal(plain, result)
 
 
@@ -376,11 +387,12 @@ def test_num_rebuilds_zero_when_the_buffer_holds():
 
 
 def test_num_rebuilds_zero_outside_the_unbounded_mode(instance,
-                                                      small_apply_cap):
+                                                      overflowing_start):
     prob, r0 = instance
-    sp, s0 = _sparse_instance()
+    sp, s0 = overflowing_start
     one, _ = refine_sweeps(sp, s0, moves_per_machine=1, max_sweeps=64)
     assert int(one.num_moves) > 0 and int(one.num_rebuilds) == 0
+    assert int(one.apply_slots) == 0
     turn = refine(prob, r0, "c", max_turns=500)
     assert int(turn.num_moves) > 0 and int(turn.num_rebuilds) == 0
 
