@@ -32,7 +32,10 @@ Two cost paths (DESIGN.md §10), selected by ``incremental``:
 Also implements the paper-§4.5 *simultaneous transfer* mode (one move per
 machine per sweep, descent not guaranteed — measured in benchmarks), which
 applies a rank-K aggregate update per sweep and re-derives both potentials
-via the O(K) closed forms of :mod:`repro.core.aggregate`.
+via the O(K) closed forms of :mod:`repro.core.aggregate`.  It has one
+loop: ``refine_simultaneous`` is the degenerate configuration of the
+multi-move ``refine_sweeps`` (DESIGN.md §17) and runs its jitted
+``lax.while_loop``, which stops at the first sweep with no candidate.
 
 Sparse problems (DESIGN.md §13): all three entry points accept a
 :class:`~repro.core.sparse.SparseProblem` in place of the dense
@@ -327,9 +330,8 @@ class RefineResult(NamedTuple):
     # outgrew the largest edge buffer and took the O(E) rebuild (0 elsewhere)
     num_rebuilds: Array | int = 0
     # sweeps up to and including the first with no candidate (max_sweeps
-    # when every sweep had one): the sweeps refine_sweeps runs before it
-    # stops.  refine_simultaneous reports the same count but still scans
-    # all max_sweeps (0 outside the sweep modes)
+    # when every sweep had one): the sweeps refine_sweeps (and so
+    # refine_simultaneous) runs before it stops (0 outside the sweep modes)
     num_sweeps: Array | int = 0
     # edge slots the unbounded sparse refine_sweeps apply scattered: the sum
     # of the edge buffers its sweeps took (0 elsewhere)
@@ -701,76 +703,6 @@ def refine_traced(problem: PartitionProblem, assignment: Array,
     return result, trace
 
 
-@partial(jax.jit, static_argnames=("framework", "max_sweeps", "telemetry"))
-def _refine_simultaneous(problem: PartitionProblem, assignment: Array,
-                         framework: str = costs.C_FRAMEWORK,
-                         max_sweeps: int = 256, tol: float = DEFAULT_TOL,
-                         theta=None, telemetry: bool = False):
-    """Jitted scan body of :func:`refine_simultaneous`.
-
-    Returns ``(RefineResult, (c0s, ct0s, active), movers)`` where
-    ``movers`` is the (T,) per-sweep transfer count — a telemetry-only
-    side output (``None`` unless ``telemetry=True``; the default jaxpr
-    is the exact pre-telemetry program).
-    """
-    K = problem.num_machines
-    theta = _resolve_theta(theta, problem.num_nodes)
-    agg0 = agg_mod.init_aggregate_state(problem, assignment)
-    total_b = jnp.sum(problem.node_weights)
-    acc = acceptance(problem, agg0.aggregate, tol)
-
-    def sweep(carry, _):
-        agg, done, moves = carry
-        cost = costs.cost_matrix_from_aggregate(
-            agg.aggregate, agg.assignment, problem.node_weights, agg.loads,
-            problem.speeds, problem.mu, framework, total_weight=total_b)
-        dissat, best = costs.dissatisfaction_from_cost(cost, agg.assignment,
-                                                       theta)
-        # Per machine: the most dissatisfied owned node.
-        owned = jax.nn.one_hot(agg.assignment, K, dtype=cost.dtype)   # (N,K)
-        masked = jnp.where(owned.T > 0, dissat[None, :], -jnp.inf)    # (K,N)
-        pick = jnp.argmax(masked, axis=1).astype(jnp.int32)           # (K,)
-        gains = jnp.max(masked, axis=1)
-        will_move = gains > acceptance_threshold(
-            acc, framework, problem.node_weights[pick],
-            jnp.arange(K, dtype=jnp.int32), best[pick], agg.loads,
-            problem.speeds)                                            # (K,)
-        any_move = jnp.any(will_move) & ~done
-
-        # Apply all K moves at once (moving machines pick disjoint nodes: a
-        # node is owned by exactly one machine).  Idle machines' argmax over
-        # an all--inf row falls back to node 0, which may collide with a
-        # real move of node 0 — apply_sweep masks their columns to zero and
-        # drops their assignment writes.
-        new_agg = agg_mod.apply_sweep(problem, agg, pick, best[pick],
-                                      will_move, total_b)
-        new_agg = jax.tree.map(
-            lambda new, old: jnp.where(any_move, new, old), new_agg, agg)
-        sweep_movers = jnp.where(any_move,
-                                 jnp.sum(will_move.astype(jnp.int32)), 0)
-        moves = moves + sweep_movers
-        out = (new_agg.c0, new_agg.ct0, any_move)
-        if telemetry:
-            out = out + (sweep_movers,)
-        return (new_agg, done | ~any_move, moves), out
-
-    (agg, done, moves), outs = jax.lax.scan(
-        sweep, (agg0, jnp.zeros((), bool), jnp.zeros((), jnp.int32)),
-        None, length=max_sweeps)
-    movers = None
-    if telemetry:
-        c0s, ct0s, active, movers = outs
-    else:
-        c0s, ct0s, active = outs
-    num_turns = jnp.sum(active.astype(jnp.int32))
-    result = RefineResult(
-        assignment=agg.assignment, loads=agg.loads,
-        num_moves=moves, num_turns=num_turns,
-        converged=done, aggregate_drift=jnp.zeros(()),
-        num_sweeps=jnp.where(done, num_turns + 1, max_sweeps))
-    return result, (c0s, ct0s, active), movers
-
-
 def refine_simultaneous(problem: PartitionProblem, assignment: Array,
                         framework: str = costs.C_FRAMEWORK,
                         max_sweeps: int = 256, tol: float = DEFAULT_TOL,
@@ -803,28 +735,17 @@ def refine_simultaneous(problem: PartitionProblem, assignment: Array,
     (with a movers-per-sweep side output) plus drift + ``run_end``;
     ``recorder=None`` (default) runs the identical pre-telemetry
     program.
+
+    This is the degenerate configuration of :func:`refine_sweeps`
+    (``moves_per_machine=1, move_prob=1, epsilon=0``), run by the same
+    jitted loop, so it stops after the first sweep with no candidate;
+    the per-sweep outputs keep length ``max_sweeps``.
     """
-    if recorder is None:
-        result, outs, _ = _refine_simultaneous(
-            problem, assignment, framework, max_sweeps=max_sweeps, tol=tol,
-            theta=theta)
-        return result, outs
-    run = _open_run(recorder, "refine_simultaneous", problem, assignment,
-                    framework, theta)
-    t0 = time.perf_counter()
-    with recorder.phase("core.refine_simultaneous", run):
-        result, outs, movers = _refine_simultaneous(
-            problem, assignment, framework, max_sweeps=max_sweeps, tol=tol,
-            theta=theta, telemetry=True)
-        jax.block_until_ready(result)
-    wall = time.perf_counter() - t0
-    c0s, ct0s, active = outs
-    recorder.record_sweeps(run, c0s, ct0s, active, movers=movers)
-    turns = int(result.num_turns)
-    last = max(turns - 1, 0)
-    recorder.record_result(run, result, wall=wall, c0=float(c0s[last]),
-                           ct0=float(ct0s[last]))
-    return result, outs
+    return _run_sweeps("refine_simultaneous", {}, recorder, problem,
+                       assignment, None, framework, max_sweeps=max_sweeps,
+                       tol=tol, theta=theta, moves_per_machine=1,
+                       move_prob=1.0, epsilon=0.0, dissat_fn=None,
+                       sweep_fn=None)
 
 
 class SweepCandidateFn(Protocol):
@@ -868,9 +789,9 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
     that stops after the first sweep with no candidate, or after
     ``max_sweeps`` sweeps.
 
-    Returns ``(RefineResult, (c0s, ct0s, active), movers)`` exactly like
-    :func:`_refine_simultaneous` (``movers`` is ``None`` unless
-    ``telemetry=True``; the default jaxpr is the pre-telemetry program).
+    Returns ``(RefineResult, (c0s, ct0s, active), movers)`` (``movers``
+    is ``None`` unless ``telemetry=True``; the default jaxpr is the
+    pre-telemetry program).
     The per-sweep outputs keep length ``max_sweeps``: the slots of the
     sweeps not run hold what a sweep after convergence writes (the final
     potentials, ``active`` false, no movers), so they equal the outputs of
@@ -897,9 +818,8 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
                 # potential, so the acceptance floor scales with the CARRIED
                 # potential and the loop stops at an ε-Nash point instead of
                 # chasing O(tol) tail gains.  epsilon=0 is statically elided:
-                # the threshold is then exactly the one
-                # _refine_simultaneous compares against, keeping the
-                # degenerate config bitwise.
+                # the threshold is then the plain acceptance threshold of
+                # the §4.5 mode (refine_simultaneous).
                 thresh = acceptance_threshold(acc, framework, b, source, dest,
                                               agg.loads, problem.speeds)
                 if epsilon:
@@ -976,8 +896,8 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
             # coin — their overshoot is already bounded by the election.
             # ``move_prob >= 1`` is statically elided: ``accept`` IS
             # ``cand`` (same tensor, no PRNG op staged), which is what makes
-            # the degenerate config bitwise-reproduce
-            # :func:`_refine_simultaneous`.
+            # the degenerate config the §4.5 mode
+            # (:func:`refine_simultaneous`).
             if move_prob < 1.0:
                 coin_key = jax.random.fold_in(key, sweep_idx)
                 if sweep_fn is None and moves_per_machine is None:
@@ -1077,6 +997,37 @@ def _refine_sweeps(problem: PartitionProblem, assignment: Array, key=None,
     return result, (c0s, ct0s, active), movers
 
 
+def _run_sweeps(runtime: str, run_fields: dict, recorder, problem,
+                assignment, key, framework, **sweep_args):
+    """Run :func:`_refine_sweeps` (``sweep_args``: its keyword options)
+    for the public sweep entry points.
+
+    ``recorder=None`` calls the jitted loop and returns ``(result, outs)``;
+    otherwise the run opens as ``runtime`` with ``run_fields`` on its
+    ``run_start``, is timed under the phase ``core.<runtime>``, and
+    streams the per-sweep events (with movers) and ``run_end``."""
+    if recorder is None:
+        result, outs, _ = _refine_sweeps(problem, assignment, key, framework,
+                                         **sweep_args)
+        return result, outs
+    run = _open_run(recorder, runtime, problem, assignment, framework,
+                    sweep_args["theta"], **run_fields)
+    t0 = time.perf_counter()
+    with recorder.phase(f"core.{runtime}", run):
+        result, outs, movers = _refine_sweeps(
+            problem, assignment, key, framework, **sweep_args,
+            telemetry=True)
+        jax.block_until_ready(result)
+    wall = time.perf_counter() - t0
+    c0s, ct0s, active = outs
+    recorder.record_sweeps(run, c0s, ct0s, active, movers=movers)
+    turns = int(result.num_turns)
+    last = max(turns - 1, 0)
+    recorder.record_result(run, result, wall=wall, c0=float(c0s[last]),
+                           ct0=float(ct0s[last]))
+    return result, outs
+
+
 @partial(jax.profiler.annotate_function, name="repro.refine_sweeps")
 def refine_sweeps(problem: PartitionProblem, assignment: Array,
                   framework: str = costs.C_FRAMEWORK,
@@ -1123,10 +1074,10 @@ def refine_sweeps(problem: PartitionProblem, assignment: Array,
     converged.
 
     The degenerate config — ``moves_per_machine=1, move_prob=1.0,
-    epsilon=0`` — stages the same per-sweep op sequence as
-    :func:`refine_simultaneous` and reproduces its accepted-move
-    sequence, potentials and mover counts BITWISE on dense and sparse
-    problems alike (CI-gated by ``benchmarks/sparse_bench.py``).
+    epsilon=0`` — is the §4.5 mode: :func:`refine_simultaneous` runs it.
+    Its move sequences, potentials and mover counts are pinned bitwise
+    on dense and sparse problems against recorded reference outputs
+    (``tests/data/refine_simultaneous_ref.npz``).
 
     ``key`` (a ``jax.random`` PRNG key) is required when
     ``move_prob < 1``; per-sweep coins derive via ``fold_in(key, sweep)``
@@ -1160,34 +1111,15 @@ def refine_sweeps(problem: PartitionProblem, assignment: Array,
     if sweep_fn is not None and dissat_fn is not None:
         raise ValueError("pass sweep_fn or dissat_fn, not both (sweep_fn "
                          "subsumes the per-node reduction)")
-    if recorder is None:
-        result, outs, _ = _refine_sweeps(
-            problem, assignment, key, framework, max_sweeps=max_sweeps,
-            tol=tol, theta=theta, moves_per_machine=moves_per_machine,
-            move_prob=move_prob, epsilon=epsilon, dissat_fn=dissat_fn,
-            sweep_fn=sweep_fn)
-        return result, outs
-    run = _open_run(recorder, "refine_sweeps", problem, assignment,
-                    framework, theta,
-                    moves_per_machine=(-1 if moves_per_machine is None
-                                       else moves_per_machine),
-                    move_prob=move_prob, epsilon=epsilon)
-    t0 = time.perf_counter()
-    with recorder.phase("core.refine_sweeps", run):
-        result, outs, movers = _refine_sweeps(
-            problem, assignment, key, framework, max_sweeps=max_sweeps,
-            tol=tol, theta=theta, moves_per_machine=moves_per_machine,
-            move_prob=move_prob, epsilon=epsilon, dissat_fn=dissat_fn,
-            sweep_fn=sweep_fn, telemetry=True)
-        jax.block_until_ready(result)
-    wall = time.perf_counter() - t0
-    c0s, ct0s, active = outs
-    recorder.record_sweeps(run, c0s, ct0s, active, movers=movers)
-    turns = int(result.num_turns)
-    last = max(turns - 1, 0)
-    recorder.record_result(run, result, wall=wall, c0=float(c0s[last]),
-                           ct0=float(ct0s[last]))
-    return result, outs
+    run_fields = dict(moves_per_machine=(-1 if moves_per_machine is None
+                                         else moves_per_machine),
+                      move_prob=move_prob, epsilon=epsilon)
+    return _run_sweeps("refine_sweeps", run_fields, recorder, problem,
+                       assignment, key, framework, max_sweeps=max_sweeps,
+                       tol=tol, theta=theta,
+                       moves_per_machine=moves_per_machine,
+                       move_prob=move_prob, epsilon=epsilon,
+                       dissat_fn=dissat_fn, sweep_fn=sweep_fn)
 
 
 def count_discrepancies(trace: Trace, framework: str, initial_other: Array,

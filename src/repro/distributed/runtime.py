@@ -3,47 +3,34 @@
 Three execution modes over the same shard-local kernel + O(K) protocol:
 
   * :func:`refine_distributed`          — sequential round-robin turns,
-    ``lax.while_loop`` to convergence (the production entry point; this is
-    what ``repro.des.engine`` calls when ``refine_backend="distributed"``).
+    ``lax.while_loop`` to convergence (what ``repro.des.engine`` calls
+    when ``refine_backend="distributed"``).
   * :func:`refine_distributed_traced`   — fixed-length scan recording the
-    per-turn move sequence and both global potentials; move-for-move
-    identical to :func:`repro.core.refine.refine_traced` (the equivalence
-    the paper's Thm 4.1 convergence argument needs and
-    tests/test_distributed.py asserts).
+    per-turn moves and both global potentials; move-for-move identical
+    to :func:`repro.core.refine.refine_traced` (tests/test_distributed.py).
   * :func:`refine_distributed_simultaneous` — the §4.5 sweep mode: every
     machine moves its most dissatisfied node in the same round (descent
     not guaranteed, K× fewer exchange rounds).
 
 Shard-local compute is **incremental by default** (DESIGN.md §10): each
-shard carries its (Ns, K) row-block aggregate through the loop — built by
-one O(Ns·N·K) matmul at round 0 — and thereafter
-
-  * assembles its candidate costs from the carried block in O(Ns·K)/turn,
-  * applies the elected move as the same rank-1 column update the
-    controller applies (`A_s[:, s] -= c_s[:, l]; A_s[:, d] += c_s[:, l]`),
-    O(Ns), using only its own rows — wire traffic stays the O(K)
-    candidate exchange,
-  * (traced) attaches the exact-potential-identity deltas (ΔC_0, ΔCt_0,
-    Thm. 3.1/5.1) to its candidate — 8 B — so every machine updates its
-    replicated potentials without any O(N) pass.
-
+shard carries its (Ns, K) row-block aggregate, built by one O(Ns·N·K)
+matmul at round 0, assembles its candidates from it in O(Ns·K) per turn
+and applies the elected move as the controller's rank-1 column update —
+wire traffic stays the O(K) candidate exchange; the traced mode attaches
+the 8-byte exact-potential deltas (Thm. 3.1/5.1) to each candidate.
 ``incremental=False`` restores the recompute path (block aggregate matmul
-every turn), which is also what ``cost_fn="pallas"`` drives through the
-fused Pallas cost kernel when recomputing; on the incremental path
-``cost_fn="pallas"`` routes the per-turn reduction through the fused
-aggregate→(dissat, best) kernel instead.
+every turn); ``cost_fn="pallas"`` routes either path through its fused
+Pallas kernel.
 
-Two drivers realize the SPMD program:
-
-  * the **emulated** driver maps the shard axis with ``vmap`` and performs
-    the candidate all-gather as a plain stacked reduction — it runs on a
-    single device, is fully jit/cond-compatible (the DES engine embeds
-    it), and is bit-identical in protocol terms to the mesh driver;
-  * :func:`refine_distributed_shard_map` places each shard's row block on
-    its own device of a ``jax.sharding.Mesh`` and exchanges candidates
-    with ``lax.all_gather`` — the real-collective path, exercised by
-    ``benchmarks/distributed_bench.py`` under a forced multi-device host
-    platform.
+Each mode is ONE jitted loop.  The recompute path leaves the carried
+aggregate's slot of the loop state ``None`` (an empty pytree), and a
+``fault_plan`` (DESIGN.md §15) stages the fault steps on the incremental
+path; ``fault_plan=None`` stages none of them.  The **emulated** drivers
+map the shard axis with ``vmap`` on one device (the DES engine embeds
+them); :func:`refine_distributed_shard_map` puts each row block on its
+own device of a ``Mesh`` and exchanges candidates with ``lax.all_gather``.
+One harness (:func:`_run`) serves the four public wrappers: telemetry,
+the wire reconciliation and the recover-or-raise audit.
 """
 from __future__ import annotations
 
@@ -99,16 +86,11 @@ DISTRIBUTED_COLLECTIVES = {
 class WireMeasurement(NamedTuple):
     """Measured exchange bytes of one distributed run (DESIGN.md §14.5).
 
-    Produced by the drivers under ``measure_wire=True``: ``payload_bytes``
-    is the byte size of the pytrees that actually crossed the emulated
-    (or real) exchange each round — measured from the staged buffers via
-    :func:`_nbytes`, not from the analytic formulas — times the rounds
-    the run executed; ``setup_bytes`` covers the one-time replicated
-    state (O(K) loads + total-B scalar, plus the initial-potential
-    partials on the incremental traced path).  ``rounds`` follows the
-    same convention as ``RefineResult.num_turns`` (active turns/sweeps),
-    which is what :func:`repro.distributed.accounting.ledger_for_run`
-    is built from — so ``accounting.reconcile`` compares like with like.
+    Produced under ``measure_wire=True``: ``payload_bytes`` is the byte
+    size of the buffers that crossed the exchange each round (measured via
+    :func:`_nbytes`, not from the analytic formulas) times the rounds run;
+    ``setup_bytes`` the one-time replicated state.  ``rounds`` follows
+    ``RefineResult.num_turns``, as ``accounting.ledger_for_run`` does.
     """
     rounds: Array          # int32 — active turns/sweeps (== num_turns)
     payload_bytes: Array   # int32 — per-round exchange, whole run
@@ -116,11 +98,8 @@ class WireMeasurement(NamedTuple):
 
 
 def _nbytes(tree) -> int:
-    """Total byte size of a pytree's array leaves, at trace time.
-
-    Shapes and dtypes are static under tracing, so this is a Python int
-    even inside jit — the measured size of the buffers being exchanged.
-    """
+    """Total byte size of a pytree's array leaves — a Python int even
+    under jit (shapes are static): the size of the buffers exchanged."""
     return int(sum(leaf.size * leaf.dtype.itemsize
                    for leaf in jax.tree.leaves(tree)))
 
@@ -138,11 +117,8 @@ def _vmap_shards(fn, theta_blocks: Array | None, *axes):
 
 def _shard_theta(theta, problem: PartitionProblem,
                  num_shards: int) -> Array | None:
-    """(S, Ns) shard blocks of the per-node hysteresis threshold, or None.
-
-    theta never crosses the wire: each shard reads only its own block
-    (DESIGN.md §11), mirroring the single controller's (N,) broadcast.
-    """
+    """(S, Ns) shard blocks of the per-node hysteresis threshold, or None;
+    each shard reads only its own block, never the wire (DESIGN.md §11)."""
     if theta is None:
         return None
     theta = jnp.broadcast_to(jnp.asarray(theta, jnp.float32),
@@ -182,10 +158,8 @@ def _shard_cost_fn(cost_fn: str):
 def _shard_dissat_fn(cost_fn: str) -> DissatFn | None:
     """Shard-local (dissat, best) from the carried block aggregate, for the
     INCREMENTAL path: "jnp" (shared O(Ns·K) assembly, bitwise equal to the
-    controller) or "pallas" (fused aggregate→(dissat, best) kernel).  Both
-    follow the canonical 9-argument ``dissat_fn`` convention — see "The
-    ``dissat_fn`` convention" in :mod:`repro.core.refine` — so the same
-    ``ops.make_aggregate_dissat_fn`` adapter plugs in everywhere."""
+    controller) or "pallas" (the fused kernel, through the ``dissat_fn``
+    convention of :mod:`repro.core.refine`)."""
     if cost_fn == "jnp":
         return None
     if cost_fn == "pallas":
@@ -202,20 +176,6 @@ def _acceptance(problem: PartitionProblem, assignment: Array,
         problem, assignment, problem.num_machines), tol)
 
 
-def _elect_sweep(cands: protocol.Candidate, acc: Acceptance, loads: Array,
-                 problem: PartitionProblem, framework: str,
-                 lag: Array | None = None, penalty=None) -> protocol.Winner:
-    """Per-machine elections of a sweep over (S, K) candidates."""
-    k = problem.num_machines
-    thresh = protocol.candidate_thresholds(
-        cands, acc, jnp.arange(k, dtype=jnp.int32)[None, :], loads,
-        problem.speeds, framework)                                  # (S, K)
-    if lag is None:
-        return jax.vmap(protocol.elect, in_axes=(1, 1))(cands, thresh)
-    return jax.vmap(protocol.elect_degraded, in_axes=(1, 1, None, None))(
-        cands, thresh, lag, penalty)
-
-
 def _init_block_aggregates(views: ShardViews, assignment: Array,
                            num_machines: int) -> Array:
     """(S, Ns, K) carried block aggregates — the one-time matmuls."""
@@ -224,33 +184,27 @@ def _init_block_aggregates(views: ShardViews, assignment: Array,
     )(views.row_block)
 
 
-def _vmap_candidates(views: ShardViews, assignment: Array, loads: Array,
-                     speeds: Array, mu: Array, total_b: Array,
-                     machine: Array, framework: str, cost_fn: str,
-                     acc: Acceptance,
-                     theta_blocks: Array | None = None) -> protocol.Candidate:
-    """Recompute-path emulated exchange: all S candidates, stacked."""
-    shard_cost = _shard_cost_fn(cost_fn)
+def _vmap_candidates(views: ShardViews, aggs: Array | None,
+                     assignment: Array, loads: Array, speeds: Array,
+                     mu: Array, total_b: Array, machine: Array,
+                     framework: str, cost_fn: str, acc: Acceptance,
+                     with_deltas: bool = False,
+                     theta_blocks: Array | None = None):
+    """Emulated turn exchange: all S candidates, stacked — from the carried
+    block aggregates ``aggs``, or recomputed from the row blocks when
+    ``aggs`` is None (the recompute path, which has no deltas)."""
+    if aggs is None:
+        shard_cost = _shard_cost_fn(cost_fn)
 
-    def one(rb, b, ids, valid, th):
-        with jax.named_scope("shard_candidate"):
-            return protocol.local_candidate(
-                rb, b, ids, valid, assignment, loads, speeds, mu, total_b,
-                machine, framework, acc, cost_matrix_fn=shard_cost,
-                theta_local=th)
+        def one(rb, b, ids, valid, th):
+            with jax.named_scope("shard_candidate"):
+                return protocol.local_candidate(
+                    rb, b, ids, valid, assignment, loads, speeds, mu,
+                    total_b, machine, framework, acc,
+                    cost_matrix_fn=shard_cost, theta_local=th)
 
-    return _vmap_shards(one, theta_blocks, views.row_block, views.weights,
-                        views.ids, views.valid)
-
-
-def _vmap_candidates_incremental(views: ShardViews, block_aggs: Array,
-                                 assignment: Array, loads: Array,
-                                 speeds: Array, mu: Array, total_b: Array,
-                                 machine: Array, framework: str,
-                                 cost_fn: str, acc: Acceptance,
-                                 with_deltas: bool = False,
-                                 theta_blocks: Array | None = None):
-    """Incremental-path emulated exchange from the carried block aggregates."""
+        return _vmap_shards(one, theta_blocks, views.row_block,
+                            views.weights, views.ids, views.valid)
     dissat_fn = _shard_dissat_fn(cost_fn)
 
     def one(agg, b, ids, valid, th):
@@ -260,7 +214,7 @@ def _vmap_candidates_incremental(views: ShardViews, block_aggs: Array,
                 machine, framework, acc, with_deltas=with_deltas,
                 dissat_fn=dissat_fn, theta_local=th)
 
-    return _vmap_shards(one, theta_blocks, block_aggs, views.weights,
+    return _vmap_shards(one, theta_blocks, aggs, views.weights,
                         views.ids, views.valid)
 
 
@@ -274,27 +228,27 @@ def _update_block_aggregates(views: ShardViews, block_aggs: Array,
     )(block_aggs, views.row_block)
 
 
+def _load_partials(views: ShardViews, weights: Array, assignment: Array,
+                   num_machines: int) -> Array:
+    """(S, K) per-shard load partials for the given per-shard weights."""
+    return jax.vmap(
+        lambda b, ids, v: protocol.shard_load_partial(
+            b, ids, v, assignment, num_machines)
+    )(weights, views.ids, views.valid)
+
+
 def _vmap_potentials(views: ShardViews, assignment: Array, speeds: Array,
                      mu: Array, total_b: Array, num_machines: int,
                      fresh_loads: Array | None = None):
-    """Emulated reduction of the per-shard potential partials (used once to
-    initialize the traced potentials, and by the recompute traced path).
-
-    Pass ``fresh_loads`` when the caller already reduced the shard load
-    partials for ``assignment`` (the sweep driver does) to skip the
-    redundant second reduction.
-
-    Returns ``(c0, ct0, partial_bytes)`` — the third element is the
-    measured byte size of the partial arrays this reduction exchanged
-    (a trace-time Python int, consumed by the ``measure_wire`` counters
-    of DESIGN.md §14.5 and free to ignore otherwise).
-    """
+    """Emulated reduction of the per-shard potential partials from the row
+    blocks (the traced init, and every round of the recompute paths);
+    ``fresh_loads``, when the caller holds them, skips the load partials.
+    Returns ``(loads, c0, ct0, partial_bytes)``, the last a trace-time
+    int: the bytes of the partials exchanged (DESIGN.md §14.5)."""
     partial_bytes = 0
     if fresh_loads is None:
-        load_partials = jax.vmap(
-            lambda b, ids, v: protocol.shard_load_partial(
-                b, ids, v, assignment, num_machines)
-        )(views.weights, views.ids, views.valid)
+        load_partials = _load_partials(views, views.weights, assignment,
+                                       num_machines)
         fresh_loads = jnp.sum(load_partials, axis=0)
         partial_bytes += _nbytes(load_partials)
     c0_partials = jax.vmap(
@@ -307,420 +261,103 @@ def _vmap_potentials(views: ShardViews, assignment: Array, speeds: Array,
     partial_bytes += _nbytes((c0_partials, cut_partials))
     c0, ct0 = protocol.global_potentials(c0_partials, cut_partials,
                                          fresh_loads, speeds, mu, total_b)
-    return c0, ct0, partial_bytes
+    return fresh_loads, c0, ct0, partial_bytes
+
+
+def _closed_potentials(views: ShardViews, sq_weights: Array, aggs: Array,
+                       assignment: Array, speeds: Array, mu, total_b,
+                       num_machines: int):
+    """Loads + closed-form potentials from the load, squared-load and
+    aggregate-cut partials (O(K) wire, no pass over the row blocks);
+    returns ``(loads, c0, ct0, partial_bytes)``."""
+    load_partials = _load_partials(views, views.weights, assignment,
+                                   num_machines)
+    fresh_loads = jnp.sum(load_partials, axis=0)
+    sq_partials = _load_partials(views, sq_weights, assignment, num_machines)
+    sq_loads = jnp.sum(sq_partials, axis=0)
+    cut_partials = jax.vmap(
+        lambda agg, ids, v: protocol.shard_cut_partial_from_aggregate(
+            agg, ids, v, assignment)
+    )(aggs, views.ids, views.valid)
+    cut = 0.5 * jnp.sum(cut_partials)
+    c0, ct0 = agg_mod.potentials_closed_form(fresh_loads, sq_loads, cut,
+                                             speeds, mu, total_b)
+    return fresh_loads, c0, ct0, _nbytes((load_partials, sq_partials,
+                                          cut_partials))
+
+
+def _elect(cands: protocol.Candidate, acc: Acceptance, loads: Array,
+           problem: PartitionProblem, framework: str, machine=None,
+           row=None, penalty=None) -> protocol.Winner:
+    """One turn's election over (S,) candidates for ``machine`` or, with
+    ``machine=None``, a sweep's per-machine elections over (S, K)
+    candidates.  A fault plan ``row`` masks the blocked shards and prices
+    staleness (``protocol.elect_degraded``)."""
+    if row is not None:
+        cands = _mask_blocked(cands, row)
+    sweep = machine is None
+    if sweep:
+        machine = jnp.arange(problem.num_machines, dtype=jnp.int32)[None, :]
+    thresh = protocol.candidate_thresholds(cands, acc, machine, loads,
+                                           problem.speeds, framework)
+    if row is None:
+        elect, args, axes = protocol.elect, (cands, thresh), (1, 1)
+    else:
+        elect, args, axes = (protocol.elect_degraded,
+                             (cands, thresh, row.lag, penalty),
+                             (1, 1, None, None))
+    return (jax.vmap(elect, in_axes=axes) if sweep else elect)(*args)
+
+
+def _next_idle(idle: Array, moved: Array, row=None) -> Array:
+    """Consecutive no-move turns; under a fault plan only fault-clear
+    rounds count (a blocked no-move round is no evidence of equilibrium)."""
+    step = idle + 1 if row is None else jnp.where(row.clear, idle + 1, idle)
+    return jnp.where(moved, 0, step)
+
+
+def _trace_row(winner: protocol.Winner, moved: Array, machine: Array,
+               c0: Array, ct0: Array, active: Array) -> Trace:
+    """One turn of the traced driver's per-turn record."""
+    return Trace(
+        moved=moved,
+        node=jnp.where(winner.moved, winner.node, -1),
+        source=jnp.where(winner.moved, machine, -1),
+        dest=jnp.where(winner.moved, winner.dest, -1),
+        gain=jnp.where(winner.moved, winner.gain, 0.0),
+        c0=c0, ct0=ct0, active=active)
+
+
+def _with_wire(outs: tuple, measure_wire: bool, rounds, per_round: int,
+               setup_bytes: int, fault_bytes=None):
+    """A driver's return: ``outs`` plus, under ``measure_wire``, the
+    :class:`WireMeasurement` of ``rounds`` exchanges of ``per_round``
+    bytes (+ a faulty run's fault bytes); a lone result is bare."""
+    if measure_wire:
+        payload = rounds * per_round
+        if fault_bytes is not None:
+            payload = payload + fault_bytes
+        outs = outs + (WireMeasurement(
+            rounds=rounds, payload_bytes=payload,
+            setup_bytes=jnp.int32(setup_bytes)),)
+    return outs[0] if len(outs) == 1 else outs
 
 
 # ---------------------------------------------------------------------------
-# Sequential round-robin turns (paper §4.2 protocol, distributed)
-# ---------------------------------------------------------------------------
-
-@partial(jax.jit, static_argnames=("framework", "num_shards", "max_turns",
-                                   "cost_fn", "incremental", "measure_wire"))
-def _refine_distributed(problem: PartitionProblem, assignment: Array,
-                        framework: str = costs.C_FRAMEWORK,
-                        num_shards: int | None = None,
-                        max_turns: int = 10_000, tol: float = DEFAULT_TOL,
-                        cost_fn: str = "jnp",
-                        incremental: bool = True,
-                        theta=None, measure_wire: bool = False):
-    """Distributed round-robin refinement to convergence (K idle turns).
-
-    Protocol per turn: each shard computes one Candidate from local state
-    (16 bytes on the wire), the candidates are all-gathered, every machine
-    elects the same winner and applies the same O(1) delta to its
-    replicated assignment mirror + O(K) load vector — and, on the default
-    incremental path, the same rank-1 update to its carried (Ns, K) block
-    aggregate, so no shard ever rebuilds its aggregate matmul after turn 0.
-
-    ``theta`` (scalar or (N,)) is the migration-price hysteresis threshold
-    (DESIGN.md §11), evaluated shard-locally — the wire stays O(K) and
-    ``theta=None``/``0`` reproduces the threshold-free move sequence
-    bitwise (the core↔distributed contract).
-
-    ``measure_wire=True`` (static) additionally returns a
-    :class:`WireMeasurement` counting the bytes of the actual per-turn
-    candidate exchange — ``(result, wire)`` instead of ``result`` — for
-    reconciliation against ``accounting.ledger_for_run`` (DESIGN.md
-    §14.5).  The default jaxpr is unchanged.
-    """
-    k = problem.num_machines
-    s = _resolve_shards(problem, num_shards)
-    views = build_views(problem, s)
-    state0 = make_state(problem, assignment)
-    total_b = jnp.sum(problem.node_weights)
-    theta_blocks = _shard_theta(theta, problem, s)
-    acc = _acceptance(problem, state0.assignment, tol)
-    measured: dict = {}
-
-    if incremental:
-        aggs0 = _init_block_aggregates(views, state0.assignment, k)
-
-        def cond(carry):
-            _, _, _, _, idle, turns, _ = carry
-            return (idle < k) & (turns < max_turns)
-
-        def body(carry):
-            r, loads, aggs, machine, idle, turns, moves = carry
-            cands = _vmap_candidates_incremental(
-                views, aggs, r, loads, problem.speeds, problem.mu, total_b,
-                machine, framework, cost_fn, acc, theta_blocks=theta_blocks)
-            measured["turn"] = _nbytes(cands)
-            winner = protocol.elect(
-                cands, protocol.candidate_thresholds(
-                    cands, acc, machine, loads, problem.speeds, framework))
-            aggs = _update_block_aggregates(views, aggs, winner, machine)
-            r, loads = protocol.apply_move(r, loads, winner, machine)
-            idle = jnp.where(winner.moved, 0, idle + 1)
-            return (r, loads, aggs, (machine + 1) % k, idle, turns + 1,
-                    moves + winner.moved.astype(jnp.int32))
-
-        init = (state0.assignment, state0.loads, aggs0,
-                jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32),
-                jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-        r, loads, _, _, idle, turns, moves = jax.lax.while_loop(
-            cond, body, init)
-        result = RefineResult(assignment=r, loads=loads, num_moves=moves,
-                              num_turns=turns, converged=idle >= k)
-        if not measure_wire:
-            return result
-        return result, WireMeasurement(
-            rounds=turns, payload_bytes=turns * measured["turn"],
-            setup_bytes=jnp.int32(_nbytes((state0.loads, total_b))))
-
-    def cond(carry):
-        _, _, _, idle, turns, _ = carry
-        return (idle < k) & (turns < max_turns)
-
-    def body(carry):
-        r, loads, machine, idle, turns, moves = carry
-        cands = _vmap_candidates(views, r, loads, problem.speeds, problem.mu,
-                                 total_b, machine, framework, cost_fn,
-                                 acc, theta_blocks=theta_blocks)
-        measured["turn"] = _nbytes(cands)
-        winner = protocol.elect(
-            cands, protocol.candidate_thresholds(
-                cands, acc, machine, loads, problem.speeds, framework))
-        r, loads = protocol.apply_move(r, loads, winner, machine)
-        idle = jnp.where(winner.moved, 0, idle + 1)
-        return (r, loads, (machine + 1) % k, idle, turns + 1,
-                moves + winner.moved.astype(jnp.int32))
-
-    init = (state0.assignment, state0.loads, jnp.zeros((), jnp.int32),
-            jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32),
-            jnp.zeros((), jnp.int32))
-    r, loads, _, idle, turns, moves = jax.lax.while_loop(cond, body, init)
-    result = RefineResult(assignment=r, loads=loads, num_moves=moves,
-                          num_turns=turns, converged=idle >= k)
-    if not measure_wire:
-        return result
-    return result, WireMeasurement(
-        rounds=turns, payload_bytes=turns * measured["turn"],
-        setup_bytes=jnp.int32(_nbytes((state0.loads, total_b))))
-
-
-@partial(jax.jit, static_argnames=("framework", "num_shards", "max_turns",
-                                   "cost_fn", "incremental", "measure_wire"))
-def _refine_distributed_traced(problem: PartitionProblem, assignment: Array,
-                               framework: str = costs.C_FRAMEWORK,
-                               num_shards: int | None = None,
-                               max_turns: int = 512,
-                               tol: float = DEFAULT_TOL,
-                               cost_fn: str = "jnp",
-                               incremental: bool = True,
-                               theta=None, measure_wire: bool = False):
-    """Fixed-length traced variant; returns ``(RefineResult, Trace)`` with
-    the exact semantics (and, in sequential mode, the exact move sequence)
-    of :func:`repro.core.refine.refine_traced`.
-
-    On the incremental path the potentials are initialized once from
-    per-shard partials and thereafter updated by the winner's 8-byte
-    exact-potential deltas (Thm. 3.1/5.1) — O(1) wire + O(K) compute per
-    turn, no O(N) pass of any kind.  ``incremental=False`` restores the
-    per-turn partial-reduction recompute.  ``theta`` as in
-    :func:`refine_distributed`.
-
-    ``measure_wire=True`` (static) returns ``(result, trace, wire)``
-    with a :class:`WireMeasurement` counting the actual per-turn
-    exchange (candidates + potential deltas, or + the recompute
-    partials) and the one-time setup including the initial-potential
-    partials (DESIGN.md §14.5).  The default jaxpr is unchanged.
-    """
-    k = problem.num_machines
-    s = _resolve_shards(problem, num_shards)
-    views = build_views(problem, s)
-    state0 = make_state(problem, assignment)
-    total_b = jnp.sum(problem.node_weights)
-    theta_blocks = _shard_theta(theta, problem, s)
-    acc = _acceptance(problem, state0.assignment, tol)
-    measured: dict = {}
-    setup_base = _nbytes((state0.loads, total_b))
-
-    if incremental:
-        aggs0 = _init_block_aggregates(views, state0.assignment, k)
-        c0_init, ct0_init, init_pot_bytes = _vmap_potentials(
-            views, state0.assignment, problem.speeds, problem.mu,
-            total_b, k, fresh_loads=state0.loads)
-
-        def step(carry, _):
-            r, loads, aggs, c0, ct0, machine, idle = carry
-            active = idle < k
-            cands, dc0s, dct0s = _vmap_candidates_incremental(
-                views, aggs, r, loads, problem.speeds, problem.mu, total_b,
-                machine, framework, cost_fn, acc, with_deltas=True,
-                theta_blocks=theta_blocks)
-            measured["turn"] = _nbytes((cands, dc0s, dct0s))
-            winner = protocol.elect(
-                cands, protocol.candidate_thresholds(
-                    cands, acc, machine, loads, problem.speeds, framework))
-            moved = winner.moved & active
-            gated = winner._replace(moved=moved)
-            new_aggs = _update_block_aggregates(views, aggs, gated, machine)
-            new_r, new_loads = protocol.apply_move(r, loads, gated, machine)
-            new_c0 = jnp.where(moved, c0 + dc0s[winner.shard], c0)
-            new_ct0 = jnp.where(moved, ct0 + dct0s[winner.shard], ct0)
-            idle = jnp.where(moved, 0, idle + 1)
-            out = Trace(
-                moved=moved,
-                node=jnp.where(winner.moved, winner.node, -1),
-                source=jnp.where(winner.moved, machine, -1),
-                dest=jnp.where(winner.moved, winner.dest, -1),
-                gain=jnp.where(winner.moved, winner.gain, 0.0),
-                c0=new_c0, ct0=new_ct0, active=active)
-            return (new_r, new_loads, new_aggs, new_c0, new_ct0,
-                    (machine + 1) % k, idle), out
-
-        init = (state0.assignment, state0.loads, aggs0, c0_init, ct0_init,
-                jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-        (r, loads, _, _, _, _, idle), trace = jax.lax.scan(
-            step, init, None, length=max_turns)
-        moves = jnp.sum(trace.moved.astype(jnp.int32))
-        turns = jnp.sum(trace.active.astype(jnp.int32))
-        result = RefineResult(assignment=r, loads=loads, num_moves=moves,
-                              num_turns=turns, converged=idle >= k)
-        if not measure_wire:
-            return result, trace
-        return result, trace, WireMeasurement(
-            rounds=turns, payload_bytes=turns * measured["turn"],
-            setup_bytes=jnp.int32(setup_base + init_pot_bytes))
-
-    def step(carry, _):
-        r, loads, machine, idle = carry
-        active = idle < k
-        cands = _vmap_candidates(views, r, loads, problem.speeds, problem.mu,
-                                 total_b, machine, framework, cost_fn,
-                                 acc, theta_blocks=theta_blocks)
-        winner = protocol.elect(
-            cands, protocol.candidate_thresholds(
-                cands, acc, machine, loads, problem.speeds, framework))
-        new_r, new_loads = protocol.apply_move(r, loads, winner, machine)
-        new_r = jnp.where(active, new_r, r)
-        new_loads = jnp.where(active, new_loads, loads)
-        moved = winner.moved & active
-        idle = jnp.where(moved, 0, idle + 1)
-        c0, ct0, pot_bytes = _vmap_potentials(views, new_r, problem.speeds,
-                                              problem.mu, total_b, k)
-        measured["turn"] = _nbytes(cands) + pot_bytes
-        out = Trace(
-            moved=moved,
-            node=jnp.where(winner.moved, winner.node, -1),
-            source=jnp.where(winner.moved, machine, -1),
-            dest=jnp.where(winner.moved, winner.dest, -1),
-            gain=jnp.where(winner.moved, winner.gain, 0.0),
-            c0=c0, ct0=ct0, active=active)
-        return (new_r, new_loads, (machine + 1) % k, idle), out
-
-    init = (state0.assignment, state0.loads, jnp.zeros((), jnp.int32),
-            jnp.zeros((), jnp.int32))
-    (r, loads, _, idle), trace = jax.lax.scan(step, init, None,
-                                              length=max_turns)
-    moves = jnp.sum(trace.moved.astype(jnp.int32))
-    turns = jnp.sum(trace.active.astype(jnp.int32))
-    result = RefineResult(assignment=r, loads=loads, num_moves=moves,
-                          num_turns=turns, converged=idle >= k)
-    if not measure_wire:
-        return result, trace
-    return result, trace, WireMeasurement(
-        rounds=turns, payload_bytes=turns * measured["turn"],
-        setup_bytes=jnp.int32(setup_base))
-
-
-# ---------------------------------------------------------------------------
-# §4.5 simultaneous sweeps, distributed
-# ---------------------------------------------------------------------------
-
-@partial(jax.jit, static_argnames=("framework", "num_shards", "max_sweeps",
-                                   "cost_fn", "incremental", "measure_wire"))
-def _refine_distributed_simultaneous(problem: PartitionProblem,
-                                     assignment: Array,
-                                     framework: str = costs.C_FRAMEWORK,
-                                     num_shards: int | None = None,
-                                     max_sweeps: int = 256,
-                                     tol: float = DEFAULT_TOL,
-                                     cost_fn: str = "jnp",
-                                     incremental: bool = True,
-                                     theta=None, measure_wire: bool = False):
-    """Distributed §4.5 sweeps: each shard ships K candidates per sweep
-    (one per machine), elections run per machine, all K disjoint moves
-    apply at once as a rank-K block-aggregate update.  Exchange per sweep:
-    S*K candidates + S load/sq-load/cut partials — still independent of N.
-
-    ``num_moves`` counts actual transfers (sum of per-sweep movers), not
-    the K*sweeps upper bound.  ``theta`` as in :func:`refine_distributed`.
-
-    ``measure_wire=True`` (static) returns ``(result, traces, wire)``
-    with a :class:`WireMeasurement` of the actual per-sweep exchange
-    (K candidates per shard + the partial reductions); ``rounds`` counts
-    active sweeps, matching ``num_turns`` and the ledger convention
-    (DESIGN.md §14.5).  The default jaxpr is unchanged.
-    """
-    k = problem.num_machines
-    s = _resolve_shards(problem, num_shards)
-    views = build_views(problem, s)
-    state0 = make_state(problem, assignment)
-    total_b = jnp.sum(problem.node_weights)
-    sq_weights = views.weights * views.weights
-    theta_blocks = _shard_theta(theta, problem, s)
-    acc = _acceptance(problem, state0.assignment, tol)
-    measured: dict = {}
-
-    def _sweep_cands_incremental(aggs, r, loads, dissat_fn):
-        def one(agg, b, ids, v, th):
-            return protocol.local_candidates_all_machines_from_aggregate(
-                agg, b, ids, v, r, loads, problem.speeds, problem.mu,
-                total_b, framework, dissat_fn=dissat_fn, theta_local=th)
-
-        return _vmap_shards(one, theta_blocks, aggs, views.weights,
-                            views.ids, views.valid)              # (S, K)
-
-    if incremental:
-        aggs0 = _init_block_aggregates(views, state0.assignment, k)
-        dissat_fn = _shard_dissat_fn(cost_fn)
-
-        def sweep(carry, _):
-            r, loads, aggs, done, moves = carry
-            cands = _sweep_cands_incremental(aggs, r, loads, dissat_fn)
-            winners = _elect_sweep(cands, acc, loads, problem,
-                                   framework)                     # (K,)
-            any_move = jnp.any(winners.moved) & ~done
-            # Idle machines elect a fallback candidate (all gains -inf)
-            # whose node id may collide with a real move — mask their
-            # columns / drop their writes instead of racing the update.
-            safe_picks = jnp.where(winners.moved, winners.node,
-                                   jnp.int32(problem.num_nodes))
-            new_r = r.at[safe_picks].set(winners.dest, mode="drop")
-            new_r = jnp.where(any_move, new_r, r)
-            new_aggs = jax.vmap(
-                lambda agg, rb: protocol.update_block_aggregate_sweep(
-                    agg, rb, winners.node, winners.dest, winners.moved)
-            )(aggs, views.row_block)
-            new_aggs = jnp.where(any_move, new_aggs, aggs)
-            load_partials = jax.vmap(
-                lambda b, ids, v: protocol.shard_load_partial(
-                    b, ids, v, new_r, k)
-            )(views.weights, views.ids, views.valid)
-            new_loads = jnp.sum(load_partials, axis=0)
-            sq_partials = jax.vmap(
-                lambda b2, ids, v: protocol.shard_load_partial(
-                    b2, ids, v, new_r, k)
-            )(sq_weights, views.ids, views.valid)
-            sq_loads = jnp.sum(sq_partials, axis=0)
-            cut_partials = jax.vmap(
-                lambda agg, ids, v: protocol.shard_cut_partial_from_aggregate(
-                    agg, ids, v, new_r)
-            )(new_aggs, views.ids, views.valid)
-            measured["sweep"] = _nbytes(
-                (cands, load_partials, sq_partials, cut_partials))
-            cut = 0.5 * jnp.sum(cut_partials)
-            c0, ct0 = agg_mod.potentials_closed_form(
-                new_loads, sq_loads, cut, problem.speeds, problem.mu,
-                total_b)
-            moves = moves + jnp.where(
-                any_move, jnp.sum(winners.moved.astype(jnp.int32)), 0)
-            return ((new_r, new_loads, new_aggs, done | ~any_move, moves),
-                    (c0, ct0, any_move))
-
-        (r, loads, _, done, moves), (c0s, ct0s, active) = jax.lax.scan(
-            sweep, (state0.assignment, state0.loads, aggs0,
-                    jnp.zeros((), bool), jnp.zeros((), jnp.int32)),
-            None, length=max_sweeps)
-        sweeps = jnp.sum(active.astype(jnp.int32))
-        result = RefineResult(
-            assignment=r, loads=loads, num_moves=moves,
-            num_turns=sweeps, converged=done)
-        if not measure_wire:
-            return result, (c0s, ct0s, active)
-        return result, (c0s, ct0s, active), WireMeasurement(
-            rounds=sweeps, payload_bytes=sweeps * measured["sweep"],
-            setup_bytes=jnp.int32(_nbytes((state0.loads, total_b))))
-
-    shard_cost = _shard_cost_fn(cost_fn)
-
-    def sweep(carry, _):
-        r, loads, done, moves = carry
-
-        def one(rb, b, ids, v, th):
-            return protocol.local_candidates_all_machines(
-                rb, b, ids, v, r, loads, problem.speeds, problem.mu,
-                total_b, framework, cost_matrix_fn=shard_cost,
-                theta_local=th)
-
-        cands = _vmap_shards(one, theta_blocks, views.row_block,
-                             views.weights, views.ids, views.valid)  # (S, K)
-        winners = _elect_sweep(cands, acc, loads, problem,
-                               framework)                          # (K,)
-        any_move = jnp.any(winners.moved) & ~done
-        safe_picks = jnp.where(winners.moved, winners.node,
-                               jnp.int32(problem.num_nodes))
-        new_r = r.at[safe_picks].set(winners.dest, mode="drop")
-        new_r = jnp.where(any_move, new_r, r)
-        load_partials = jax.vmap(
-            lambda b, ids, v: protocol.shard_load_partial(b, ids, v, new_r, k)
-        )(views.weights, views.ids, views.valid)
-        new_loads = jnp.sum(load_partials, axis=0)
-        c0, ct0, pot_bytes = _vmap_potentials(views, new_r, problem.speeds,
-                                              problem.mu, total_b, k,
-                                              fresh_loads=new_loads)
-        measured["sweep"] = _nbytes((cands, load_partials)) + pot_bytes
-        moves = moves + jnp.where(
-            any_move, jnp.sum(winners.moved.astype(jnp.int32)), 0)
-        return ((new_r, new_loads, done | ~any_move, moves),
-                (c0, ct0, any_move))
-
-    (r, loads, done, moves), (c0s, ct0s, active) = jax.lax.scan(
-        sweep, (state0.assignment, state0.loads, jnp.zeros((), bool),
-                jnp.zeros((), jnp.int32)),
-        None, length=max_sweeps)
-    sweeps = jnp.sum(active.astype(jnp.int32))
-    result = RefineResult(
-        assignment=r, loads=loads, num_moves=moves,
-        num_turns=sweeps, converged=done)
-    if not measure_wire:
-        return result, (c0s, ct0s, active)
-    return result, (c0s, ct0s, active), WireMeasurement(
-        rounds=sweeps, payload_bytes=sweeps * measured["sweep"],
-        setup_bytes=jnp.int32(_nbytes((state0.loads, total_b))))
-
-
-# ---------------------------------------------------------------------------
-# Fault-injected drivers (DESIGN.md §15)
+# Fault steps (DESIGN.md §15)
 # ---------------------------------------------------------------------------
 #
-# The faulty drivers re-run the incremental protocol with a FaultPlan row
-# consulted every round: candidates of down / quarantined / undelivered
-# shards are masked out of the election, the election itself prices
-# staleness (``protocol.elect_degraded`` — the 1109.6925 bounded-staleness
-# rule), omitted broadcasts leave a shard's carried aggregate stale, the
-# plan's corruption entries overwrite aggregate columns, and its repair
-# schedule rebuilds + column-patches flagged shards inside the loop via
-# ``lax.cond`` (the rebuild matmul stays off the per-round hot path).  A
-# zero-fault plan reproduces the fault-free drivers bitwise: every
-# degraded branch is gated by a predicate that is constant-false on a
-# clear plan, and ``elect_degraded`` is decision-equivalent to ``elect``
-# at lag 0 (the Winner fields that can differ are all downstream-gated on
-# ``moved``).  Each driver ends with an oracle audit
-# (``_fault_final_audit``): worst carried-vs-recomputed deviation before
-# and after a final guarded patch of the still-alive shards — the public
-# wrappers turn that FaultOutcome into the recover-or-raise contract.
+# With a ``fault_plan`` each round of an incremental driver consults its
+# FaultPlan row: blocked shards' candidates are masked out, the election
+# prices staleness (``protocol.elect_degraded``, the 1109.6925
+# bounded-staleness rule), omitted broadcasts leave a carried aggregate
+# stale, corruption entries overwrite aggregate columns, and the repair
+# schedule column-patches flagged shards inside the loop via ``lax.cond``.
+# A zero-fault plan reproduces the fault-free run bitwise: every degraded
+# branch is gated by a predicate that is constant-false on a clear plan,
+# and ``elect_degraded`` decides as ``elect`` does at lag 0.  The run ends
+# with an oracle audit (:func:`_fault_close`) that the harness turns into
+# the recover-or-raise contract.
 
 class FaultTrace(NamedTuple):
     """Per-round repair side channel of the faulty scan drivers."""
@@ -735,15 +372,6 @@ def _inf_dev(x: Array) -> Array:
     return jnp.nan_to_num(x, nan=jnp.inf, posinf=jnp.inf)
 
 
-def _shard_load_partials(views: ShardViews, weights: Array,
-                         assignment: Array, num_machines: int) -> Array:
-    """(S, K) per-shard load partials for the given per-shard weights."""
-    return jax.vmap(
-        lambda b, ids, v: protocol.shard_load_partial(
-            b, ids, v, assignment, num_machines)
-    )(weights, views.ids, views.valid)
-
-
 def _fault_inject(aggs: Array, row, gate, num_machines: int) -> Array:
     """Overwrite column ``corrupt_col`` of flagged shards with
     ``corrupt_val`` (set semantics — a NaN payload lands as NaN)."""
@@ -751,6 +379,21 @@ def _fault_inject(aggs: Array, row, gate, num_machines: int) -> Array:
                == row.corrupt_col[:, None])                     # (S, K)
     zap = (row.corrupt & gate)[:, None] & colmask
     return jnp.where(zap[:, None, :], row.corrupt_val[:, None, None], aggs)
+
+
+def _mask_blocked(cands: protocol.Candidate, row) -> protocol.Candidate:
+    """Candidates of down / quarantined / undelivered shards cannot win:
+    their gains become -inf ((S,) turn or (S, K) sweep candidates)."""
+    blocked = row.down | row.quarantined | ~row.delivered
+    if cands.gain.ndim == 2:
+        blocked = blocked[:, None]
+    return cands._replace(gain=jnp.where(blocked, -jnp.inf, cands.gain))
+
+
+def _keep_missed(aggs: Array, new_aggs: Array, row) -> Array:
+    """Shards that are down or missed the winner broadcast keep their
+    carried (now stale) block aggregates."""
+    return jnp.where((row.omit | row.down)[:, None, None], aggs, new_aggs)
 
 
 def _fault_repair_cols(views: ShardViews, aggs: Array, assignment: Array,
@@ -768,44 +411,26 @@ def _fault_repair_cols(views: ShardViews, aggs: Array, assignment: Array,
     return patched, drift, cols
 
 
-def _fault_closed_potentials(views: ShardViews, sq_weights: Array,
-                             aggs: Array, assignment: Array, speeds: Array,
-                             mu, total_b, num_machines: int):
-    """Oracle loads + closed-form potentials from the (patched) aggregates
-    — the repair-round resync of the traced driver's carried values."""
-    load_partials = _shard_load_partials(views, views.weights, assignment,
-                                         num_machines)
-    fresh_loads = jnp.sum(load_partials, axis=0)
-    sq_loads = jnp.sum(_shard_load_partials(views, sq_weights, assignment,
-                                            num_machines), axis=0)
-    cut_partials = jax.vmap(
-        lambda agg, ids, v: protocol.shard_cut_partial_from_aggregate(
-            agg, ids, v, assignment)
-    )(aggs, views.ids, views.valid)
-    cut = 0.5 * jnp.sum(cut_partials)
-    c0, ct0 = agg_mod.potentials_closed_form(fresh_loads, sq_loads, cut,
-                                             speeds, mu, total_b)
-    return fresh_loads, c0, ct0
-
-
-def _fault_final_audit(views: ShardViews, fault_plan, aggs: Array,
-                       loads: Array, assignment: Array, last_round,
-                       converged, rtol: float, num_machines: int):
-    """Post-run oracle audit + unconditional guarded patch.
+def _fault_close(views: ShardViews, fault_plan, aggs: Array,
+                 result: RefineResult, last_round, rtol: float,
+                 num_machines: int, counters):
+    """Post-run oracle audit + guarded patch of a faulty run; returns the
+    audited ``(result, FaultOutcome)``.  ``counters`` are the in-loop
+    ``(repairs, repaired_cols, max_repair_drift)`` or the
+    :class:`FaultTrace` they are summed from.
 
     ``final_drift`` is the worst carried-vs-recomputed deviation (columns
-    and loads, NaN → inf) *before* patching; the patch then replaces bad
-    columns of still-alive shards and bad load entries, and
-    ``post_drift`` re-measures.  A shard down on the last executed round
-    of a non-converged run is dead — its columns stay un-patched and the
-    wrapper raises ``DeadShardError`` (a converged run necessarily ended
-    on a fault-clear round, so ``converged`` gates the dead check)."""
+    and loads, NaN → inf) before the patch of still-alive shards' bad
+    columns and bad loads; ``post_drift`` (the ``aggregate_drift``) after
+    it.  A shard down on the last round of a non-converged run is dead:
+    it stays unpatched and the harness raises ``DeadShardError``."""
+    assignment, loads = result.assignment, result.loads
     horizon = fault_plan.down.shape[0] - 1
     last = jnp.clip(last_round, 0, horizon)
-    dead_row = fault_plan.down[last] & ~converged               # (S,)
+    dead_row = fault_plan.down[last] & ~result.converged        # (S,)
     fresh = _init_block_aggregates(views, assignment, num_machines)
     col_dev = jnp.max(jnp.abs(aggs - fresh), axis=1)            # (S, K)
-    fresh_loads = jnp.sum(_shard_load_partials(
+    fresh_loads = jnp.sum(_load_partials(
         views, views.weights, assignment, num_machines), axis=0)
     load_dev = _inf_dev(jnp.abs(loads - fresh_loads))
     final_drift = jnp.maximum(jnp.max(_inf_dev(col_dev)),
@@ -817,31 +442,60 @@ def _fault_final_audit(views: ShardViews, fault_plan, aggs: Array,
     post_drift = jnp.maximum(
         jnp.max(_inf_dev(post_col)),
         jnp.max(_inf_dev(jnp.abs(loads - fresh_loads))))
-    cols = jnp.sum(sel.astype(jnp.int32))
-    return aggs, loads, dead_row, final_drift, post_drift, cols
+    fcols = jnp.sum(sel.astype(jnp.int32))
+    dead = jnp.any(dead_row)
+    if isinstance(counters, FaultTrace):
+        repairs = jnp.sum(counters.repaired.astype(jnp.int32))
+        cols = jnp.sum(counters.repaired_cols) + fcols
+        repair_drift = jnp.max(counters.repair_drift)
+    else:
+        repairs, cols, repair_drift = counters
+        cols = cols + fcols
+    outcome = faults.FaultOutcome(
+        final_drift=final_drift, post_drift=post_drift, dead=dead,
+        repairs=repairs, repaired_cols=cols, max_repair_drift=repair_drift)
+    return result._replace(loads=loads, aggregate_drift=post_drift), outcome
 
 
-@partial(jax.jit, static_argnames=("framework", "num_shards", "max_rounds",
-                                   "cost_fn", "degraded", "measure_wire"))
-def _refine_distributed_faulty(problem: PartitionProblem, assignment: Array,
-                               fault_plan,
-                               framework: str = costs.C_FRAMEWORK,
-                               num_shards: int | None = None,
-                               max_rounds: int = 10_000,
-                               tol: float = DEFAULT_TOL,
-                               cost_fn: str = "jnp",
-                               degraded=faults.DEFAULT_DEGRADED,
-                               theta=None, measure_wire: bool = False):
-    """Fault-injected round-robin driver (incremental protocol only).
+# ---------------------------------------------------------------------------
+# Sequential round-robin turns (paper §4.2 protocol, distributed)
+# ---------------------------------------------------------------------------
 
-    Same election/apply protocol as :func:`_refine_distributed`, plus the
-    per-round degraded machinery described in the section comment above.
-    Convergence idles only accumulate on fault-clear rounds (a blocked
-    no-move round is not evidence of equilibrium).  Returns
-    ``(result, outcome)`` — ``outcome`` is a
-    :class:`repro.distributed.faults.FaultOutcome` of device scalars —
-    plus a :class:`WireMeasurement` when ``measure_wire`` whose payload
-    includes the per-round retry/duplicate/repair extra bytes."""
+@partial(jax.jit, static_argnames=("framework", "num_shards", "max_turns",
+                                   "cost_fn", "incremental", "measure_wire",
+                                   "degraded"))
+def _refine_distributed(problem: PartitionProblem, assignment: Array,
+                        framework: str = costs.C_FRAMEWORK,
+                        num_shards: int | None = None,
+                        max_turns: int = 10_000, tol: float = DEFAULT_TOL,
+                        cost_fn: str = "jnp",
+                        incremental: bool = True,
+                        theta=None, measure_wire: bool = False,
+                        fault_plan=None,
+                        degraded=faults.DEFAULT_DEGRADED):
+    """Distributed round-robin refinement to convergence (K idle turns).
+
+    Protocol per turn: each shard computes one Candidate from local state
+    (16 bytes on the wire), the candidates are all-gathered, every machine
+    elects the same winner and applies the same O(1) delta to its
+    replicated assignment mirror + O(K) load vector — and, on the default
+    incremental path, the same rank-1 update to its carried (Ns, K) block
+    aggregate, so no shard ever rebuilds its aggregate matmul after turn 0.
+
+    ``theta`` (scalar or (N,)) is the migration-price hysteresis threshold
+    (DESIGN.md §11), evaluated shard-locally — the wire stays O(K) and
+    ``theta=None``/``0`` reproduces the threshold-free move sequence
+    bitwise (the core↔distributed contract).
+
+    ``measure_wire=True`` (static) returns ``(result, wire)`` with a
+    :class:`WireMeasurement` of the per-turn candidate exchange
+    (DESIGN.md §14.5); the default jaxpr is unchanged.
+
+    ``fault_plan`` (incremental path only) stages the fault steps under
+    the static ``degraded`` rules: idles count only on fault-clear
+    rounds, the wire includes the fault bytes, and the return is
+    ``(result, outcome)`` (+ wire) with a :class:`faults.FaultOutcome`.
+    """
     k = problem.num_machines
     s = _resolve_shards(problem, num_shards)
     views = build_views(problem, s)
@@ -850,90 +504,105 @@ def _refine_distributed_faulty(problem: PartitionProblem, assignment: Array,
     theta_blocks = _shard_theta(theta, problem, s)
     acc = _acceptance(problem, state0.assignment, tol)
     measured: dict = {}
-    rtol = degraded.repair_tol
-    penalty = degraded.stale_penalty
-    msg = faults.message_bytes(traced=False, simultaneous=False,
-                               num_machines=k)
-    aggs0 = _init_block_aggregates(views, state0.assignment, k)
-    zero_i = jnp.zeros((), jnp.int32)
-    zero_f = jnp.zeros((), jnp.float32)
+    faulty = fault_plan is not None
+    aggs0 = (_init_block_aggregates(views, state0.assignment, k)
+             if incremental else None)
+    if faulty:
+        msg = faults.message_bytes(traced=False, simultaneous=False,
+                                   num_machines=k)
+        zero_i, zero_f = jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
 
     def cond(carry):
-        return (carry[4] < k) & (carry[5] < max_rounds)
+        idle, turns = carry[4], carry[5]
+        return (idle < k) & (turns < max_turns)
 
     def body(carry):
-        (r, loads, aggs, machine, idle, turns, moves,
-         fbytes, repairs, rcols, rdrift) = carry
-        row = faults.plan_row(fault_plan, turns)
-        aggs = _fault_inject(aggs, row, True, k)
-        cands = _vmap_candidates_incremental(
+        r, loads, aggs, machine, idle, turns, moves = carry[:7]
+        row = None
+        if faulty:
+            row = faults.plan_row(fault_plan, turns)
+            aggs = _fault_inject(aggs, row, True, k)
+        cands = _vmap_candidates(
             views, aggs, r, loads, problem.speeds, problem.mu, total_b,
             machine, framework, cost_fn, acc, theta_blocks=theta_blocks)
         measured["turn"] = _nbytes(cands)
-        blocked = row.down | row.quarantined | ~row.delivered
-        cands = cands._replace(gain=jnp.where(blocked, -jnp.inf, cands.gain))
-        winner = protocol.elect_degraded(
-            cands, protocol.candidate_thresholds(
-                cands, acc, machine, loads, problem.speeds, framework),
-            row.lag, penalty)
-        new_aggs = _update_block_aggregates(views, aggs, winner, machine)
-        miss = (row.omit | row.down)[:, None, None]
-        aggs = jnp.where(miss, aggs, new_aggs)
+        winner = _elect(cands, acc, loads, problem, framework, machine,
+                        row, degraded.stale_penalty)
+        if incremental:
+            new_aggs = _update_block_aggregates(views, aggs, winner, machine)
+            aggs = new_aggs if row is None else _keep_missed(aggs, new_aggs,
+                                                             row)
         r, loads = protocol.apply_move(r, loads, winner, machine)
-        idle = jnp.where(winner.moved, 0,
-                         jnp.where(row.clear, idle + 1, idle))
-        do_repair = jnp.any(row.repair)
-        aggs, rd, rc = jax.lax.cond(
-            do_repair,
-            lambda a: _fault_repair_cols(views, a, r, row.repair, rtol, k),
-            lambda a: (a, zero_f, zero_i), aggs)
-        fbytes = fbytes + faults.round_extra_bytes(row, msg)
-        return (r, loads, aggs, (machine + 1) % k, idle, turns + 1,
-                moves + winner.moved.astype(jnp.int32), fbytes,
-                repairs + do_repair.astype(jnp.int32), rcols + rc,
-                jnp.maximum(rdrift, rd))
+        idle = _next_idle(idle, winner.moved, row)
+        if faulty:
+            do_repair = jnp.any(row.repair)
+            aggs, rd, rc = jax.lax.cond(
+                do_repair,
+                lambda a: _fault_repair_cols(views, a, r, row.repair,
+                                             degraded.repair_tol, k),
+                lambda a: (a, zero_f, zero_i), aggs)
+            fbytes = carry[7] + faults.round_extra_bytes(row, msg)
+        new = (r, loads, aggs, (machine + 1) % k, idle, turns + 1,
+               moves + winner.moved.astype(jnp.int32))
+        if not faulty:
+            return new
+        repairs, rcols, rdrift = carry[8:]
+        return new + (fbytes, repairs + do_repair.astype(jnp.int32),
+                      rcols + rc, jnp.maximum(rdrift, rd))
 
-    init = (state0.assignment, state0.loads, aggs0, zero_i, zero_i, zero_i,
-            zero_i, zero_i, zero_i, zero_i, zero_f)
-    (r, loads, aggs, _, idle, turns, moves,
-     fbytes, repairs, rcols, rdrift) = jax.lax.while_loop(cond, body, init)
-    converged = idle >= k
-    aggs, loads, dead_row, final_drift, post_drift, fcols = \
-        _fault_final_audit(views, fault_plan, aggs, loads, r, turns - 1,
-                           converged, rtol, k)
+    init = (state0.assignment, state0.loads, aggs0) + tuple(
+        jnp.zeros((), jnp.int32) for _ in range(4))
+    if faulty:
+        init = init + (zero_i, zero_i, zero_i, zero_f)
+    out = jax.lax.while_loop(cond, body, init)
+    r, loads, aggs, _, idle, turns, moves = out[:7]
     result = RefineResult(assignment=r, loads=loads, num_moves=moves,
-                          num_turns=turns, converged=converged,
-                          aggregate_drift=post_drift)
-    outcome = faults.FaultOutcome(
-        final_drift=final_drift, post_drift=post_drift,
-        dead=jnp.any(dead_row), repairs=repairs,
-        repaired_cols=rcols + fcols, max_repair_drift=rdrift)
-    if not measure_wire:
-        return result, outcome
-    return result, outcome, WireMeasurement(
-        rounds=turns, payload_bytes=turns * measured["turn"] + fbytes,
-        setup_bytes=jnp.int32(_nbytes((state0.loads, total_b))))
+                          num_turns=turns, converged=idle >= k)
+    setup = _nbytes((state0.loads, total_b))
+    if not faulty:
+        return _with_wire((result,), measure_wire, turns, measured["turn"],
+                          setup)
+    result, outcome = _fault_close(views, fault_plan, aggs, result,
+                                   turns - 1, degraded.repair_tol, k,
+                                   out[8:])
+    return _with_wire((result, outcome), measure_wire, turns,
+                      measured["turn"], setup, out[7])
 
 
-@partial(jax.jit, static_argnames=("framework", "num_shards", "max_rounds",
-                                   "cost_fn", "degraded", "measure_wire"))
-def _refine_distributed_traced_faulty(problem: PartitionProblem,
-                                      assignment: Array, fault_plan,
-                                      framework: str = costs.C_FRAMEWORK,
-                                      num_shards: int | None = None,
-                                      max_rounds: int = 512,
-                                      tol: float = DEFAULT_TOL,
-                                      cost_fn: str = "jnp",
-                                      degraded=faults.DEFAULT_DEGRADED,
-                                      theta=None,
-                                      measure_wire: bool = False):
-    """Fault-injected traced driver (incremental protocol only).
+@partial(jax.jit, static_argnames=("framework", "num_shards", "max_turns",
+                                   "cost_fn", "incremental", "measure_wire",
+                                   "degraded"))
+def _refine_distributed_traced(problem: PartitionProblem, assignment: Array,
+                               framework: str = costs.C_FRAMEWORK,
+                               num_shards: int | None = None,
+                               max_turns: int = 512,
+                               tol: float = DEFAULT_TOL,
+                               cost_fn: str = "jnp",
+                               incremental: bool = True,
+                               theta=None, measure_wire: bool = False,
+                               fault_plan=None,
+                               degraded=faults.DEFAULT_DEGRADED):
+    """Fixed-length traced variant; returns ``(RefineResult, Trace)`` with
+    the exact semantics (and, in sequential mode, the exact move sequence)
+    of :func:`repro.core.refine.refine_traced`.
 
-    Carried C_0/Ct_0 follow the winner's exact-potential deltas between
-    repairs; a repair round recomputes them closed-form from the patched
-    aggregates and guard-patches the carried values (relative tolerance,
-    so fault-free float noise never triggers a patch).  Returns
-    ``(result, trace, ftrace, outcome)`` (+ wire)."""
+    On the incremental path the potentials are initialized once from
+    per-shard partials and thereafter updated by the winner's 8-byte
+    exact-potential deltas (Thm. 3.1/5.1) — O(1) wire + O(K) compute per
+    turn, no O(N) pass of any kind.  ``incremental=False`` restores the
+    per-turn partial-reduction recompute.  ``theta`` as in
+    :func:`refine_distributed`.
+
+    ``measure_wire=True`` (static) returns ``(result, trace, wire)``;
+    the per-turn exchange counts the potential deltas (or the recompute
+    partials) and the setup the initial-potential partials.
+
+    ``fault_plan`` as in :func:`_refine_distributed`; a repair round also
+    recomputes C_0/Ct_0 closed-form from the patched aggregates and
+    guard-patches the carried values (relative tolerance, so fault-free
+    float noise never patches).  Returns ``(result, trace, ftrace,
+    outcome)`` (+ wire).
+    """
     k = problem.num_machines
     s = _resolve_shards(problem, num_shards)
     views = build_views(problem, s)
@@ -942,130 +611,152 @@ def _refine_distributed_traced_faulty(problem: PartitionProblem,
     theta_blocks = _shard_theta(theta, problem, s)
     acc = _acceptance(problem, state0.assignment, tol)
     measured: dict = {}
-    setup_base = _nbytes((state0.loads, total_b))
-    rtol = degraded.repair_tol
-    penalty = degraded.stale_penalty
-    msg = faults.message_bytes(traced=True, simultaneous=False,
-                               num_machines=k)
-    sq_weights = views.weights * views.weights
-    aggs0 = _init_block_aggregates(views, state0.assignment, k)
-    c0_init, ct0_init, init_pot_bytes = _vmap_potentials(
-        views, state0.assignment, problem.speeds, problem.mu,
-        total_b, k, fresh_loads=state0.loads)
-    zero_i = jnp.zeros((), jnp.int32)
-    zero_f = jnp.zeros((), jnp.float32)
+    setup = _nbytes((state0.loads, total_b))
+    faulty = fault_plan is not None
+    aggs0 = c0_init = ct0_init = None
+    if faulty:
+        rtol = degraded.repair_tol
+        msg = faults.message_bytes(traced=True, simultaneous=False,
+                                   num_machines=k)
+        sq_weights = views.weights * views.weights
+    if incremental:
+        aggs0 = _init_block_aggregates(views, state0.assignment, k)
+        _, c0_init, ct0_init, init_pot_bytes = _vmap_potentials(
+            views, state0.assignment, problem.speeds, problem.mu,
+            total_b, k, fresh_loads=state0.loads)
+        setup = setup + init_pot_bytes
+    if faulty:
+        zero_i, zero_f = jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
 
     def step(carry, t):
-        r, loads, aggs, c0, ct0, machine, idle, fbytes = carry
+        r, loads, aggs, c0, ct0, machine, idle = carry[:7]
         active = idle < k
-        row = faults.plan_row(fault_plan, t)
-        aggs = _fault_inject(aggs, row, active, k)
-        cands, dc0s, dct0s = _vmap_candidates_incremental(
+        row = None
+        if faulty:
+            row = faults.plan_row(fault_plan, t)
+            aggs = _fault_inject(aggs, row, active, k)
+        cands = _vmap_candidates(
             views, aggs, r, loads, problem.speeds, problem.mu, total_b,
-            machine, framework, cost_fn, acc, with_deltas=True,
+            machine, framework, cost_fn, acc, with_deltas=incremental,
             theta_blocks=theta_blocks)
-        measured["turn"] = _nbytes((cands, dc0s, dct0s))
-        blocked = row.down | row.quarantined | ~row.delivered
-        cands = cands._replace(gain=jnp.where(blocked, -jnp.inf, cands.gain))
-        winner = protocol.elect_degraded(
-            cands, protocol.candidate_thresholds(
-                cands, acc, machine, loads, problem.speeds, framework),
-            row.lag, penalty)
-        moved = winner.moved & active
-        gated = winner._replace(moved=moved)
-        new_aggs = _update_block_aggregates(views, aggs, gated, machine)
-        miss = (row.omit | row.down)[:, None, None]
-        new_aggs = jnp.where(miss, aggs, new_aggs)
-        new_r, new_loads = protocol.apply_move(r, loads, gated, machine)
-        new_c0 = jnp.where(moved, c0 + dc0s[winner.shard], c0)
-        new_ct0 = jnp.where(moved, ct0 + dct0s[winner.shard], ct0)
-        idle = jnp.where(moved, 0, jnp.where(row.clear, idle + 1, idle))
-        do_repair = jnp.any(row.repair) & active
+        if incremental:
+            cands, dc0s, dct0s = cands
+            measured["turn"] = _nbytes((cands, dc0s, dct0s))
+        winner = _elect(cands, acc, loads, problem, framework, machine,
+                        row, degraded.stale_penalty)
+        if incremental:
+            moved = winner.moved & active
+            gated = winner._replace(moved=moved)
+            aggs_next = _update_block_aggregates(views, aggs, gated, machine)
+            if faulty:
+                aggs_next = _keep_missed(aggs, aggs_next, row)
+            new_r, new_loads = protocol.apply_move(r, loads, gated, machine)
+            new_c0 = jnp.where(moved, c0 + dc0s[winner.shard], c0)
+            new_ct0 = jnp.where(moved, ct0 + dct0s[winner.shard], ct0)
+            idle = _next_idle(idle, moved, row)
+        else:
+            new_r, new_loads = protocol.apply_move(r, loads, winner, machine)
+            new_r = jnp.where(active, new_r, r)
+            new_loads = jnp.where(active, new_loads, loads)
+            moved = winner.moved & active
+            idle = _next_idle(idle, moved)
+            _, new_c0, new_ct0, pot_bytes = _vmap_potentials(
+                views, new_r, problem.speeds, problem.mu, total_b, k)
+            measured["turn"] = _nbytes(cands) + pot_bytes
+        if faulty:
+            do_repair = jnp.any(row.repair) & active
 
-        def with_repair(ops):
-            aggs_, loads_, c0_, ct0_ = ops
-            patched, rd, rc = _fault_repair_cols(views, aggs_, new_r,
-                                                 row.repair, rtol, k)
-            fl, c0f, ct0f = _fault_closed_potentials(
-                views, sq_weights, patched, new_r, problem.speeds,
-                problem.mu, total_b, k)
+            def with_repair(ops):
+                aggs_, loads_, c0_, ct0_ = ops
+                patched, rd, rc = _fault_repair_cols(views, aggs_, new_r,
+                                                     row.repair, rtol, k)
+                fl, c0f, ct0f, _ = _closed_potentials(
+                    views, sq_weights, patched, new_r, problem.speeds,
+                    problem.mu, total_b, k)
 
-            def guard(x, fresh):
-                bad = ~(jnp.abs(x - fresh)
-                        <= rtol * jnp.maximum(1.0, jnp.abs(fresh)))
-                return jnp.where(bad, fresh, x)
+                def guard(x, fresh):
+                    bad = ~(jnp.abs(x - fresh)
+                            <= rtol * jnp.maximum(1.0, jnp.abs(fresh)))
+                    return jnp.where(bad, fresh, x)
 
-            loads2 = jnp.where(~(jnp.abs(loads_ - fl) <= rtol), fl, loads_)
-            return patched, loads2, guard(c0_, c0f), guard(ct0_, ct0f), rd, rc
+                loads2 = jnp.where(~(jnp.abs(loads_ - fl) <= rtol), fl,
+                                   loads_)
+                return (patched, loads2, guard(c0_, c0f), guard(ct0_, ct0f),
+                        rd, rc)
 
-        def without(ops):
-            aggs_, loads_, c0_, ct0_ = ops
-            return aggs_, loads_, c0_, ct0_, zero_f, zero_i
-
-        new_aggs, new_loads, new_c0, new_ct0, rd, rc = jax.lax.cond(
-            do_repair, with_repair, without,
-            (new_aggs, new_loads, new_c0, new_ct0))
-        fbytes = fbytes + jnp.where(
-            active, faults.round_extra_bytes(row, msg), 0)
-        out = (Trace(moved=moved,
-                     node=jnp.where(winner.moved, winner.node, -1),
-                     source=jnp.where(winner.moved, machine, -1),
-                     dest=jnp.where(winner.moved, winner.dest, -1),
-                     gain=jnp.where(winner.moved, winner.gain, 0.0),
-                     c0=new_c0, ct0=new_ct0, active=active),
-               FaultTrace(repaired=do_repair, repair_drift=rd,
-                          repaired_cols=rc))
-        return (new_r, new_loads, new_aggs, new_c0, new_ct0,
-                (machine + 1) % k, idle, fbytes), out
+            aggs_next, new_loads, new_c0, new_ct0, rd, rc = jax.lax.cond(
+                do_repair, with_repair, lambda ops: ops + (zero_f, zero_i),
+                (aggs_next, new_loads, new_c0, new_ct0))
+            fbytes = carry[7] + jnp.where(
+                active, faults.round_extra_bytes(row, msg), 0)
+        out = _trace_row(winner, moved, machine, new_c0, new_ct0, active)
+        carried = ((aggs_next, new_c0, new_ct0) if incremental
+                   else (None, None, None))
+        new = (new_r, new_loads, *carried, (machine + 1) % k, idle)
+        if not faulty:
+            return new, out
+        return new + (fbytes,), (out, FaultTrace(
+            repaired=do_repair, repair_drift=rd, repaired_cols=rc))
 
     init = (state0.assignment, state0.loads, aggs0, c0_init, ct0_init,
-            zero_i, zero_i, zero_i)
-    (r, loads, aggs, _, _, _, idle, fbytes), (trace, ftrace) = jax.lax.scan(
-        step, init, jnp.arange(max_rounds))
+            jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
+    if faulty:
+        init = init + (zero_i,)
+    final, trace = jax.lax.scan(
+        step, init, jnp.arange(max_turns) if faulty else None,
+        length=max_turns)
+    r, loads, aggs, _, _, _, idle = final[:7]
+    if faulty:
+        trace, ftrace = trace
     moves = jnp.sum(trace.moved.astype(jnp.int32))
     turns = jnp.sum(trace.active.astype(jnp.int32))
-    converged = idle >= k
-    aggs, loads, dead_row, final_drift, post_drift, fcols = \
-        _fault_final_audit(views, fault_plan, aggs, loads, r, turns - 1,
-                           converged, rtol, k)
     result = RefineResult(assignment=r, loads=loads, num_moves=moves,
-                          num_turns=turns, converged=converged,
-                          aggregate_drift=post_drift)
-    outcome = faults.FaultOutcome(
-        final_drift=final_drift, post_drift=post_drift,
-        dead=jnp.any(dead_row),
-        repairs=jnp.sum(ftrace.repaired.astype(jnp.int32)),
-        repaired_cols=jnp.sum(ftrace.repaired_cols) + fcols,
-        max_repair_drift=jnp.max(ftrace.repair_drift))
-    if not measure_wire:
-        return result, trace, ftrace, outcome
-    return result, trace, ftrace, outcome, WireMeasurement(
-        rounds=turns, payload_bytes=turns * measured["turn"] + fbytes,
-        setup_bytes=jnp.int32(setup_base + init_pot_bytes))
+                          num_turns=turns, converged=idle >= k)
+    if not faulty:
+        return _with_wire((result, trace), measure_wire, turns,
+                          measured["turn"], setup)
+    result, outcome = _fault_close(views, fault_plan, aggs, result,
+                                   turns - 1, rtol, k, ftrace)
+    return _with_wire((result, trace, ftrace, outcome), measure_wire,
+                      turns, measured["turn"], setup, final[7])
 
 
-@partial(jax.jit, static_argnames=("framework", "num_shards", "max_rounds",
-                                   "cost_fn", "degraded", "measure_wire"))
-def _refine_distributed_simultaneous_faulty(problem: PartitionProblem,
-                                            assignment: Array, fault_plan,
-                                            framework: str = costs.C_FRAMEWORK,
-                                            num_shards: int | None = None,
-                                            max_rounds: int = 256,
-                                            tol: float = DEFAULT_TOL,
-                                            cost_fn: str = "jnp",
-                                            degraded=faults.DEFAULT_DEGRADED,
-                                            theta=None,
-                                            measure_wire: bool = False):
-    """Fault-injected §4.5 sweep driver (incremental protocol only).
+# ---------------------------------------------------------------------------
+# §4.5 simultaneous sweeps, distributed
+# ---------------------------------------------------------------------------
 
-    The sweep can only latch ``done`` on a fault-clear no-move round — a
-    blocked round proves nothing about equilibrium.  Wire counts the
-    executed (non-done) rounds: ``counted = ~done & (any_move | ~clear)``
-    reduces to the fault-free active-sweep count on a zero plan, and the
-    counted rounds always form a prefix, which is what keeps the host-side
-    ledger (``faults.plan_extra_bytes``) byte-exact against the device
-    accumulator.  Returns ``(result, (c0s, ct0s, counted), ftrace,
-    outcome)`` (+ wire)."""
+@partial(jax.jit, static_argnames=("framework", "num_shards", "max_sweeps",
+                                   "cost_fn", "incremental", "measure_wire",
+                                   "degraded"))
+def _refine_distributed_simultaneous(problem: PartitionProblem,
+                                     assignment: Array,
+                                     framework: str = costs.C_FRAMEWORK,
+                                     num_shards: int | None = None,
+                                     max_sweeps: int = 256,
+                                     tol: float = DEFAULT_TOL,
+                                     cost_fn: str = "jnp",
+                                     incremental: bool = True,
+                                     theta=None, measure_wire: bool = False,
+                                     fault_plan=None,
+                                     degraded=faults.DEFAULT_DEGRADED):
+    """Distributed §4.5 sweeps: each shard ships K candidates per sweep
+    (one per machine), elections run per machine, all K disjoint moves
+    apply at once as a rank-K block-aggregate update.  Exchange per sweep:
+    S*K candidates + S load/sq-load/cut partials — still independent of N.
+
+    ``num_moves`` counts actual transfers (sum of per-sweep movers), not
+    the K*sweeps upper bound.  ``theta`` as in :func:`refine_distributed`.
+
+    ``measure_wire=True`` (static) returns ``(result, traces, wire)``;
+    ``rounds`` counts active sweeps, like ``num_turns``.
+
+    ``fault_plan`` as in :func:`_refine_distributed`: ``done`` latches
+    only on a fault-clear no-move round, and the wire counts the executed
+    rounds, ``counted = ~done & (any_move | ~clear)`` — the active sweeps
+    on a zero plan, and always a prefix, which keeps the host ledger
+    (``faults.plan_extra_bytes``) byte-exact.  Returns ``(result, (c0s,
+    ct0s, counted), ftrace, outcome)`` (+ wire).
+    """
     k = problem.num_machines
     s = _resolve_shards(problem, num_shards)
     views = build_views(problem, s)
@@ -1075,104 +766,112 @@ def _refine_distributed_simultaneous_faulty(problem: PartitionProblem,
     theta_blocks = _shard_theta(theta, problem, s)
     acc = _acceptance(problem, state0.assignment, tol)
     measured: dict = {}
-    rtol = degraded.repair_tol
-    penalty = degraded.stale_penalty
-    msg = faults.message_bytes(traced=False, simultaneous=True,
-                               num_machines=k)
-    dissat_fn = _shard_dissat_fn(cost_fn)
-    aggs0 = _init_block_aggregates(views, state0.assignment, k)
-    zero_i = jnp.zeros((), jnp.int32)
-    zero_f = jnp.zeros((), jnp.float32)
+    faulty = fault_plan is not None
+    aggs0 = (_init_block_aggregates(views, state0.assignment, k)
+             if incremental else None)
+    if faulty:
+        msg = faults.message_bytes(traced=False, simultaneous=True,
+                                   num_machines=k)
+        zero_i, zero_f = jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
 
-    def _sweep_cands(aggs, r, loads):
+    def sweep_candidates(aggs, r, loads):
+        """(S, K) candidates from ``aggs``, or the row blocks if None."""
+        if aggs is None:
+            shard_cost = _shard_cost_fn(cost_fn)
+
+            def one(rb, b, ids, v, th):
+                return protocol.local_candidates_all_machines(
+                    rb, b, ids, v, r, loads, problem.speeds, problem.mu,
+                    total_b, framework, cost_matrix_fn=shard_cost,
+                    theta_local=th)
+
+            return _vmap_shards(one, theta_blocks, views.row_block,
+                                views.weights, views.ids, views.valid)
+        dissat_fn = _shard_dissat_fn(cost_fn)
+
         def one(agg, b, ids, v, th):
             return protocol.local_candidates_all_machines_from_aggregate(
                 agg, b, ids, v, r, loads, problem.speeds, problem.mu,
                 total_b, framework, dissat_fn=dissat_fn, theta_local=th)
 
         return _vmap_shards(one, theta_blocks, aggs, views.weights,
-                            views.ids, views.valid)              # (S, K)
+                            views.ids, views.valid)
 
     def sweep(carry, t):
-        r, loads, aggs, done, moves, fbytes = carry
-        row = faults.plan_row(fault_plan, t)
-        aggs = _fault_inject(aggs, row, ~done, k)
-        cands = _sweep_cands(aggs, r, loads)
-        blocked = row.down | row.quarantined | ~row.delivered
-        cands = cands._replace(
-            gain=jnp.where(blocked[:, None], -jnp.inf, cands.gain))
-        winners = _elect_sweep(cands, acc, loads, problem, framework,
-                               row.lag, penalty)                   # (K,)
+        r, loads, aggs, done, moves = carry[:5]
+        row = None
+        if faulty:
+            row = faults.plan_row(fault_plan, t)
+            aggs = _fault_inject(aggs, row, ~done, k)
+        cands = sweep_candidates(aggs, r, loads)                # (S, K)
+        winners = _elect(cands, acc, loads, problem, framework, None, row,
+                         degraded.stale_penalty)                  # (K,)
         any_move = jnp.any(winners.moved) & ~done
+        # Idle machines elect a fallback candidate (all gains -inf) whose
+        # node id may collide with a real move — mask their columns / drop
+        # their writes instead of racing the update.
         safe_picks = jnp.where(winners.moved, winners.node,
                                jnp.int32(problem.num_nodes))
         new_r = r.at[safe_picks].set(winners.dest, mode="drop")
         new_r = jnp.where(any_move, new_r, r)
-        new_aggs = jax.vmap(
-            lambda agg, rb: protocol.update_block_aggregate_sweep(
-                agg, rb, winners.node, winners.dest, winners.moved)
-        )(aggs, views.row_block)
-        new_aggs = jnp.where(any_move, new_aggs, aggs)
-        miss = (row.omit | row.down)[:, None, None]
-        new_aggs = jnp.where(miss, aggs, new_aggs)
-        do_repair = jnp.any(row.repair) & ~done
-        new_aggs, rd, rc = jax.lax.cond(
-            do_repair,
-            lambda a: _fault_repair_cols(views, a, new_r, row.repair,
-                                         rtol, k),
-            lambda a: (a, zero_f, zero_i), new_aggs)
-        load_partials = jax.vmap(
-            lambda b, ids, v: protocol.shard_load_partial(
-                b, ids, v, new_r, k)
-        )(views.weights, views.ids, views.valid)
-        new_loads = jnp.sum(load_partials, axis=0)
-        sq_partials = jax.vmap(
-            lambda b2, ids, v: protocol.shard_load_partial(
-                b2, ids, v, new_r, k)
-        )(sq_weights, views.ids, views.valid)
-        sq_loads = jnp.sum(sq_partials, axis=0)
-        cut_partials = jax.vmap(
-            lambda agg, ids, v: protocol.shard_cut_partial_from_aggregate(
-                agg, ids, v, new_r)
-        )(new_aggs, views.ids, views.valid)
-        measured["sweep"] = _nbytes(
-            (cands, load_partials, sq_partials, cut_partials))
-        cut = 0.5 * jnp.sum(cut_partials)
-        c0, ct0 = agg_mod.potentials_closed_form(
-            new_loads, sq_loads, cut, problem.speeds, problem.mu, total_b)
+        new_aggs = None
+        if incremental:
+            new_aggs = jax.vmap(
+                lambda agg, rb: protocol.update_block_aggregate_sweep(
+                    agg, rb, winners.node, winners.dest, winners.moved)
+            )(aggs, views.row_block)
+            new_aggs = jnp.where(any_move, new_aggs, aggs)
+        if faulty:
+            new_aggs = _keep_missed(aggs, new_aggs, row)
+            do_repair = jnp.any(row.repair) & ~done
+            new_aggs, rd, rc = jax.lax.cond(
+                do_repair,
+                lambda a: _fault_repair_cols(views, a, new_r, row.repair,
+                                             degraded.repair_tol, k),
+                lambda a: (a, zero_f, zero_i), new_aggs)
+        if incremental:
+            new_loads, c0, ct0, pot_bytes = _closed_potentials(
+                views, sq_weights, new_aggs, new_r, problem.speeds,
+                problem.mu, total_b, k)
+        else:
+            new_loads, c0, ct0, pot_bytes = _vmap_potentials(
+                views, new_r, problem.speeds, problem.mu, total_b, k)
+        measured["sweep"] = _nbytes(cands) + pot_bytes
         moves = moves + jnp.where(
             any_move, jnp.sum(winners.moved.astype(jnp.int32)), 0)
+        if not faulty:
+            return ((new_r, new_loads, new_aggs, done | ~any_move, moves),
+                    (c0, ct0, any_move))
         counted = ~done & (any_move | ~row.clear)
-        fbytes = fbytes + jnp.where(
+        fbytes = carry[5] + jnp.where(
             counted, faults.round_extra_bytes(row, msg), 0)
         new_done = done | (~any_move & row.clear)
         return ((new_r, new_loads, new_aggs, new_done, moves, fbytes),
-                ((c0, ct0, counted),
-                 FaultTrace(repaired=do_repair, repair_drift=rd,
-                            repaired_cols=rc)))
+                ((c0, ct0, counted), FaultTrace(
+                    repaired=do_repair, repair_drift=rd, repaired_cols=rc)))
 
-    (r, loads, aggs, done, moves, fbytes), ((c0s, ct0s, active), ftrace) = \
-        jax.lax.scan(sweep, (state0.assignment, state0.loads, aggs0,
-                             jnp.zeros((), bool), zero_i, zero_i),
-                     jnp.arange(max_rounds))
-    sweeps = jnp.sum(active.astype(jnp.int32))
-    aggs, loads, dead_row, final_drift, post_drift, fcols = \
-        _fault_final_audit(views, fault_plan, aggs, loads, r,
-                           max_rounds - 1, done, rtol, k)
+    init = (state0.assignment, state0.loads, aggs0, jnp.zeros((), bool),
+            jnp.zeros((), jnp.int32))
+    if faulty:
+        init = init + (zero_i,)
+    final, outs = jax.lax.scan(
+        sweep, init, jnp.arange(max_sweeps) if faulty else None,
+        length=max_sweeps)
+    r, loads, aggs, done, moves = final[:5]
+    if faulty:
+        outs, ftrace = outs
+    sweeps = jnp.sum(outs[2].astype(jnp.int32))
     result = RefineResult(assignment=r, loads=loads, num_moves=moves,
-                          num_turns=sweeps, converged=done,
-                          aggregate_drift=post_drift)
-    outcome = faults.FaultOutcome(
-        final_drift=final_drift, post_drift=post_drift,
-        dead=jnp.any(dead_row),
-        repairs=jnp.sum(ftrace.repaired.astype(jnp.int32)),
-        repaired_cols=jnp.sum(ftrace.repaired_cols) + fcols,
-        max_repair_drift=jnp.max(ftrace.repair_drift))
-    if not measure_wire:
-        return result, (c0s, ct0s, active), ftrace, outcome
-    return result, (c0s, ct0s, active), ftrace, outcome, WireMeasurement(
-        rounds=sweeps, payload_bytes=sweeps * measured["sweep"] + fbytes,
-        setup_bytes=jnp.int32(_nbytes((state0.loads, total_b))))
+                          num_turns=sweeps, converged=done)
+    setup = _nbytes((state0.loads, total_b))
+    if not faulty:
+        return _with_wire((result, outs), measure_wire, sweeps,
+                          measured["sweep"], setup)
+    result, outcome = _fault_close(views, fault_plan, aggs, result,
+                                   max_sweeps - 1, degraded.repair_tol, k,
+                                   ftrace)
+    return _with_wire((result, outs, ftrace, outcome), measure_wire, sweeps,
+                      measured["sweep"], setup, final[5])
 
 
 # ---------------------------------------------------------------------------
@@ -1202,12 +901,12 @@ def refine_distributed_shard_map(problem: PartitionProblem, assignment: Array,
     host platform via ``XLA_FLAGS``; on one device it degenerates to a
     1-shard mesh (still the collective code path).
 
-    ``measure_wire=True`` returns ``(result, wire)`` with a
-    :class:`WireMeasurement` whose payload counts the real
-    ``lax.all_gather`` output buffers per turn (DESIGN.md §14.5).
-    ``recorder`` (a :class:`repro.obs.Recorder`) opts into run telemetry:
-    a phase-timed ``run_start``/``wire``/``run_end`` stream with the
-    measured bytes reconciled against the analytic ledger.
+    ``measure_wire=True`` returns ``(result, wire)``, the payload
+    counting the real ``lax.all_gather`` buffers per turn; ``recorder``
+    as in :func:`refine_distributed`.  ``fault_plan`` as there too
+    (DESIGN.md §15.3): the plan rides replicated (one ``P()`` spec), each
+    device masks/injects/repairs only its own block (``lax.axis_index``)
+    and the outcome scalars reduce with ``pmax``/``psum``.
     """
     k = problem.num_machines
     if devices is None:
@@ -1230,25 +929,33 @@ def refine_distributed_shard_map(problem: PartitionProblem, assignment: Array,
     if theta_blocks is None:
         theta_blocks = jnp.zeros((s, views.shard_size), jnp.float32)
     acc = _acceptance(problem, state0.assignment, tol)
-
-    if fault_plan is not None:
-        return _shard_map_faulty_run(
-            problem, assignment, fault_plan, framework, s, mesh, views,
-            state0, total_b, theta_blocks, theta, max_turns, acc,
-            degraded or faults.DEFAULT_DEGRADED, measure_wire, recorder)
-
+    faulty = fault_plan is not None
+    dm = degraded or faults.DEFAULT_DEGRADED
+    rtol = dm.repair_tol
+    msg = faults.message_bytes(traced=False, simultaneous=False,
+                               num_machines=k)
     measured: dict = {}
 
-    def spmd(rb, b, ids, valid, th, r0, loads0, speeds, mu, tot):
+    def spmd(rb, b, ids, valid, th, r0, loads0, speeds, mu, tot, *plan):
         rb, b, ids, valid, th = rb[0], b[0], ids[0], valid[0], th[0]
+        if faulty:
+            (plan,) = plan
+            idx = jax.lax.axis_index("shards")
         agg0 = protocol.block_aggregate(rb, r0, k)   # once, O(Ns·N·K)
 
         def cond(carry):
-            _, _, _, _, idle, turns, _ = carry
+            idle, turns = carry[4], carry[5]
             return (idle < k) & (turns < max_turns)
 
         def body(carry):
-            r, loads, agg, machine, idle, turns, moves = carry
+            r, loads, agg, machine, idle, turns, moves = carry[:7]
+            row = None
+            if faulty:
+                row = faults.plan_row(plan, turns)
+                colmask = (jnp.arange(k, dtype=jnp.int32)
+                           == row.corrupt_col[idx])
+                agg = jnp.where(row.corrupt[idx] & colmask[None, :],
+                                row.corrupt_val[idx], agg)
             cand = protocol.local_candidate_from_aggregate(
                 agg, b, ids, valid, r, loads, speeds, mu, tot, machine,
                 framework, acc, theta_local=th)
@@ -1258,146 +965,46 @@ def refine_distributed_shard_map(problem: PartitionProblem, assignment: Array,
                 dest=jax.lax.all_gather(cand.dest, "shards"),
                 weight=jax.lax.all_gather(cand.weight, "shards"))
             measured["turn"] = _nbytes(cands)
-            winner = protocol.elect(
-                cands, protocol.candidate_thresholds(
-                    cands, acc, machine, loads, problem.speeds, framework))
-            agg = protocol.update_block_aggregate(
-                agg, rb, winner.node, machine, winner.dest, winner.moved)
-            r, loads = protocol.apply_move(r, loads, winner, machine)
-            idle = jnp.where(winner.moved, 0, idle + 1)
-            return (r, loads, agg, (machine + 1) % k, idle, turns + 1,
-                    moves + winner.moved.astype(jnp.int32))
-
-        init = (r0, loads0, agg0, jnp.zeros((), jnp.int32),
-                jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32),
-                jnp.zeros((), jnp.int32))
-        r, loads, _, _, idle, turns, moves = jax.lax.while_loop(
-            cond, body, init)
-        return r, loads, moves, turns, idle >= k
-
-    sharded = P("shards")
-    rep = P()
-    fn = jax.shard_map(spmd, mesh=mesh,
-                       in_specs=(sharded, sharded, sharded, sharded, sharded,
-                                 rep, rep, rep, rep, rep),
-                       out_specs=(rep, rep, rep, rep, rep),
-                       check_vma=False)
-    run = (None if recorder is None else
-           _open_run(recorder, "shard_map", problem, assignment, framework,
-                     theta, num_shards=s))
-    args = (views.row_block, views.weights, views.ids, views.valid,
-            theta_blocks, state0.assignment, state0.loads, problem.speeds,
-            problem.mu, total_b)
-    t0 = time.perf_counter()
-    if recorder is None:
-        r, loads, moves, turns, converged = jax.jit(fn)(*args)
-    else:
-        with recorder.phase("distributed.shard_map", run):
-            out = jax.jit(fn)(*args)
-            jax.block_until_ready(out)
-        r, loads, moves, turns, converged = out
-    wall = time.perf_counter() - t0
-    result = RefineResult(assignment=r, loads=loads, num_moves=moves,
-                          num_turns=turns, converged=converged)
-    if not (measure_wire or recorder is not None):
-        return result
-    # jax.jit(fn) is freshly constructed above, so tracing always ran
-    # this call and populated measured["turn"] with the gathered
-    # candidates' buffer size.
-    rounds = int(np.asarray(turns))
-    wire = WireMeasurement(
-        rounds=jnp.int32(rounds),
-        payload_bytes=jnp.int32(rounds * measured["turn"]),
-        setup_bytes=jnp.int32(_nbytes((state0.loads, total_b))))
-    if recorder is not None:
-        _record_wire(recorder, run, problem, s, wire)
-        recorder.record_result(run, result, wall=wall)
-    return (result, wire) if measure_wire else result
-
-
-def _shard_map_faulty_run(problem: PartitionProblem, assignment: Array,
-                          fault_plan, framework: str, s: int, mesh, views,
-                          state0, total_b, theta_blocks, theta,
-                          max_turns: int, acc: Acceptance, degraded,
-                          measure_wire: bool, recorder):
-    """Real-mesh faulty path (DESIGN.md §15.3): the FaultPlan rides
-    replicated (one ``P()`` spec covers the whole pytree); each device
-    masks/injects/repairs only its *own* block (``lax.axis_index``), the
-    outcome scalars reduce with ``pmax``/``psum``, and the wrapper-level
-    recover-or-raise audit is identical to the emulated drivers."""
-    k = problem.num_machines
-    rtol = degraded.repair_tol
-    penalty = degraded.stale_penalty
-    msg = faults.message_bytes(traced=False, simultaneous=False,
-                               num_machines=k)
-    horizon = int(np.asarray(fault_plan.down).shape[0]) - 1
-    measured: dict = {}
-
-    def spmd(rb, b, ids, valid, th, r0, loads0, speeds, mu, tot, plan):
-        rb, b, ids, valid, th = rb[0], b[0], ids[0], valid[0], th[0]
-        idx = jax.lax.axis_index("shards")
-        agg0 = protocol.block_aggregate(rb, r0, k)
-        zero_i = jnp.zeros((), jnp.int32)
-        zero_f = jnp.zeros((), jnp.float32)
-
-        def cond(carry):
-            return (carry[4] < k) & (carry[5] < max_turns)
-
-        def body(carry):
-            (r, loads, agg, machine, idle, turns, moves,
-             fbytes, repairs, rcols, rdrift) = carry
-            row = faults.plan_row(plan, turns)
-            colmask = (jnp.arange(k, dtype=jnp.int32)
-                       == row.corrupt_col[idx])
-            agg = jnp.where(row.corrupt[idx] & colmask[None, :],
-                            row.corrupt_val[idx], agg)
-            cand = protocol.local_candidate_from_aggregate(
-                agg, b, ids, valid, r, loads, speeds, mu, tot, machine,
-                framework, acc, theta_local=th)
-            cands = protocol.Candidate(
-                gain=jax.lax.all_gather(cand.gain, "shards"),
-                node=jax.lax.all_gather(cand.node, "shards"),
-                dest=jax.lax.all_gather(cand.dest, "shards"),
-                weight=jax.lax.all_gather(cand.weight, "shards"))
-            measured["turn"] = _nbytes(cands)
-            blocked = row.down | row.quarantined | ~row.delivered
-            cands = cands._replace(
-                gain=jnp.where(blocked, -jnp.inf, cands.gain))
-            winner = protocol.elect_degraded(
-                cands, protocol.candidate_thresholds(
-                    cands, acc, machine, loads, problem.speeds, framework),
-                row.lag, penalty)
+            winner = _elect(cands, acc, loads, problem, framework, machine,
+                            row, dm.stale_penalty)
             new_agg = protocol.update_block_aggregate(
                 agg, rb, winner.node, machine, winner.dest, winner.moved)
-            agg = jnp.where(row.omit[idx] | row.down[idx], agg, new_agg)
+            agg = (new_agg if row is None else
+                   jnp.where(row.omit[idx] | row.down[idx], agg, new_agg))
             r, loads = protocol.apply_move(r, loads, winner, machine)
-            idle = jnp.where(winner.moved, 0,
-                             jnp.where(row.clear, idle + 1, idle))
+            idle = _next_idle(idle, winner.moved, row)
+            if faulty:
+                def with_repair(a):
+                    fresh = protocol.block_aggregate(rb, r, k)
+                    col_dev = jnp.max(jnp.abs(a - fresh), axis=0)   # (K,)
+                    colbad = ~(col_dev <= rtol)
+                    patched = jnp.where(colbad[None, :], fresh, a)
+                    return (patched, jnp.max(_inf_dev(col_dev)),
+                            jnp.sum(colbad.astype(jnp.int32)))
 
-            def with_repair(a):
-                fresh = protocol.block_aggregate(rb, r, k)
-                col_dev = jnp.max(jnp.abs(a - fresh), axis=0)    # (K,)
-                colbad = ~(col_dev <= rtol)
-                patched = jnp.where(colbad[None, :], fresh, a)
-                return (patched, jnp.max(_inf_dev(col_dev)),
-                        jnp.sum(colbad.astype(jnp.int32)))
+                agg, rd, rc = jax.lax.cond(
+                    row.repair[idx], with_repair,
+                    lambda a: (a, zero_f, zero_i), agg)
+                fbytes = carry[7] + faults.round_extra_bytes(row, msg)
+            new = (r, loads, agg, (machine + 1) % k, idle, turns + 1,
+                   moves + winner.moved.astype(jnp.int32))
+            if not faulty:
+                return new
+            repairs, rcols, rdrift = carry[8:]
+            return new + (fbytes, repairs + row.repair[idx].astype(jnp.int32),
+                          rcols + rc, jnp.maximum(rdrift, rd))
 
-            agg, rd, rc = jax.lax.cond(
-                row.repair[idx], with_repair,
-                lambda a: (a, zero_f, zero_i), agg)
-            fbytes = fbytes + faults.round_extra_bytes(row, msg)
-            return (r, loads, agg, (machine + 1) % k, idle, turns + 1,
-                    moves + winner.moved.astype(jnp.int32), fbytes,
-                    repairs + row.repair[idx].astype(jnp.int32),
-                    rcols + rc, jnp.maximum(rdrift, rd))
-
-        init = (r0, loads0, agg0) + tuple(
-            jnp.zeros((), jnp.int32) for _ in range(7)) + (
-            jnp.zeros((), jnp.float32),)
-        (r, loads, agg, _, idle, turns, moves, fbytes,
-         repairs, rcols, rdrift) = jax.lax.while_loop(cond, body, init)
+        zero_i, zero_f = jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32)
+        init = (r0, loads0, agg0, zero_i, zero_i, zero_i, zero_i)
+        if faulty:
+            init = init + (zero_i, zero_i, zero_i, zero_f)
+        out = jax.lax.while_loop(cond, body, init)
+        r, loads, agg, _, idle, turns, moves = out[:7]
+        if not faulty:
+            return r, loads, moves, turns, idle >= k
+        fbytes, repairs, rcols, rdrift = out[7:]
         converged = idle >= k
-        last = jnp.clip(turns - 1, 0, horizon)
+        last = jnp.clip(turns - 1, 0, plan.down.shape[0] - 1)
         dead_row = plan.down[last] & ~converged
         fresh = protocol.block_aggregate(rb, r, k)
         col_dev = jnp.max(jnp.abs(agg - fresh), axis=0)
@@ -1424,169 +1031,157 @@ def _shard_map_faulty_run(problem: PartitionProblem, assignment: Array,
 
     sharded, rep = P("shards"), P()
     fn = jax.shard_map(spmd, mesh=mesh,
-                       in_specs=(sharded,) * 5 + (rep,) * 6,
-                       out_specs=(rep,) * 12, check_vma=False)
-    run = (None if recorder is None else
-           _open_run(recorder, "shard_map", problem, assignment, framework,
-                     theta, num_shards=s, faults=True))
+                       in_specs=(sharded,) * 5 + (rep,) * (6 if faulty else 5),
+                       out_specs=(rep,) * (12 if faulty else 5),
+                       check_vma=False)
     args = (views.row_block, views.weights, views.ids, views.valid,
             theta_blocks, state0.assignment, state0.loads, problem.speeds,
-            problem.mu, total_b, fault_plan)
-    t0 = time.perf_counter()
-    if recorder is None:
+            problem.mu, total_b) + ((fault_plan,) if faulty else ())
+
+    def call(mw: bool):
         out = jax.jit(fn)(*args)
-        jax.block_until_ready(out)
-    else:
-        with recorder.phase("distributed.shard_map", run):
-            out = jax.jit(fn)(*args)
-            jax.block_until_ready(out)
-    wall = time.perf_counter() - t0
-    (r, loads, moves, turns, converged, fbytes, final_drift, post_drift,
-     dead, repairs, rcols, rdrift) = out
-    result = RefineResult(assignment=r, loads=loads, num_moves=moves,
-                          num_turns=turns, converged=converged,
-                          aggregate_drift=post_drift)
-    outcome = faults.FaultOutcome(
-        final_drift=final_drift, post_drift=post_drift, dead=dead,
-        repairs=repairs, repaired_cols=rcols, max_repair_drift=rdrift)
-    rounds = int(np.asarray(turns))
-    report = faults.build_report(fault_plan, outcome, rounds,
-                                 budget=degraded.repair_tol,
-                                 raise_on_failure=False)
-    wire = None
-    if measure_wire or recorder is not None:
-        wire = WireMeasurement(
-            rounds=jnp.int32(rounds),
-            payload_bytes=jnp.int32(rounds * measured["turn"]
-                                    + int(np.asarray(fbytes))),
-            setup_bytes=jnp.int32(_nbytes((state0.loads, total_b))))
+        result = RefineResult(assignment=out[0], loads=out[1],
+                              num_moves=out[2], num_turns=out[3],
+                              converged=out[4])
+        outs = ((result,) if not faulty else
+                (result._replace(aggregate_drift=out[7]),
+                 faults.FaultOutcome(*out[6:])))
+        if mw:
+            # the fresh jax.jit(fn) above traces on every call, which
+            # fills measured["turn"] with the gathered candidates' size
+            rounds = int(np.asarray(out[3]))
+            fault_bytes = int(np.asarray(out[5])) if faulty else 0
+            outs = outs + (WireMeasurement(
+                rounds=jnp.int32(rounds),
+                payload_bytes=jnp.int32(rounds * measured["turn"]
+                                        + fault_bytes),
+                setup_bytes=jnp.int32(_nbytes((state0.loads, total_b)))),)
+        return outs[0] if len(outs) == 1 else outs
+
+    return _run(_SHARD_MAP, call, problem, assignment, framework, theta, s,
+                measure_wire, recorder, fault_plan, dm)
+
+
+# ---------------------------------------------------------------------------
+# Public wrappers: telemetry and recover-or-raise (DESIGN.md §14, §15)
+# ---------------------------------------------------------------------------
+
+class _Mode(NamedTuple):
+    """How a driver's runs are named and its wire is accounted."""
+    runtime: str              # run_start runtime name
+    phase: str                # recorder phase around the jitted call
+    traced: bool = False
+    simultaneous: bool = False
+
+
+_PLAIN = _Mode("distributed", "distributed.refine")
+_TRACED = _Mode("distributed_traced", "distributed.refine_traced",
+                traced=True)
+_SWEEP = _Mode("distributed_sweep", "distributed.refine_simultaneous",
+               simultaneous=True)
+_SHARD_MAP = _Mode("shard_map", "distributed.shard_map")
+
+
+def _run(mode: _Mode, call, problem: PartitionProblem, assignment: Array,
+         framework: str, theta, num_shards: int, measure_wire: bool,
+         recorder, fault_plan, degraded, **run_fields):
+    """The one harness behind the public drivers.  ``call(mw)`` runs the
+    jitted driver with ``measure_wire=mw``; with neither ``recorder`` nor
+    ``fault_plan`` its output is returned as is (no host work: the DES
+    engine calls the wrappers from traced code).  Otherwise the run is
+    opened as ``mode.runtime`` (with ``run_fields``), timed under
+    ``mode.phase``, its trace/sweeps and reconciled wire recorded; a
+    faulty run's outcome becomes an appended :class:`faults.FaultReport`
+    and a dead shard or blown recovery budget raises."""
+    if recorder is None and fault_plan is None:
+        return call(measure_wire)
+    faulty = fault_plan is not None
+    k = problem.num_machines
+    mw = measure_wire or recorder is not None
+    run = None
     if recorder is not None:
-        faults.emit_fault_events(recorder, run, fault_plan, rounds)
-        _record_wire(recorder, run, problem, s, wire,
-                     fault_extra=faults.plan_extra_bytes(
-                         fault_plan, rounds, msg))
-        recorder.record_result(run, result, wall=wall,
-                               recovered=report.recovered,
-                               recovery_drift=report.recovery_drift)
-    faults.raise_if_failed(report, budget=degraded.repair_tol)
-    if measure_wire:
-        return result, wire, report
-    return result, report
+        run = _open_run(recorder, mode.runtime, problem, assignment,
+                        framework, theta, num_shards=num_shards,
+                        **run_fields, **({"faults": True} if faulty else {}))
+    t0 = time.perf_counter()
+    with (recorder.phase(mode.phase, run) if recorder is not None
+          else contextlib.nullcontext()):
+        out = call(mw)
+        jax.block_until_ready(out)
+    wall = time.perf_counter() - t0
+    parts = list(out)
+    wire = parts.pop() if mw else None
+    outcome = parts.pop() if faulty else None
+    ftrace = (parts.pop() if faulty and (mode.traced or mode.simultaneous)
+              else None)
+    result, extras = parts[0], tuple(parts[1:])     # extras: trace/sweeps
+    rounds = int(result.num_turns)
+    if faulty:
+        report = faults.build_report(fault_plan, outcome, rounds,
+                                     budget=degraded.repair_tol,
+                                     raise_on_failure=False)
+    if recorder is not None:
+        if faulty:
+            ft = ftrace or FaultTrace(None, None, None)
+            faults.emit_fault_events(
+                recorder, run, fault_plan, rounds, repaired=ft.repaired,
+                repair_drift=ft.repair_drift, repaired_cols=ft.repaired_cols)
+        pots = None
+        if mode.traced:
+            recorder.record_trace(run, extras[0], problem.node_weights, k)
+            pots = (extras[0].c0, extras[0].ct0)
+        elif mode.simultaneous:
+            recorder.record_sweeps(run, *extras[0])
+            pots = extras[0][:2]
+        c0 = ct0 = None
+        if pots is not None and rounds:
+            c0, ct0 = (float(np.asarray(p)[rounds - 1]) for p in pots)
+        # measured wire vs the analytic ledger of the same executed run,
+        # the plan's retry/repair bytes included
+        fault_extra = 0 if not faulty else faults.plan_extra_bytes(
+            fault_plan, rounds, faults.message_bytes(
+                traced=mode.traced, simultaneous=mode.simultaneous,
+                num_machines=k))
+        ledger = accounting.ledger_for_run(
+            boundary_stats(problem, num_shards), k, int(wire.rounds),
+            traced=mode.traced, simultaneous=mode.simultaneous,
+            incremental=run_fields.get("incremental", True),
+            fault_bytes=fault_extra)
+        recorder.record_wire(run, accounting.reconcile(ledger, wire))
+        verdict = ({} if not faulty else
+                   dict(recovered=report.recovered,
+                        recovery_drift=report.recovery_drift))
+        recorder.record_result(run, result, wall=wall, c0=c0, ct0=ct0,
+                               **verdict)
+    outs = (result, *extras) + ((wire,) if measure_wire else ())
+    if faulty:
+        faults.raise_if_failed(report, budget=degraded.repair_tol)
+        outs = outs + (report,)
+    return outs[0] if len(outs) == 1 else outs
 
 
-# ---------------------------------------------------------------------------
-# Telemetry wrappers (DESIGN.md §14)
-# ---------------------------------------------------------------------------
-
-def _record_wire(recorder, run: str, problem: PartitionProblem,
-                 num_shards: int, wire: WireMeasurement, *,
-                 traced: bool = False, simultaneous: bool = False,
-                 incremental: bool = True, fault_extra: int = 0) -> None:
-    """Reconcile a driver's measured wire counters against the analytic
-    ledger for the same executed run and emit the ``wire`` event.
-    ``fault_extra`` is the plan-derived retry/repair byte total of a
-    fault-injected run (``faults.plan_extra_bytes``)."""
-    stats = boundary_stats(problem, num_shards)
-    ledger = accounting.ledger_for_run(
-        stats, problem.num_machines, int(wire.rounds), traced=traced,
-        simultaneous=simultaneous, incremental=incremental,
-        fault_bytes=fault_extra)
-    recorder.record_wire(run, accounting.reconcile(ledger, wire))
-
-
-def _run_faulty_emulated(mode: str, problem: PartitionProblem,
-                         assignment: Array, fault_plan, framework,
-                         num_shards, max_rounds: int, tol: float,
-                         cost_fn: str, incremental: bool, theta, degraded,
-                         measure_wire: bool, recorder):
-    """Shared recover-or-raise harness behind the three emulated public
-    wrappers: run the faulty driver, audit its FaultOutcome into a
-    :class:`faults.FaultReport`, stream telemetry when asked, and raise
-    the typed error on a dead shard / blown recovery budget."""
-    if not incremental:
+def _run_emulated(driver, mode: _Mode, cap: dict, problem, assignment,
+                  framework, num_shards, tol, cost_fn, incremental, theta,
+                  measure_wire, recorder, fault_plan, degraded):
+    """:func:`_run` over an emulated driver (``cap``: its step cap)."""
+    if fault_plan is not None and not incremental:
         raise ValueError(
             "fault injection requires the incremental protocol: the "
             "carried block aggregates are what faults corrupt and what "
             "repair heals (DESIGN.md §15)")
-    dm = degraded or faults.DEFAULT_DEGRADED
     s = _resolve_shards(problem, num_shards)
-    k = problem.num_machines
-    traced = mode == "traced"
-    simultaneous = mode == "sweep"
-    impl = {"plain": _refine_distributed_faulty,
-            "traced": _refine_distributed_traced_faulty,
-            "sweep": _refine_distributed_simultaneous_faulty}[mode]
-    phase = {"plain": "distributed.refine",
-             "traced": "distributed.refine_traced",
-             "sweep": "distributed.refine_simultaneous"}[mode]
-    runtime_name = {"plain": "distributed", "traced": "distributed_traced",
-                    "sweep": "distributed_sweep"}[mode]
-    mw = measure_wire or recorder is not None
-    run = None
-    if recorder is not None:
-        run = _open_run(recorder, runtime_name, problem, assignment,
-                        framework, theta, num_shards=s, incremental=True,
-                        faults=True)
-    ctx = (recorder.phase(phase, run) if recorder is not None
-           else contextlib.nullcontext())
-    t0 = time.perf_counter()
-    with ctx:
-        out = impl(problem, assignment, fault_plan, framework,
-                   num_shards=s, max_rounds=max_rounds, tol=tol,
-                   cost_fn=cost_fn, degraded=dm, theta=theta,
-                   measure_wire=mw)
-        jax.block_until_ready(out)
-    wall = time.perf_counter() - t0
-    wire = out[-1] if mw else None
-    core = out[:-1] if mw else out
-    ftrace = None
-    if mode == "plain":
-        result, outcome = core
-        extras = ()
-    elif mode == "traced":
-        result, trace, ftrace, outcome = core
-        extras = (trace,)
-    else:
-        result, outs, ftrace, outcome = core
-        extras = (outs,)
-    rounds = int(result.num_turns)
-    report = faults.build_report(fault_plan, outcome, rounds,
-                                 budget=dm.repair_tol,
-                                 raise_on_failure=False)
-    if recorder is not None:
-        if ftrace is not None:
-            faults.emit_fault_events(
-                recorder, run, fault_plan, rounds,
-                repair_drift=ftrace.repair_drift,
-                repaired_cols=ftrace.repaired_cols,
-                repaired=ftrace.repaired)
-        else:
-            faults.emit_fault_events(recorder, run, fault_plan, rounds)
-        last = max(rounds - 1, 0)
-        c0 = ct0 = None
-        if mode == "traced":
-            recorder.record_trace(run, extras[0], problem.node_weights, k)
-            if rounds:
-                c0 = float(np.asarray(extras[0].c0)[last])
-                ct0 = float(np.asarray(extras[0].ct0)[last])
-        elif mode == "sweep":
-            recorder.record_sweeps(run, *extras[0])
-            if rounds:
-                c0 = float(np.asarray(extras[0][0])[last])
-                ct0 = float(np.asarray(extras[0][1])[last])
-        _record_wire(recorder, run, problem, s, wire, traced=traced,
-                     simultaneous=simultaneous, incremental=True,
-                     fault_extra=faults.plan_extra_bytes(
-                         fault_plan, rounds, faults.message_bytes(
-                             traced=traced, simultaneous=simultaneous,
-                             num_machines=k)))
-        recorder.record_result(run, result, wall=wall, c0=c0, ct0=ct0,
-                               recovered=report.recovered,
-                               recovery_drift=report.recovery_drift)
-    faults.raise_if_failed(report, budget=dm.repair_tol)
-    if measure_wire:
-        return (result, *extras, wire, report)
-    return (result, *extras, report)
+    dm = degraded or faults.DEFAULT_DEGRADED
+    plan = ({} if fault_plan is None else
+            dict(fault_plan=fault_plan, degraded=dm))
+
+    def call(mw: bool):
+        return driver(problem, assignment, framework, num_shards=s, tol=tol,
+                      cost_fn=cost_fn, incremental=incremental, theta=theta,
+                      measure_wire=mw, **cap, **plan)
+
+    return _run(mode, call, problem, assignment, framework, theta, s,
+                measure_wire, recorder, fault_plan, dm,
+                incremental=incremental)
 
 
 def refine_distributed(problem: PartitionProblem, assignment: Array,
@@ -1599,41 +1194,19 @@ def refine_distributed(problem: PartitionProblem, assignment: Array,
                        recorder=None, fault_plan=None, degraded=None):
     """Distributed round-robin refinement (see :func:`_refine_distributed`
     for the protocol).  ``recorder`` (a :class:`repro.obs.Recorder`) opts
-    into run telemetry: the run is phase-timed, its measured wire bytes
-    are reconciled against ``accounting.ledger_for_run``, and the stream
-    closes with drift + ``run_end`` events.  ``recorder=None`` dispatches
-    straight to the identical jitted program — same cache entry.
+    into telemetry: the run is phase-timed, its measured wire reconciled
+    against ``accounting.ledger_for_run``, and the stream closes with
+    drift + ``run_end``; ``recorder=None`` calls the jitted program.
 
-    ``fault_plan`` (a :class:`repro.distributed.faults.FaultPlan`) opts
-    into the fault-injected driver under ``degraded``-mode rules
-    (DESIGN.md §15): returns ``(result, report[, wire in between])`` with
-    a :class:`faults.FaultReport` appended, raising ``DeadShardError`` /
-    ``RecoveryFailedError`` when the run cannot recover to the drift
-    budget — never silently diverging."""
-    if fault_plan is not None:
-        return _run_faulty_emulated(
-            "plain", problem, assignment, fault_plan, framework,
-            num_shards, max_turns, tol, cost_fn, incremental, theta,
-            degraded, measure_wire, recorder)
-    if recorder is None:
-        return _refine_distributed(
-            problem, assignment, framework, num_shards=num_shards,
-            max_turns=max_turns, tol=tol, cost_fn=cost_fn,
-            incremental=incremental, theta=theta, measure_wire=measure_wire)
-    s = _resolve_shards(problem, num_shards)
-    run = _open_run(recorder, "distributed", problem, assignment, framework,
-                    theta, num_shards=s, incremental=incremental)
-    t0 = time.perf_counter()
-    with recorder.phase("distributed.refine", run):
-        result, wire = _refine_distributed(
-            problem, assignment, framework, num_shards=s,
-            max_turns=max_turns, tol=tol, cost_fn=cost_fn,
-            incremental=incremental, theta=theta, measure_wire=True)
-        jax.block_until_ready(result)
-    wall = time.perf_counter() - t0
-    _record_wire(recorder, run, problem, s, wire, incremental=incremental)
-    recorder.record_result(run, result, wall=wall)
-    return (result, wire) if measure_wire else result
+    ``fault_plan`` (a :class:`faults.FaultPlan`) opts into the fault steps
+    under ``degraded``-mode rules (DESIGN.md §15): a
+    :class:`faults.FaultReport` is appended to the return (after the
+    wire), and ``DeadShardError`` / ``RecoveryFailedError`` raise when the
+    run cannot recover to the drift budget — never silently diverging."""
+    return _run_emulated(_refine_distributed, _PLAIN,
+                         dict(max_turns=max_turns), problem, assignment,
+                         framework, num_shards, tol, cost_fn, incremental,
+                         theta, measure_wire, recorder, fault_plan, degraded)
 
 
 def refine_distributed_traced(problem: PartitionProblem, assignment: Array,
@@ -1647,43 +1220,13 @@ def refine_distributed_traced(problem: PartitionProblem, assignment: Array,
                               recorder=None, fault_plan=None,
                               degraded=None):
     """Traced distributed refinement (see :func:`_refine_distributed_traced`).
-    ``recorder`` additionally streams one ``turn`` event per active turn
-    (from the returned trace — the carried exact-potential values ride
-    along) and the measured-vs-ledger ``wire`` reconciliation.
-    ``fault_plan`` as in :func:`refine_distributed` — the return tuple
-    gains a trailing :class:`faults.FaultReport`."""
-    if fault_plan is not None:
-        return _run_faulty_emulated(
-            "traced", problem, assignment, fault_plan, framework,
-            num_shards, max_turns, tol, cost_fn, incremental, theta,
-            degraded, measure_wire, recorder)
-    if recorder is None:
-        return _refine_distributed_traced(
-            problem, assignment, framework, num_shards=num_shards,
-            max_turns=max_turns, tol=tol, cost_fn=cost_fn,
-            incremental=incremental, theta=theta, measure_wire=measure_wire)
-    s = _resolve_shards(problem, num_shards)
-    run = _open_run(recorder, "distributed_traced", problem, assignment,
-                    framework, theta, num_shards=s, incremental=incremental)
-    t0 = time.perf_counter()
-    with recorder.phase("distributed.refine_traced", run):
-        result, trace, wire = _refine_distributed_traced(
-            problem, assignment, framework, num_shards=s,
-            max_turns=max_turns, tol=tol, cost_fn=cost_fn,
-            incremental=incremental, theta=theta, measure_wire=True)
-        jax.block_until_ready(result)
-    wall = time.perf_counter() - t0
-    recorder.record_trace(run, trace, problem.node_weights,
-                          problem.num_machines)
-    _record_wire(recorder, run, problem, s, wire, traced=True,
-                 incremental=incremental)
-    turns = int(result.num_turns)
-    last = max(turns - 1, 0)
-    recorder.record_result(
-        run, result, wall=wall,
-        c0=float(np.asarray(trace.c0)[last]) if turns else None,
-        ct0=float(np.asarray(trace.ct0)[last]) if turns else None)
-    return (result, trace, wire) if measure_wire else (result, trace)
+    ``recorder`` additionally streams one ``turn`` event per active turn,
+    carried potentials included.  ``recorder`` and ``fault_plan`` as in
+    :func:`refine_distributed`."""
+    return _run_emulated(_refine_distributed_traced, _TRACED,
+                         dict(max_turns=max_turns), problem, assignment,
+                         framework, num_shards, tol, cost_fn, incremental,
+                         theta, measure_wire, recorder, fault_plan, degraded)
 
 
 def refine_distributed_simultaneous(problem: PartitionProblem,
@@ -1698,39 +1241,10 @@ def refine_distributed_simultaneous(problem: PartitionProblem,
                                     recorder=None, fault_plan=None,
                                     degraded=None):
     """Distributed §4.5 sweeps (see :func:`_refine_distributed_simultaneous`).
-    ``recorder`` streams one ``sweep`` event per active sweep plus the
-    measured-vs-ledger ``wire`` reconciliation.  ``fault_plan`` as in
-    :func:`refine_distributed` — the return tuple gains a trailing
-    :class:`faults.FaultReport`."""
-    if fault_plan is not None:
-        return _run_faulty_emulated(
-            "sweep", problem, assignment, fault_plan, framework,
-            num_shards, max_sweeps, tol, cost_fn, incremental, theta,
-            degraded, measure_wire, recorder)
-    if recorder is None:
-        return _refine_distributed_simultaneous(
-            problem, assignment, framework, num_shards=num_shards,
-            max_sweeps=max_sweeps, tol=tol, cost_fn=cost_fn,
-            incremental=incremental, theta=theta, measure_wire=measure_wire)
-    s = _resolve_shards(problem, num_shards)
-    run = _open_run(recorder, "distributed_sweep", problem, assignment,
-                    framework, theta, num_shards=s, incremental=incremental)
-    t0 = time.perf_counter()
-    with recorder.phase("distributed.refine_simultaneous", run):
-        result, (c0s, ct0s, active), wire = _refine_distributed_simultaneous(
-            problem, assignment, framework, num_shards=s,
-            max_sweeps=max_sweeps, tol=tol, cost_fn=cost_fn,
-            incremental=incremental, theta=theta, measure_wire=True)
-        jax.block_until_ready(result)
-    wall = time.perf_counter() - t0
-    recorder.record_sweeps(run, c0s, ct0s, active)
-    _record_wire(recorder, run, problem, s, wire, simultaneous=True,
-                 incremental=incremental)
-    sweeps = int(result.num_turns)
-    last = max(sweeps - 1, 0)
-    recorder.record_result(
-        run, result, wall=wall,
-        c0=float(np.asarray(c0s)[last]) if sweeps else None,
-        ct0=float(np.asarray(ct0s)[last]) if sweeps else None)
-    return ((result, (c0s, ct0s, active), wire) if measure_wire
-            else (result, (c0s, ct0s, active)))
+    ``recorder`` additionally streams one ``sweep`` event per active
+    sweep.  ``recorder`` and ``fault_plan`` as in
+    :func:`refine_distributed`."""
+    return _run_emulated(_refine_distributed_simultaneous, _SWEEP,
+                         dict(max_sweeps=max_sweeps), problem, assignment,
+                         framework, num_shards, tol, cost_fn, incremental,
+                         theta, measure_wire, recorder, fault_plan, degraded)

@@ -50,3 +50,31 @@ def small_problem(n=24, k=3, seed=0, mu=4.0):
     b, c = random_weights(adj, seed=seed + 1, mean=5.0)
     speeds = np.random.default_rng(seed + 2).uniform(0.5, 2.0, size=k)
     return adj, make_problem(c, b, speeds, mu=mu)
+
+
+@pytest.fixture(scope="session")
+def simultaneous_ref():
+    """Checker against the recorded outputs of the §4.5 simultaneous mode
+    (``tests/data/refine_simultaneous_ref.npz``, keyed by case prefix):
+    ``check(prefix, result, (c0s, ct0s, active))`` asserts the final
+    assignment, loads, move/turn/sweep counts and per-sweep potentials
+    and ``active`` flags equal the recorded ones bitwise."""
+    from pathlib import Path
+
+    ref = np.load(Path(__file__).parent / "data"
+                  / "refine_simultaneous_ref.npz")
+
+    def check(prefix, result, outs):
+        c0s, ct0s, active = outs
+        got = dict(assignment=result.assignment, loads=result.loads,
+                   num_moves=result.num_moves, num_turns=result.num_turns,
+                   num_sweeps=result.num_sweeps, c0s=c0s, ct0s=ct0s,
+                   active=active)
+        for field, value in got.items():
+            want = ref[f"{prefix}/{field}"]
+            value = np.asarray(value)
+            assert value.dtype == want.dtype, (prefix, field, value.dtype)
+            np.testing.assert_array_equal(value, want,
+                                          err_msg=f"{prefix}/{field}")
+
+    return check
